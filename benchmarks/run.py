@@ -384,9 +384,11 @@ def shared_rows(bench_json: str = "BENCH_pr2.json"):
 def _shard_subprocess(argv, timeout=1800):
     """Run benchmarks/shard_bench.py in a subprocess (it must force the host
     device count before jax initializes — this process has usually already
-    initialized jax on 1 device).  Raises RuntimeError with a one-line
-    detail on timeout or a non-zero exit."""
-    env = dict(os.environ)
+    initialized jax on 1 device).  The child runs on the CPU, which is what
+    it measures by design — and a parent holding an accelerator would make
+    a child that reaches for it fail or hang.  Raises RuntimeError with a
+    one-line detail on timeout or a non-zero exit."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = os.path.join(REPO_ROOT, "src") + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
     try:

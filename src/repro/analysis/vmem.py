@@ -120,7 +120,7 @@ def _build_families() -> List[Family]:
     from repro.kernels.pcilt_conv2d import pcilt_conv2d_pallas
     from repro.kernels.pcilt_dwconv1d import pcilt_fused_dwconv1d_pallas
     from repro.kernels.pcilt_fused import (
-        pcilt_fused_conv2d_pallas, pcilt_fused_gemv_pallas,
+        pack_chunk, pcilt_fused_conv2d_pallas, pcilt_fused_gemv_pallas,
         pcilt_fused_gemv_paired_pallas,
         pcilt_fused_gemv_paired_stacked_pallas, pcilt_fused_gemv_plan_pallas,
         pcilt_fused_gemv_stacked_pallas)
@@ -186,7 +186,8 @@ def _build_families() -> List[Family]:
         return j, tiles
 
     def fused_gemv_witness(s, eff):
-        return [(eff[0], eff[1] * s["V"])]
+        # the [Bb, Gs*V] one-hot of one pack chunk (Gs | Gb segments)
+        return [(eff[0], pack_chunk(eff[1], s["group"], s["V"]) * s["V"])]
 
     STACKED_SWEEP = {
         "quick": [dict(B=8, L=3, G=16, V=16, O=256, group=2, bits=2,
@@ -367,7 +368,8 @@ def _build_families() -> List[Family]:
         return j, tiles
 
     def fused_conv_witness(s, eff):
-        return [(eff[0] * s["Wo"], eff[1] * s["V"])]
+        return [(eff[0] * s["Wo"],
+                 pack_chunk(eff[1], s["group"], s["V"]) * s["V"])]
 
     # -- shared pool (extension 3) ----------------------------------------
 
@@ -405,7 +407,9 @@ def _build_families() -> List[Family]:
         return j, tiles
 
     def shared_gemv_witness(s, eff):
-        return [(eff[0], eff[1], s["V"])]
+        # one offset value's [Bb, Gb] slice of the modeled [Bb, Gb, V]
+        # one-hot (the kernel walks the V values in turn)
+        return [(eff[0], eff[1])]
 
     SHARED_CONV_SWEEP = {
         "quick": [dict(B=1, Ho=8, Wo=8, C=8, kh=3, kw=3, stride=1, G=36,
@@ -445,7 +449,7 @@ def _build_families() -> List[Family]:
         return j, tiles
 
     def shared_conv_witness(s, eff):
-        return [(eff[0] * s["Wo"], eff[1], s["V"])]
+        return [(eff[0] * s["Wo"], eff[1])]
 
     # -- fused depthwise conv1d --------------------------------------------
 
@@ -460,7 +464,8 @@ def _build_families() -> List[Family]:
 
     def dw_cands(s, budget):
         return atn.dwconv1d_candidates(s["To"], s["C"], dw_V(s), s["k"],
-                                       s["itemsize"], scratch_budget=budget)
+                                       s["itemsize"], scratch_budget=budget,
+                                       B=s["B"])
 
     def dw_eff(s, c):
         return (atn._div_down(s["To"], max(1, c.Bb)),
@@ -468,11 +473,9 @@ def _build_families() -> List[Family]:
 
     def dw_scratch(s, c):
         V = dw_V(s)
-        h = (s["bits"] * s["k"]) // 2
-        Vl, Vh = 1 << h, V >> h
         Tb, Cb = dw_eff(s, c)
-        fixed = (s["To"] + s["k"] - 1) * Cb * 4 + Cb * V * s["itemsize"]
-        return Tb * Cb * (Vl + 2 * Vh) * 4 + fixed
+        fixed = Cb * V * 4 + Cb * V * s["itemsize"]
+        return Tb * s["B"] * Cb * 3 * 4 + fixed
 
     def dw_trace(s, c, counters=False):
         Tb, Cb = dw_eff(s, c)
@@ -486,12 +489,8 @@ def _build_families() -> List[Family]:
         return j, (Tb, Cb)
 
     def dw_witness(s, eff):
-        h = (s["bits"] * s["k"]) // 2
-        Vh = dw_V(s) >> h
-        Tb, Cb = eff
-        # the factored fetch's largest intermediate: the [Cb, Vh, Tb]
-        # partial-fetch tensor (f32)
-        return [(Cb, Vh, Tb)]
+        # the in-VMEM transposed [V, Cb] table the select fetch walks
+        return [(dw_V(s), eff[1])]
 
     return [
         Family("gemv_host", _kpath("pcilt_gemv.py"), GEMV_SWEEP,
@@ -561,12 +560,12 @@ def FAMILIES() -> List[Family]:
 
 
 def _subjaxprs(params: dict):
-    import jax
+    from jax.extend import core as jcore
 
     def as_jaxprs(v):
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, jcore.ClosedJaxpr):
             yield v.jaxpr
-        elif isinstance(v, jax.core.Jaxpr):
+        elif isinstance(v, jcore.Jaxpr):
             yield v
         elif isinstance(v, (list, tuple)):
             for x in v:
@@ -601,7 +600,9 @@ def _all_avals(jaxpr, out: Optional[list] = None) -> list:
 
 
 def _block_shape(bm) -> Tuple[int, ...]:
-    return tuple(int(b) if isinstance(b, int) else 1
+    # block dims are ``pl.Blocked`` (carrying ``block_size``) or squeezed
+    # (size-1) markers
+    return tuple(b if isinstance(b, int) else int(getattr(b, "block_size", 1))
                  for b in bm.block_shape)
 
 
@@ -657,7 +658,7 @@ def _check_blocks(fam: Family, sym: str, eqn, L: Optional[int]
     for bi, bm in enumerate(gm.block_mappings):
         is_output = bi >= len(gm.block_mappings) - n_out
         bs = _block_shape(bm)
-        dims = tuple(int(d) for d in bm.array_shape_dtype.shape)
+        dims = tuple(int(d) for d in bm.array_aval.shape)
         nblocks = [max(1, -(-d // b)) for d, b in zip(dims, bs)]
         per_l = []
         for pv in prefetch_vals:
@@ -724,14 +725,15 @@ def _staged_bytes(eqn) -> int:
         n = 1
         for b in bs:
             n *= b
-        total += n * bm.array_shape_dtype.dtype.itemsize
+        total += n * bm.array_aval.dtype.itemsize
     return total
 
 
 def _has_witness(eqn, shapes: Sequence[Tuple[int, ...]]) -> bool:
+    from jax.extend import core as jcore
+
     kj = eqn.params["jaxpr"]
-    import jax
-    if isinstance(kj, jax.core.ClosedJaxpr):
+    if isinstance(kj, jcore.ClosedJaxpr):
         kj = kj.jaxpr
     want = {tuple(s) for s in shapes}
     for aval in _all_avals(kj):

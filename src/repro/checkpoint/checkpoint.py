@@ -40,9 +40,7 @@ def _flatten(tree):
 
 
 def _paths(tree):
-    from repro.compat import tree_flatten_with_path
-
-    flat, _ = tree_flatten_with_path(tree)
+    flat, _ = jax.tree.flatten_with_path(tree)
     return ["/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
             for path, _ in flat]
 

@@ -1,83 +1,16 @@
-"""Version-tolerance shims for the jax APIs this repo uses.
+"""Tracer detection for eager-vs-traced dispatch.
 
-The codebase targets the modern public spellings (``jax.shard_map``,
-``jax.tree.flatten_with_path``); older jax releases only ship them under
-``jax.experimental`` / ``jax.tree_util``.  Everything funnels through here so
-the rest of the code can stay on one spelling.
+The autotuner times kernels only on concrete inputs — never under a ``jit``
+trace — so the dispatch wrappers need to tell the two apart.
 """
 
 from __future__ import annotations
 
 import jax
 
-__all__ = ["shard_map", "tree_flatten_with_path", "axis_size", "is_tracer"]
-
-
-_TRACER_TYPES: tuple = ()
-
-
-def _tracer_types() -> tuple:
-    global _TRACER_TYPES
-    if not _TRACER_TYPES:
-        types = []
-        try:  # newer jax: the supported public home
-            from jax.extend import core as _xcore
-
-            t = getattr(_xcore, "Tracer", None)
-            if t is not None:
-                types.append(t)
-        except ImportError:
-            pass
-        t = getattr(getattr(jax, "core", None), "Tracer", None)
-        if t is not None and t not in types:
-            types.append(t)
-        _TRACER_TYPES = tuple(types)
-    return _TRACER_TYPES
+__all__ = ["is_tracer"]
 
 
 def is_tracer(x) -> bool:
-    """``isinstance(x, Tracer)`` across jax versions.
-
-    ``jax.core.Tracer`` is deprecated/being removed; newer jax exposes the
-    class under ``jax.extend.core``.  Falls back to an MRO name probe when
-    neither module offers it, so eager-vs-traced dispatch (e.g. the autotune
-    "never time under a jit trace" rule) keeps working across versions.
-    """
-    ts = _tracer_types()
-    if ts:
-        return isinstance(x, ts)
-    return any(c.__name__ == "Tracer" for c in type(x).__mro__)
-
-
-def shard_map(f=None, /, **kwargs):
-    """``jax.shard_map`` with a fallback to the experimental location.
-
-    The old API names the replication-check kwarg ``check_rep`` instead of
-    ``check_vma``; translate when falling back.
-    """
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-    if f is None:
-        return lambda g: fn(g, **kwargs)
-    return fn(f, **kwargs)
-
-
-def tree_flatten_with_path(tree, is_leaf=None):
-    """``jax.tree.flatten_with_path`` with a ``jax.tree_util`` fallback."""
-    fn = getattr(jax.tree, "flatten_with_path", None)
-    if fn is None:
-        fn = jax.tree_util.tree_flatten_with_path
-    return fn(tree, is_leaf=is_leaf)
-
-
-def axis_size(axis_name) -> int:
-    """``jax.lax.axis_size`` only exists in newer jax; ``psum(1)`` is the
-    portable spelling of "how many shards am I across this axis"."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """True when ``x`` is an abstract value of an ongoing trace."""
+    return isinstance(x, jax.core.Tracer)

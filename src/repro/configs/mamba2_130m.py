@@ -6,10 +6,12 @@ d_state 128, head_dim 64 (24 heads), expand 2, conv kernel 4, tied embeds.
 head-replicated (projections still TP-shard); noted in the roofline table.
 """
 
-from .base import ModelConfig, SSMConfig
+from .base import ModelConfig, PCILTConfig, SSMConfig
 
 
 def config():
+    # Served table format: unpaired INT2 g2 f32 tables, ~6 GB at this width
+    # (INT4 g2 would be ~77 GB) — the one that fits a 16 GB chip.
     return ModelConfig(
         name="mamba2-130m", family="ssm",
         n_layers=24, d_model=768, n_heads=0, n_kv_heads=0,
@@ -18,6 +20,7 @@ def config():
                       expand=2, chunk=256),
         tie_embeddings=True,
         remat_policy="full", loss_chunk=1024,
+        pcilt=PCILTConfig(act_bits=2, group=2),
     )
 
 

@@ -77,6 +77,7 @@ __all__ = [
     "im2col",
     "conv_same_pads",
     "mesh_shard_count",
+    "replicated_on",
 ]
 
 
@@ -144,6 +145,18 @@ def mesh_shard_count(mesh, mesh_axis: str, n_segments: int) -> int:
     if d <= 1 or n_segments % d:
         return 1
     return d
+
+
+def replicated_on(mesh, fn):
+    """``fn`` run replicated on every device of ``mesh``, under
+    ``shard_map``: in a program partitioned over several devices, each
+    Pallas kernel must sit inside a ``shard_map`` (the compiler cannot
+    partition a Mosaic call).  Without a multi-device mesh, ``fn`` itself.
+    """
+    if mesh is None or mesh.size == 1:
+        return fn
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)
 
 
 def _check_contiguous_segments(path: str, plan, n: int, n_segments: int,
@@ -221,8 +234,6 @@ def _pcilt_linear_sharded(x, tables, spec, scale, group, path, mesh,
     the ``psum`` over ``mesh_axis`` — the one collective of the whole layer.
     ``check_vma=False``: Pallas calls carry no replication rule.
     """
-    from repro import compat
-
     lead = x.shape[:-1]
     flat = x.reshape(-1, x.shape[-1])
 
@@ -233,7 +244,7 @@ def _pcilt_linear_sharded(x, tables, spec, scale, group, path, mesh,
             part = pcilt_linear(xl, local, spec, scale, group, path=path)
             return jax.lax.psum(part, mesh_axis)
 
-        out = compat.shard_map(
+        out = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(None, mesh_axis), P(mesh_axis), P(mesh_axis)),
             out_specs=P(), check_vma=False,
@@ -244,7 +255,7 @@ def _pcilt_linear_sharded(x, tables, spec, scale, group, path, mesh,
                                 paired=paired)
             return jax.lax.psum(part, mesh_axis)
 
-        out = compat.shard_map(
+        out = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(None, mesh_axis), P(mesh_axis, None, None)),
             out_specs=P(), check_vma=False,
@@ -262,7 +273,6 @@ def _pcilt_linear_stacked_sharded(x, tables, layer, spec, scale, group,
     sums — the stacked kernel's scalar-prefetch table staging survives the
     mesh unchanged because every shard's stack stays put in its own HBM.
     """
-    from repro import compat
     from repro.kernels import ops  # local import: kernels are optional
 
     lead = x.shape[:-1]
@@ -274,7 +284,7 @@ def _pcilt_linear_stacked_sharded(x, tables, layer, spec, scale, group,
                                             group)
         return jax.lax.psum(part, mesh_axis)
 
-    out = compat.shard_map(
+    out = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(None, mesh_axis), P(None, mesh_axis, None, None), P()),
         out_specs=P(), check_vma=False,
@@ -289,7 +299,6 @@ def _pcilt_linear_paired_stacked_sharded(x, tables, layer, spec, scale,
     ``ndim=4, seg_axis=0`` in ``pcilt_table_sharding``), each device runs
     the paired stacked kernel over its resident ``[G2/D, L, V2, O]`` shard,
     and one ``psum`` per step combines the partial adder-tree sums."""
-    from repro import compat
     from repro.kernels import ops  # local import: kernels are optional
 
     lead = x.shape[:-1]
@@ -301,7 +310,7 @@ def _pcilt_linear_paired_stacked_sharded(x, tables, layer, spec, scale,
                                                    scale, group)
         return jax.lax.psum(part, mesh_axis)
 
-    out = compat.shard_map(
+    out = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(None, mesh_axis), P(mesh_axis, None, None, None), P()),
         out_specs=P(), check_vma=False,
@@ -692,7 +701,6 @@ def _pcilt_conv2d_sharded_kernel(x, tables, spec, scale, group, kh, kw,
     over ``mesh_axis`` combines the partial adder-tree sums, exactly like
     the sharded linear path.
     """
-    from repro import compat
     from repro.kernels import ops  # local import: kernels are optional
 
     if isinstance(tables, ShardedSharedPool):
@@ -722,7 +730,7 @@ def _pcilt_conv2d_sharded_kernel(x, tables, spec, scale, group, kh, kw,
                 padding=padding, seg_offset=seg0, n_total=n_total)
             return jax.lax.psum(part, mesh_axis)
 
-        return compat.shard_map(
+        return jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(), P(mesh_axis, None, None)),
             out_specs=P(), check_vma=False,
@@ -735,7 +743,7 @@ def _pcilt_conv2d_sharded_kernel(x, tables, spec, scale, group, kh, kw,
             stride=stride, padding=padding, seg_offset=seg0, n_total=n_total)
         return jax.lax.psum(part, mesh_axis)
 
-    return compat.shard_map(
+    return jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(), P(mesh_axis), P(mesh_axis)),
         out_specs=P(), check_vma=False,
@@ -856,7 +864,8 @@ def build_dwconv_tables(filters: jax.Array, spec: QuantSpec, scale) -> jax.Array
 
     k, _ = filters.shape
     vals = code_values(spec, scale)[offset_grid(spec.bits, k)]  # [V, k]
-    return jnp.einsum("vk,kc->cv", vals, filters.astype(vals.dtype))
+    return jnp.einsum("vk,kc->cv", vals, filters.astype(vals.dtype),
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def pcilt_depthwise_conv1d(

@@ -169,7 +169,10 @@ def build_grouped_tables(
     V = vals.shape[0]
 
     if fn is mul_fn:
-        return jnp.einsum("vj,gjo->gvo", vals, w_seg)
+        # full precision: a TPU's default f32 contraction rounds operands
+        # to bf16, and a table must hold the exact pre-summed products
+        return jnp.einsum("vj,gjo->gvo", vals, w_seg,
+                          precision=jax.lax.Precision.HIGHEST)
 
     def chunk_tables(vchunk):  # [C, g] -> [G, C, out]
         contrib = fn(w_seg[:, None, :, :], vchunk[None, :, :, None])
@@ -457,7 +460,8 @@ def build_shared_grouped_tables(
     V = vals.shape[0]
 
     if fn is mul_fn:
-        pool = jnp.einsum("vj,xjo->xvo", vals, uw)
+        pool = jnp.einsum("vj,xjo->xvo", vals, uw,
+                          precision=jax.lax.Precision.HIGHEST)
     else:
         def chunk_tables(vchunk):  # [C, g] -> [X, C, out]
             contrib = fn(uw[:, None, :, :], vchunk[None, :, :, None])

@@ -559,34 +559,62 @@ class PCILTMambaDecode:
         # dimension R is a first-class tuned axis of the stacked kernels
         # (``fused_gemv_stacked`` keys carry R), so an engine serving R=8
         # slots and a sibling serving R=32 dispatch distinct compiled steps
-        # — each closing over the same resident table stack — instead of
-        # sharing one retraced-on-shape-change function.  The stats flag is
-        # a static trace property (counter outputs change the step's
-        # result pytree), so monitored and unmonitored steps likewise hold
-        # separate compiled executors.
+        # instead of sharing one retraced-on-shape-change function.  The
+        # stats flag is a static trace property (counter outputs change the
+        # step's result pytree), so monitored and unmonitored steps likewise
+        # hold separate compiled executors.
+        #
+        # The bundle's arrays are executor *arguments*, never closure
+        # constants: a closed-over array is embedded in the compiled
+        # program (GBs of tables at published widths — past what a
+        # serialized module can hold, and a second device copy).  The
+        # bundle's structure and its non-array leaves (quantizer specs,
+        # groups, mesh) are the static part, captured here.
         self._execs: Dict[Tuple[int, bool], object] = {}
+        leaves, self._treedef = jax.tree.flatten(self._traced_bundle())
+        self._is_array = tuple(isinstance(l, (jax.Array, np.ndarray))
+                               for l in leaves)
+        self._statics = [l for l, a in zip(leaves, self._is_array) if not a]
+
+    def _traced_bundle(self) -> Dict:
+        # the integrity record is host-side bookkeeping, not step input
+        return {k: v for k, v in self.pcilt.items() if k != "integrity"}
+
+    def bundle_arrays(self) -> List[jax.Array]:
+        """The bundle's current array leaves, in executor-argument order —
+        read at every step, so swapped tables are what the step uses."""
+        leaves = jax.tree.leaves(self._traced_bundle())
+        return [l for l, a in zip(leaves, self._is_array) if a]
+
+    def _rebuild(self, arrays) -> Dict:
+        arrs, statics = iter(arrays), iter(self._statics)
+        return jax.tree.unflatten(
+            self._treedef,
+            [next(arrs) if a else next(statics) for a in self._is_array])
 
     def executor(self, rows: int, stats: bool = False):
         """The hoisted jitted step for a decode batch of ``rows`` slots
         (built on first use, then cached — serving loops at a fixed slot
-        count pay tracing exactly once).  ``stats=True`` builds the
-        drift-monitored variant: the step additionally returns the
-        per-layer saturation counters (``decode_step(with_stats=True)``)."""
+        count pay tracing exactly once): ``f(params, cache, tokens,
+        layer_ok, head_ok, arrays)`` with ``arrays`` from
+        :meth:`bundle_arrays`.  ``stats=True`` builds the drift-monitored
+        variant: the step additionally returns the per-layer saturation
+        counters (``decode_step(with_stats=True)``)."""
         key = (rows, stats)
         f = self._execs.get(key)
         if f is None:
             f = jax.jit(
-                lambda p, c, t, ok, hok: self.model.decode_step(
-                    p, c, t, self.ctx, pcilt=self.pcilt, layer_ok=ok,
-                    head_ok=hok, with_stats=stats))
+                lambda p, c, t, ok, hok, arrays: self.model.decode_step(
+                    p, c, t, self.ctx, pcilt=self._rebuild(arrays),
+                    layer_ok=ok, head_ok=hok, with_stats=stats))
             self._execs[key] = f
         return f
 
     def rehoist(self, verify: bool = False) -> None:
-        """Rebuild the jitted executors after the bundle's table arrays were
-        *replaced* (jit closes over the array values — swapping a dict entry
-        has no effect on the compiled step until re-hoisted).  Drops every
-        cached executor; each is rebuilt lazily on its next step.
+        """Rebuild the jitted executors after the bundle was modified.
+        Swapped table *arrays* need no rehoist — they are step arguments —
+        but a change to the bundle's structure or static entries does; this
+        drops every cached executor, each rebuilt lazily on its next step.
 
         By default this does NOT re-verify integrity: detecting bad bytes at
         serving time is the health monitor's job, and the chaos suite
@@ -618,7 +646,7 @@ class PCILTMambaDecode:
             head_ok = jnp.asarray(True)
         fn = self.executor(int(tokens.shape[0]), stats=with_stats)
         return fn(params, cache, tokens, jnp.asarray(layer_ok, bool),
-                  jnp.asarray(head_ok, bool))
+                  jnp.asarray(head_ok, bool), self.bundle_arrays())
 
     __call__ = step
 
@@ -894,7 +922,9 @@ class HealthMonitor:
         got = pcilt_linear(jnp.asarray(xx), t, spec, scale, group,
                            path="gather", stacked=int(layer), paired=paired)
         k = self.params["blocks"]["mixer"][name]["kernel"][layer]
-        want = fake_quant(jnp.asarray(x), spec, scale) @ k.astype(jnp.float32)
+        want = jnp.dot(fake_quant(jnp.asarray(x), spec, scale),
+                       k.astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
         return bool(np.allclose(np.asarray(got), np.asarray(want),
                                 rtol=self.oracle_tol, atol=self.oracle_tol))
 

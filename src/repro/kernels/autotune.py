@@ -11,9 +11,8 @@ Cache format (JSON, one object per shape key)::
     {
       "fused_gemv|B=8,G=512,V=16,O=1024,bits=2,g=2,dtype=float32|backend=cpu": {
         "tiles": {"Bb": 8, "Gb": 512, "Ob": 128, "row_tile": 8},
-        "us": 812.4,          # winning candidate's measured microseconds,
-                              # or null when every candidate failed to run
-                              # (the heuristic fallback was recorded untimed)
+        "us": 812.4,          # winning candidate's measured microseconds
+                              # (null only in hand-written or legacy files)
         "candidates": 4       # how many tilings were timed at record time
       },
       "shared_gemv|B=8,G=512,O=1024,V=16,X=16,bits=2,g=2,...": {...},
@@ -60,7 +59,7 @@ only on the problem the kernel actually sees.  A failed sharded tune records
 ``us: null`` exactly like an unsharded one.
 
 The cache file lives at ``$REPRO_PCILT_TUNE_CACHE`` (tests point this at a
-tmpdir) or ``~/.cache/repro-pcilt/tiles.json`` by default, and is written
+tmpdir) or ``pcilt_tiles.json`` at the root of the checkout, and is written
 atomically (tmp + rename) so concurrent processes can share it.  On save, a
 process merges the freshest on-disk state with **only the keys it recorded
 itself** — last writer wins per key, and a writer can never clobber another
@@ -117,9 +116,10 @@ __all__ = [
 #: this to assert that a warm cache performs *zero* timing runs.
 TIMING_RUNS = 0
 
+#: the tile table kept in the checkout (``src/repro/kernels`` -> repo root)
 _DEFAULT_CACHE = os.path.join(
-    os.path.expanduser("~"), ".cache", "repro-pcilt", "tiles.json"
-)
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), "pcilt_tiles.json")
 
 
 #: quarantined cache files kept per path — repeated corruption (flaky disk,
@@ -328,7 +328,9 @@ def tune(
 
     ``bench(cfg)`` returns a nullary closure that runs the kernel once (and
     blocks) at tiling ``cfg``.  A candidate that fails to run (e.g. a tiling
-    the backend rejects) is skipped rather than fatal.
+    the backend rejects) is skipped; when *no* candidate runs the tune
+    raises with the last error — recording an untimed fallback would hide a
+    kernel the device cannot compile at all.
     """
     cache = get_cache()
     hit = cache.lookup(key)
@@ -337,18 +339,21 @@ def tune(
     best: Optional[TileConfig] = None
     best_us = float("inf")
     tried = 0
+    last_err: Optional[Exception] = None
     for cfg in candidates:
         try:
             fn = bench(cfg)
             us = _time_one(fn, reps=reps, warmup=warmup)
-        except Exception:
+        except Exception as e:  # noqa: BLE001 — a rejected tiling
+            last_err = e
             continue
         tried += 1
         if us < best_us:
             best, best_us = cfg, us
-    if best is None:  # nothing ran; fall back to the first heuristic candidate
-        # Recorded with us=null (valid JSON) — "untimed", not a bare NaN token.
-        best, best_us = candidates[0], None
+    if best is None:
+        raise RuntimeError(
+            f"autotune {key}: none of {len(candidates)} candidate tilings "
+            f"ran (last error: {last_err!r})") from last_err
     cache.record(key, best, best_us, tried)
     return best
 
@@ -764,29 +769,26 @@ def shared_conv2d_candidates(Ho: int, G: int, V: int, O: int, X: int,
 
 
 def dwconv1d_candidates(T: int, C: int, V: int, k: int, itemsize: int = 4,
-                        scratch_budget: float = SCRATCH_BUDGET
+                        scratch_budget: float = SCRATCH_BUDGET, B: int = 1
                         ) -> List[TileConfig]:
     """``(Tb, Cb)`` tilings for the fused depthwise conv1d
     (``kernels/pcilt_dwconv1d.py``), encoded as ``TileConfig(Bb=Tb, Ob=Cb)``.
 
-    The kernel's per-step scratch is the *factored* two-level one-hot —
-    ``Vl + Vh`` indicator lanes plus the ``[Cb, Vh, Tb]`` partial fetch
-    (``V = Vl * Vh``, split at ``(bits*k)//2``) — so the analytic bound caps
-    the *time* tile per channel block on ``Vl + 2*Vh`` effective lanes, not
-    ``V`` (``T`` is the output length; the staged signal strip adds
-    ``(T + k - 1) * Cb`` floats of fixed bytes, and the ``[Cb, V]`` table
-    tile is Tb-independent)."""
+    A grid step covers ``Tb`` time steps of all ``B`` batch rows
+    (``R = Tb*B`` rows).  Its scratch is the in-VMEM transposed ``[V, Cb]``
+    f32 table plus the staged ``[Cb, V]`` table tile (``Tb``-independent
+    fixed bytes) and three f32/int32 ``[R, Cb]`` working planes (codes,
+    packed offsets, the select accumulator) — so the analytic bound caps
+    the time tile at 3 effective lanes per row (``T`` is the output
+    length)."""
     Cb = _div_down(C, 128)
-    bw = max((V - 1).bit_length(), 1)
-    Vl = 1 << (bw // 2)
-    Vh = -(-V // Vl)
-    v_eff = Vl + 2 * Vh
     out: List[TileConfig] = []
     seen = set()
 
     def add(tb: int, cb: int) -> None:
-        fixed = (T + k - 1) * cb * 4 + cb * V * itemsize
-        cap = _fit_scratch_gb(T, cb, v_eff, 4, fixed, budget=scratch_budget)
+        fixed = cb * V * 4 + cb * V * itemsize
+        cap = _fit_scratch_gb(T, max(B, 1) * cb, 3, 4, fixed,
+                              budget=scratch_budget)
         tb = _div_down(T, max(1, min(tb, cap)))
         if (tb, cb) not in seen:
             seen.add((tb, cb))

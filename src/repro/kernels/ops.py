@@ -301,12 +301,13 @@ def pcilt_fused_dwconv1d(
                 xp, s2, tp):
             cfg = atn.tune(
                 key,
-                atn.dwconv1d_candidates(To, Cp, V, k, tables.dtype.itemsize),
+                atn.dwconv1d_candidates(To, Cp, V, k, tables.dtype.itemsize,
+                                        B=B),
                 lambda c: _fused_dwconv1d_bench(xp, s2, tp, c, kw, To),
             )
         if cfg is None:
             cfg = atn.dwconv1d_candidates(To, Cp, V, k,
-                                          tables.dtype.itemsize)[0]
+                                          tables.dtype.itemsize, B=B)[0]
         tiles = (cfg.Bb, cfg.Ob)
     tiles = (atn._div_down(To, max(1, tiles[0])),
              atn._div_down(Cp, max(1, tiles[1])))

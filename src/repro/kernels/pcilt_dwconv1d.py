@@ -13,20 +13,20 @@ sublanes.  Two kernels implement it:
   is a blocked masked-sum "gather" (a ``fori_loop`` over the ``V`` table
   entries) with the per-channel tables staged in VMEM.
 * **fused** (``pcilt_fused_dwconv1d_pallas``): raw float activations in —
-  quantize, causal tap-stack (a static ``k``-slice loop over the staged
-  signal strip), and little-endian shift-or pack all run in VMEM, so the
-  ``[B, T, C]`` int32 offset tensor (as large as the activations themselves)
-  never touches HBM.  The fetch is one batched one-hot contraction
-  ``[Cb, Tb, V] x [Cb, V] -> [Cb, Tb]`` instead of the ``V``-step masked
-  sum: exactly one one-hot term is nonzero per output, so f32 accumulation
-  reproduces the table cell bit-exactly even for bf16 tables (same contract
-  as the host-packed kernel's f32 accumulation).
+  quantize, little-endian shift-or pack of the ``k`` causal taps, and the
+  fetch all run in VMEM, so the ``[B, T, C]`` int32 offset tensor never
+  touches HBM.  The wrapper lays the padded signal out **taps-major**,
+  ``[k, To*B, C]`` (tap ``j`` of output row ``t*B + b`` is padded input
+  ``t + j`` of batch ``b``; a decode window is just its transpose), so
+  every tap is a leading-axis slice with rows on sublanes and channels on
+  lanes.  The fetch is a select chain over the ``V`` table entries against
+  the in-VMEM transposed ``[V, Cb]`` table: exactly one entry matches each
+  output, so the result is the table cell bit-exactly (bf16 tables
+  included).
 
-The fused kernel stages the whole (padded) signal per channel block —
-``[Tp, Cb]`` floats — and revisits it across time tiles, mirroring how the
-fused conv2d kernel stages the image; the ``(Tb, Cb)`` tiling is dispatched
-through the persistent autotune table under ``fused_dwconv1d`` keys
-(``ops.py`` / ``autotune.dwconv1d_candidates``).
+The ``(Tb, Cb)`` tiling (``Tb`` time steps of every batch row per grid step)
+is dispatched through the persistent autotune table under ``fused_dwconv1d``
+keys (``ops.py`` / ``autotune.dwconv1d_candidates``).
 """
 
 from __future__ import annotations
@@ -36,8 +36,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .pcilt_fused import _quantize
+from .pcilt_fused import _call_with_stats, _quantize_f32, _update_stats
 
 __all__ = ["pcilt_dwconv1d_pallas", "pcilt_fused_dwconv1d_pallas"]
 
@@ -92,99 +93,54 @@ def pcilt_dwconv1d_pallas(
 
 
 # ----------------------------------------------------------------------------
-# Fused pipeline: quantize + causal tap-stack + pack + fetch in VMEM
+# Fused pipeline: quantize + causal tap pack + fetch in VMEM
 # ----------------------------------------------------------------------------
 
-
-def _pack_taps(codes, *, bits: int, k: int, Tb: int):
-    """``[Tb+k-1, Cb]`` strip codes -> ``[Tb, Cb]`` packed tap offsets via a
-    static k-slice loop: the little-endian shift-or of
-    ``core.offsets.pack_offsets``, built without the ``[B, T, C, k]`` tap
-    tensor ever existing."""
-    off = codes[0:Tb]
-    for j in range(1, k):
-        off = off + (codes[j:j + Tb] << (j * bits))  # [Tb, Cb] int32
-    return off
+#: table entries per select slab: the fetch walks the transposed table in
+#: ``[min(V, _SLAB), Cb]`` row slabs (static selects within a slab)
+_SLAB = 256
 
 
-def _factored_fetch(off, tab_ref, *, bits: int, k: int, V: int, Tb: int,
-                    Cb: int):
-    """Factored two-level one-hot fetch: ``off [Tb, Cb]`` -> f32 ``[Tb, Cb]``.
+def _fused_kernel(x_ref, scale_ref, tab_ref, out_ref, *rest, bits: int,
+                  zero_point: int, k: int, V: int, B: int):
+    """One ``(Tb*B, Cb)`` output tile from the taps-major ``[k, Tb*B, Cb]``
+    block.
 
-    A flat [Tb, Cb, V] one-hot costs V compares per output and a V-wide
-    intermediate; splitting the offset into hi/lo halves (V = Vh * Vl)
-    exploits ``1[off==v] = 1[off_hi==vh] * 1[off_lo==vl]``: the one-hots
-    shrink to Vl + Vh lanes and the fetch becomes two small per-channel
-    contractions, with the largest intermediate only [Cb, Vh, Tb].  Every
-    product chain still has exactly one nonzero term per output, so f32
-    accumulation returns the table cell bit-exactly (bf16 tables included —
-    same contract as the host-packed kernel's fori_loop).
+    With counters, two ``STAT_BLOCK`` outputs precede the transposed-table
+    scratch in ``rest``.  Tap ``j`` of output ``t`` reads padded input
+    ``t + j``, so adjacent outputs share ``k - 1`` inputs: the count keeps
+    tap ``k - 1`` everywhere and the other taps only on the first time step
+    (rows ``< B`` of time tile 0) — every padded input row counted exactly
+    once, and the zero pads quantize in range.  ``max`` is idempotent; the
+    ratio folds every tap.
     """
-    h = (bits * k) // 2
-    Vl, Vh = 1 << h, V >> h
-    off_t = jnp.transpose(off)  # [Cb, Tb]
-    lanes_l = jax.lax.broadcasted_iota(jnp.int32, (Cb, Tb, Vl), 2)
-    lanes_h = jax.lax.broadcasted_iota(jnp.int32, (Cb, Tb, Vh), 2)
-    ohl = ((off_t & (Vl - 1))[:, :, None] == lanes_l).astype(jnp.float32)
-    ohh = ((off_t >> h)[:, :, None] == lanes_h).astype(jnp.float32)
-    tab3 = tab_ref[...].astype(jnp.float32).reshape(Cb, Vh, Vl)
-    # m[c, vh, t] = sum_vl tab3[c, vh, vl] * ohl[c, t, vl]
-    m = jax.lax.dot_general(
-        tab3, ohl, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)  # [Cb, Vh, Tb]
-    acc = jnp.sum(m * jnp.transpose(ohh, (0, 2, 1)), axis=1)  # [Cb, Tb]
-    return jnp.transpose(acc)  # [Tb, Cb]
+    *stat_refs, tabT_ref = rest
+    i, j = pl.program_id(0), pl.program_id(1)
+    scale = scale_ref[...]
+    off = None
+    for t in range(k):
+        xt = x_ref[t]  # [Rb, Cb]
+        q, codes = _quantize_f32(xt, scale, bits=bits, zero_point=zero_point)
+        if stat_refs:
+            rows = jax.lax.broadcasted_iota(jnp.int32, xt.shape, 0)
+            keep = None if t == k - 1 else (i == 0) & (rows < B)
+            _update_stats(*stat_refs, q, xt, scale, bits=bits,
+                          first=(i == 0) & (j == 0) & (t == 0), keep=keep)
+        c = codes.astype(jnp.int32) << (t * bits)
+        off = c if off is None else off + c
+    tabT_ref[...] = jnp.transpose(tab_ref[...].astype(jnp.float32))
+    slab = min(V, _SLAB)
 
+    def fetch(h, acc):
+        base = pl.multiple_of(h * slab, slab)
+        rows = tabT_ref[pl.ds(base, slab), :]  # [slab, Cb]
+        for v in range(slab):
+            acc = jnp.where(off == base + v, rows[v:v + 1, :], acc)
+        return acc
 
-def _fused_kernel(x_ref, scale_ref, tab_ref, out_ref, *,
-                  bits: int, zero_point: int, k: int, V: int, Tb: int):
-    _, _, Cb = x_ref.shape
-    # Quantize this time tile's strip (Tb outputs need Tb + k - 1 padded
-    # inputs — the caller left-pads the raw signal, so tap j of output t is
-    # padded row t + j) and tap-stack/pack in VMEM.
-    t0 = pl.program_id(1) * Tb
-    strip = x_ref[0, pl.ds(t0, Tb + k - 1), :]  # [Tb+k-1, Cb] from VMEM
-    codes = _quantize(strip, scale_ref[0, 0], bits=bits, zero_point=zero_point)
-    off = _pack_taps(codes, bits=bits, k=k, Tb=Tb)
-    acc = _factored_fetch(off, tab_ref, bits=bits, k=k, V=V, Tb=Tb, Cb=Cb)
-    out_ref[0] = acc.astype(out_ref.dtype)
-
-
-def _fused_sat_kernel(x_ref, scale_ref, tab_ref, out_ref, cnt_ref, ratio_ref,
-                      *, bits: int, zero_point: int, k: int, V: int, Tb: int):
-    """Counter-carrying :func:`_fused_kernel`: two extra ``[1, 1]`` outputs
-    (int32 saturation count, f32 running ``max(|x|)/scale``) reduced across
-    the grid, block-resident via constant index maps.
-
-    Adjacent time tiles overlap by ``k - 1`` strip rows, so the count keeps
-    the overlap rows only on the first time tile — every row of the padded
-    signal is counted exactly once (the caller's zero time/channel pads
-    quantize to the in-range zero_point and contribute nothing, so the
-    total equals the host count over the unpadded signal).  ``max`` is
-    idempotent; the ratio accumulates every step.
-    """
-    _, _, Cb = x_ref.shape
-    b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-
-    @pl.when((b == 0) & (i == 0) & (j == 0))
-    def _zero_stats():
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
-        ratio_ref[...] = jnp.zeros_like(ratio_ref)
-
-    t0 = i * Tb
-    strip = x_ref[0, pl.ds(t0, Tb + k - 1), :]  # [Tb+k-1, Cb] from VMEM
-    q = jnp.round(strip / scale_ref[0, 0]) + zero_point
-    sat = ((q < 0) | (q > (1 << bits) - 1)).astype(jnp.int32)
-    rows = jax.lax.broadcasted_iota(jnp.int32, sat.shape, 0)
-    keep = (rows >= k - 1) | (i == 0)
-    cnt_ref[0, 0] += jnp.sum(jnp.where(keep, sat, 0))
-    ratio_ref[0, 0] = jnp.maximum(
-        ratio_ref[0, 0],
-        (jnp.max(jnp.abs(strip)) / scale_ref[0, 0]).astype(jnp.float32))
-    codes = jnp.clip(q, 0, (1 << bits) - 1).astype(jnp.int32)
-    off = _pack_taps(codes, bits=bits, k=k, Tb=Tb)
-    acc = _factored_fetch(off, tab_ref, bits=bits, k=k, V=V, Tb=Tb, Cb=Cb)
-    out_ref[0] = acc.astype(out_ref.dtype)
+    acc = jax.lax.fori_loop(0, V // slab, fetch,
+                            jnp.zeros(off.shape, jnp.float32))
+    out_ref[...] = acc.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "zero_point", "k",
@@ -204,15 +160,14 @@ def pcilt_fused_dwconv1d_pallas(
     """x ``[B, Tp, C]`` float (already time-padded: ``Tp = To + k - 1``),
     scale ``[1, 1]``, tables ``[C, V]`` (``V = 2**(bits*k)``) -> ``[B, To, C]``.
 
-    The whole padded signal is staged per channel block and revisited across
-    time tiles; each grid step quantizes its strip, packs the k causal taps,
-    and fetches — offsets never exist outside VMEM.  ``tiles`` is a
-    ``(Tb, Cb)`` tuple with ``Tb | To`` and ``Cb | C``.
+    Each grid step quantizes its ``[k, Tb*B, Cb]`` taps-major block, packs
+    the ``k`` causal taps, and fetches — offsets never exist outside VMEM.
+    ``tiles`` is a ``(Tb, Cb)`` tuple with ``Tb | To`` and ``Cb | C``.
 
     ``counters=True`` (a static opt-in: the default trace is unchanged)
     returns ``(out, count, ratio)`` — the int32 number of signal elements
     the quantizer clipped and the f32 ``max(|x|)/scale`` overshoot, reduced
-    in VMEM by :func:`_fused_sat_kernel`.
+    in VMEM by the same kernel.
     """
     B, Tp, C = x.shape
     C2, V = tables.shape
@@ -222,38 +177,25 @@ def pcilt_fused_dwconv1d_pallas(
             f"(x {x.shape}, tables {tables.shape})")
     To = Tp - k + 1
     Tb, Cb = tiles
-    grid = (B, To // Tb, C // Cb)
-    in_specs = [
-        pl.BlockSpec((1, Tp, Cb), lambda b, i, j: (b, 0, j)),
-        pl.BlockSpec((1, 1), lambda b, i, j: (0, 0)),
-        pl.BlockSpec((Cb, V), lambda b, i, j: (j, 0)),
-    ]
-    out_spec = pl.BlockSpec((1, Tb, Cb), lambda b, i, j: (b, i, j))
-    if counters:
-        out, cnt, ratio = pl.pallas_call(
-            functools.partial(_fused_sat_kernel, bits=bits,
-                              zero_point=zero_point, k=k, V=V, Tb=Tb),
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=(
-                out_spec,
-                pl.BlockSpec((1, 1), lambda b, i, j: (0, 0)),
-                pl.BlockSpec((1, 1), lambda b, i, j: (0, 0)),
-            ),
-            out_shape=(
-                jax.ShapeDtypeStruct((B, To, C), tables.dtype),
-                jax.ShapeDtypeStruct((1, 1), jnp.int32),
-                jax.ShapeDtypeStruct((1, 1), jnp.float32),
-            ),
-            interpret=interpret,
-        )(x, scale, tables)
-        return out, cnt[0, 0], ratio[0, 0]
-    return pl.pallas_call(
+    taps = jnp.stack([x[:, j:j + To] for j in range(k)])  # [k, B, To, C]
+    taps = jnp.transpose(taps, (0, 2, 1, 3)).reshape(k, To * B, C)
+    out = _call_with_stats(
         functools.partial(_fused_kernel, bits=bits, zero_point=zero_point,
-                          k=k, V=V, Tb=Tb),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((B, To, C), tables.dtype),
-        interpret=interpret,
-    )(x, scale, tables)
+                          k=k, V=V, B=B),
+        counters, interpret, tables.dtype,
+        grid=(To // Tb, C // Cb),
+        in_specs=[
+            pl.BlockSpec((k, Tb * B, Cb), lambda i, j: (0, i, j)),
+            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
+            pl.BlockSpec((Cb, V), lambda i, j: (j, 0)),
+        ],
+        out_spec=pl.BlockSpec((Tb * B, Cb), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((To * B, C), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((V, Cb), jnp.float32)],
+        name="pcilt_dwconv1d",
+    )(taps, scale, tables)
+    rest = ()
+    if counters:
+        out, *rest = out
+    out = jnp.transpose(out.reshape(To, B, C), (1, 0, 2))
+    return (out, *rest) if counters else out

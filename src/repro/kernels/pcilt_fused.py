@@ -11,13 +11,19 @@ over the *raw float activations*, so the offsets live only in VMEM/registers:
 * **quantize** — ``clip(round(x / scale) + zero_point, 0, K-1)``, bit-exact
   with ``core.quantization.quantize`` (same round-half-even, same clip);
 * **pack** — little-endian shift-or of ``group`` codes per segment, bit-exact
-  with ``core.offsets.pack_offsets``;
+  with ``core.offsets.pack_offsets``, computed as one exact integer
+  contraction against a constant pack matrix (``pack_matrix``) that also
+  repeats each offset across its segment's ``V`` one-hot lanes;
 * **fetch + adder tree** — one *flattened* one-hot contraction per staged
   table tile: instead of a ``fori_loop`` of ``Gb`` small ``[Bb,V] x [V,Ob]``
-  dots, the one-hot is laid out as ``[Bb, Gb*V]`` (segment-major) and the
-  staged tables reshaped to ``[Gb*V, Ob]``, so the MXU runs a single large
-  contraction per grid step.  The adder tree over group tiles is grid
-  accumulation on the revisited output block.
+  dots, the one-hot is laid out as ``[Bb, Gb*V]`` (segment-major) against
+  the ``[Gb*V, Ob]`` tile of the free ``[G*V, O]`` table view, so the MXU
+  runs large contractions per grid step.  The adder tree over group tiles
+  is grid accumulation on the revisited output block.
+
+Every step is a 2-D matmul, compare or select: the chip's compiler refuses
+lane-splitting reshapes and scalar stores to VMEM, so the kernels have
+neither (saturation counters are lane-dense vector tiles).
 
 Tables may be stored **bf16** (pass ``tables.astype(jnp.bfloat16)``): the
 one-hot is built in the table dtype, the contraction *and* the cross-tile
@@ -47,29 +53,59 @@ __all__ = ["pcilt_fused_gemv_pallas", "pcilt_fused_gemv_stacked_pallas",
            "pcilt_fused_conv2d_pallas"]
 
 
-def _quantize(x, scale, *, bits: int, zero_point: int):
-    """In-kernel mirror of ``core.quantization.quantize`` (-> int32 codes)."""
+def _quantize_f32(x, scale, *, bits: int, zero_point: int):
+    """In-kernel mirror of ``core.quantization.quantize``: ``(q, codes)``,
+    the pre-clip code ``round(x/scale) + zero_point`` and the clipped code,
+    both f32 (small integers, exact — same round-half-even, same clip) so
+    the codes can feed the MXU pack."""
     q = jnp.round(x / scale) + zero_point
-    return jnp.clip(q, 0, (1 << bits) - 1).astype(jnp.int32)
+    return q, jnp.clip(q, 0, (1 << bits) - 1)
 
 
-def _quantize_sat(x, scale, *, bits: int, zero_point: int):
-    """:func:`_quantize` plus the block's saturation stats the clip discards.
+#: Counter outputs are one lane-dense ``(8, 128)`` tile each: every element
+#: holds the same running value (the caller reads ``[0, 0]``).  Vector
+#: stores only — Mosaic cannot store a scalar to VMEM.
+STAT_BLOCK = (8, 128)
 
-    In-kernel mirror of ``core.quantization.quantize_with_stats``: returns
-    ``(codes, count, ratio)`` where ``count`` is the int32 number of elements
-    whose *pre-clip* code ``round(x/scale) + zero_point`` fell outside
-    ``[0, K)`` and ``ratio`` is f32 ``max(|x|)/scale``.  Same arithmetic,
-    same dtype, so the count is exact (elements landing on the clip edge are
-    in range) — this is the calibration-drift signal the serving sentinel
-    reduces in VMEM alongside the adder tree.
-    """
-    q = jnp.round(x / scale) + zero_point
-    sat = (q < 0) | (q > (1 << bits) - 1)
-    codes = jnp.clip(q, 0, (1 << bits) - 1).astype(jnp.int32)
-    count = jnp.sum(sat.astype(jnp.int32))
-    ratio = (jnp.max(jnp.abs(x)) / scale).astype(jnp.float32)
-    return codes, count, ratio
+
+def _stat_outputs():
+    """``(out_specs, out_shapes)`` of the (count, ratio) counter outputs,
+    resident across the whole grid (constant index map)."""
+    const = (lambda *_: (0, 0))
+    return ((pl.BlockSpec(STAT_BLOCK, const), pl.BlockSpec(STAT_BLOCK, const)),
+            (jax.ShapeDtypeStruct(STAT_BLOCK, jnp.int32),
+             jax.ShapeDtypeStruct(STAT_BLOCK, jnp.float32)))
+
+
+def _update_stats(cnt_ref, ratio_ref, q, x, scale, *, bits: int, first,
+                  count=True, keep=None):
+    """Fold one block's saturation stats into the counter outputs.
+
+    ``q`` is the pre-clip code: an element saturates when it leaves
+    ``[0, K)`` (elements landing on the clip edge are in range, so the count
+    is exact).  ``first`` (traced bool) zeroes the counters; ``count``
+    (traced bool) gates the count — a block revisited once per output tile
+    must be counted once — and ``keep`` optionally masks elements out of
+    it.  The ratio is ``max(|x|)/scale``; ``max`` is idempotent, so it folds
+    every step."""
+    @pl.when(first)
+    def _zero():
+        cnt_ref[...] = jnp.zeros_like(cnt_ref)
+        ratio_ref[...] = jnp.zeros_like(ratio_ref)
+
+    sat = ((q < 0) | (q > (1 << bits) - 1)).astype(jnp.int32)
+    if keep is not None:
+        sat = jnp.where(keep, sat, 0)
+    c = jnp.sum(jnp.sum(sat, axis=1, keepdims=True), axis=0, keepdims=True)
+
+    @pl.when(count)
+    def _count():
+        cnt_ref[...] += jnp.broadcast_to(c, cnt_ref.shape)
+
+    r = jnp.max(jnp.max(jnp.abs(x), axis=1, keepdims=True), axis=0,
+                keepdims=True) / scale
+    ratio_ref[...] = jnp.maximum(ratio_ref[...],
+                                 jnp.broadcast_to(r, ratio_ref.shape))
 
 
 def _pack_flat(codes, *, bits: int, group: int, Gseg: int):
@@ -80,17 +116,67 @@ def _pack_flat(codes, *, bits: int, group: int, Gseg: int):
     return jnp.sum(jnp.left_shift(c, shifts), axis=-1)  # [R, Gseg]
 
 
-def _flat_onehot_dot(off, tab, *, V: int):
-    """The flattened fetch: ``off [R, Gb]``, ``tab [Gb, V, Ob]`` -> f32 ``[R, Ob]``.
+def pack_chunk(Gb: int, group: int, V: int, budget: int = 1 << 20) -> int:
+    """Segments per pack chunk of the one-hot fetch (:func:`_onehot_fetch`).
 
-    ``onehot[r, g*V + v] = (off[r, g] == v)`` — one ``[R, Gb*V] x [Gb*V, Ob]``
-    MXU contraction replaces the per-group loop of small dots.
-    """
-    R, Gb = off.shape
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (R, Gb, V), 2)
-    oh = (off[:, :, None] == lanes).astype(tab.dtype).reshape(R, Gb * V)
-    return jnp.dot(oh, tab.reshape(Gb * V, tab.shape[-1]),
-                   preferred_element_type=jnp.float32)
+    The pack matrix of a chunk of ``Gs`` segments is ``[Gs*group, Gs*V]``
+    f32, resident in VMEM.  A chunk's code columns must be whole 128-lane
+    tiles (``Gs*group % 128 == 0``) or the whole tile, so the chip slices
+    them without relayout: the largest such divisor of ``Gb`` whose matrix
+    fits ``budget`` wins, else the smallest such divisor."""
+    aligned = [d for d in range(Gb, 0, -1)
+               if Gb % d == 0 and (d == Gb or (d * group) % 128 == 0)]
+    fits = [d for d in aligned if d * group * d * V * 4 <= budget]
+    return fits[0] if fits else aligned[-1]
+
+
+def pack_matrix(Gs: int, group: int, bits: int, V: int) -> jax.Array:
+    """``[Gs*group, Gs*V]`` f32: column ``g*V + v`` of ``codes @ M`` is the
+    packed little-endian offset of segment ``g`` (row ``g*group + j``
+    carries ``2**(bits*j)`` into segment ``g``'s ``V`` columns) — the
+    offset, repeated across the segment's one-hot lanes."""
+    r = jnp.arange(Gs * group)[:, None]
+    c = jnp.arange(Gs * V)[None, :]
+    w = jnp.left_shift(1, bits * (r % group)).astype(jnp.float32)
+    return jnp.where(c // V == r // group, w, 0.0)
+
+
+def _onehot_fetch(codes, pack_ref, tab_ref, lead=(), *, V: int):
+    """The fused fetch: clipped f32 ``codes [R, Gb*group]`` against the
+    staged ``[Gb*V, Ob]`` table tile (``tab_ref[lead]``) -> f32 ``[R, Ob]``.
+
+    Per chunk of ``Gs`` segments: one exact integer contraction packs the
+    codes into each segment's offset, repeated across its ``V`` one-hot
+    lanes (``codes @ pack_matrix`` — codes and powers of two are exact in
+    any MXU precision), the compare against ``lane % V`` builds the
+    ``[R, Gs*V]`` one-hot, and one MXU contraction fetches and sums.  Two
+    2-D matmuls and a compare: no lane-splitting reshape, which the chip's
+    compiler refuses.  f32 tables contract at full precision, so a fetch
+    returns the table cell exactly; the adder tree over group tiles is grid
+    accumulation on the revisited output block."""
+    R = codes.shape[0]
+    GsV = pack_ref.shape[1]
+    Gsg = pack_ref.shape[0]
+    n_chunks = codes.shape[1] // Gsg
+    lanes = (jax.lax.broadcasted_iota(jnp.int32, (R, GsV), 1) & (V - 1)
+             ).astype(jnp.float32)
+    pack = pack_ref[...]
+    acc = None
+    for s in range(n_chunks):
+        off = jnp.dot(codes[:, s * Gsg:(s + 1) * Gsg], pack,
+                      preferred_element_type=jnp.float32)
+        tab = tab_ref[(*lead, slice(s * GsV, (s + 1) * GsV), slice(None))]
+        oh = (off == lanes).astype(tab.dtype)
+        part = jnp.dot(oh, tab, preferred_element_type=jnp.float32,
+                       precision=_precision(tab.dtype))
+        acc = part if acc is None else acc + part
+    return acc
+
+
+def _precision(dtype):
+    """Full precision for f32 table contractions (TPU's default f32 matmul
+    rounds operands to bf16); bf16 tables are exact at default."""
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
 
 
 def _take_rows(off, tab):
@@ -117,18 +203,22 @@ def _take_rows(off, tab):
 # ----------------------------------------------------------------------------
 
 
-def _gemv_kernel(x_ref, scale_ref, tab_ref, out_ref, *,
-                 bits: int, zero_point: int, group: int, Gb: int, V: int):
+def _gemv_kernel(x_ref, scale_ref, pack_ref, tab_ref, out_ref, *,
+                 bits: int, zero_point: int, V: int):
     @pl.when(pl.program_id(2) == 0)
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    codes = _quantize(x_ref[...], scale_ref[0, 0],
-                      bits=bits, zero_point=zero_point)  # [Bb, Gb*group]
-    off = _pack_flat(codes, bits=bits, group=group, Gseg=Gb)  # [Bb, Gb]
+    _, codes = _quantize_f32(x_ref[...], scale_ref[...], bits=bits,
+                             zero_point=zero_point)  # [Bb, Gb*group]
     # The output block is f32 regardless of table dtype, so the adder tree
     # over G tiles never rounds through bf16 (caller casts once at the end).
-    out_ref[...] += _flat_onehot_dot(off, tab_ref[...], V=V)
+    out_ref[...] += _onehot_fetch(codes, pack_ref, tab_ref, V=V)
+
+
+def _pack_operand(Gb: int, group: int, bits: int, V: int):
+    """The pack matrix operand for a ``Gb``-segment table tile."""
+    return pack_matrix(pack_chunk(Gb, group, V), group, bits, V)
 
 
 @functools.partial(
@@ -158,20 +248,22 @@ def pcilt_fused_gemv_pallas(
             f"x trailing dim {n} != G*group = {G}*{group} "
             f"(x {x.shape}, tables {tables.shape})")
     Bb, Gb, Ob = tiles
+    pack = _pack_operand(Gb, group, bits, V)
     grid = (pl.cdiv(B, Bb), pl.cdiv(O, Ob), G // Gb)
     return pl.pallas_call(
         functools.partial(_gemv_kernel, bits=bits, zero_point=zero_point,
-                          group=group, Gb=Gb, V=V),
+                          V=V),
         grid=grid,
         in_specs=[
             pl.BlockSpec((Bb, Gb * group), lambda i, j, k: (i, k)),
             pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),
-            pl.BlockSpec((Gb, V, Ob), lambda i, j, k: (k, 0, j)),
+            pl.BlockSpec(pack.shape, lambda i, j, k: (0, 0)),
+            pl.BlockSpec((Gb * V, Ob), lambda i, j, k: (k, j)),
         ],
         out_specs=pl.BlockSpec((Bb, Ob), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, O), jnp.float32),
         interpret=interpret,
-    )(x, scale, tables).astype(tables.dtype)
+    )(x, scale, pack, tables.reshape(G * V, O)).astype(tables.dtype)
 
 
 # ----------------------------------------------------------------------------
@@ -179,47 +271,27 @@ def pcilt_fused_gemv_pallas(
 # ----------------------------------------------------------------------------
 
 
-def _gemv_paired_kernel(x_ref, scale_ref, tab_ref, out_ref, *,
+def _gemv_paired_kernel(x_ref, scale_ref, tab_ref, out_ref, *stat_refs,
                         bits: int, zero_point: int, group: int, Gb: int):
-    @pl.when(pl.program_id(2) == 0)
-    def _zero():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    codes = _quantize(x_ref[...], scale_ref[0, 0],
-                      bits=bits, zero_point=zero_point)  # [Bb, Gb*2*group]
-    # Packing 2*group codes little-endian IS the paired index
-    # off_even + off_odd * V (V = 2**(bits*group)) — the same arithmetic
-    # `build_paired_tables` indexes its [G/2, V**2, O] entries by, so the
-    # in-kernel pack emits the paired offset directly.
-    off = _pack_flat(codes, bits=bits, group=2 * group, Gseg=Gb)  # [Bb, Gb]
-    out_ref[...] += _take_rows(off, tab_ref[...])
-
-
-def _gemv_paired_sat_kernel(x_ref, scale_ref, tab_ref,
-                            out_ref, cnt_ref, ratio_ref, *,
-                            bits: int, zero_point: int, group: int, Gb: int):
-    """Counter-carrying :func:`_gemv_paired_kernel` (see
-    :func:`_gemv_stacked_sat_kernel` for the dedup/zeroing discipline)."""
+    """Paired fetch; with ``stat_refs`` (count, ratio) the call also
+    reduces the saturation counters (see :func:`_gemv_stacked_kernel`)."""
     i, j, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-
-    @pl.when((i == 0) & (j == 0) & (k == 0))
-    def _zero_stats():
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
-        ratio_ref[...] = jnp.zeros_like(ratio_ref)
 
     @pl.when(k == 0)
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    codes, cnt, ratio = _quantize_sat(x_ref[...], scale_ref[0, 0],
-                                      bits=bits, zero_point=zero_point)
-
-    @pl.when(j == 0)
-    def _count():
-        cnt_ref[0, 0] += cnt
-
-    ratio_ref[0, 0] = jnp.maximum(ratio_ref[0, 0], ratio)
-    off = _pack_flat(codes, bits=bits, group=2 * group, Gseg=Gb)  # [Bb, Gb]
+    x, scale = x_ref[...], scale_ref[...]
+    q, codes = _quantize_f32(x, scale, bits=bits, zero_point=zero_point)
+    if stat_refs:
+        _update_stats(*stat_refs, q, x, scale, bits=bits,
+                      first=(i == 0) & (j == 0) & (k == 0), count=j == 0)
+    # Packing 2*group codes little-endian IS the paired index
+    # off_even + off_odd * V (V = 2**(bits*group)) — the same arithmetic
+    # `build_paired_tables` indexes its [G/2, V**2, O] entries by, so the
+    # in-kernel pack emits the paired offset directly.
+    off = _pack_flat(codes.astype(jnp.int32), bits=bits, group=2 * group,
+                     Gseg=Gb)  # [Bb, Gb]
     out_ref[...] += _take_rows(off, tab_ref[...])
 
 
@@ -268,41 +340,53 @@ def pcilt_fused_gemv_paired_pallas(
             f"{1 << (2 * bits * group)} (tables {tables.shape}, bits={bits}, "
             f"group={group})")
     Bb, Gb, Ob = tiles
-    grid = (pl.cdiv(B, Bb), pl.cdiv(O, Ob), G2 // Gb)
-    in_specs = [
-        pl.BlockSpec((Bb, Gb * 2 * group), lambda i, j, k: (i, k)),
-        pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),
-        pl.BlockSpec((Gb, V2, Ob), lambda i, j, k: (k, 0, j)),
-    ]
-    out_spec = pl.BlockSpec((Bb, Ob), lambda i, j, k: (i, j))
-    if counters:
-        out, cnt, ratio = pl.pallas_call(
-            functools.partial(_gemv_paired_sat_kernel, bits=bits,
-                              zero_point=zero_point, group=group, Gb=Gb),
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=(
-                out_spec,
-                pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),
-                pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),
-            ),
-            out_shape=(
-                jax.ShapeDtypeStruct((B, O), jnp.float32),
-                jax.ShapeDtypeStruct((1, 1), jnp.int32),
-                jax.ShapeDtypeStruct((1, 1), jnp.float32),
-            ),
-            interpret=interpret,
-        )(x, scale, tables)
-        return out.astype(tables.dtype), cnt[0, 0], ratio[0, 0]
-    return pl.pallas_call(
+    return _call_with_stats(
         functools.partial(_gemv_paired_kernel, bits=bits,
                           zero_point=zero_point, group=group, Gb=Gb),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_spec,
+        counters, interpret, tables.dtype,
+        grid=(pl.cdiv(B, Bb), pl.cdiv(O, Ob), G2 // Gb),
+        in_specs=[
+            pl.BlockSpec((Bb, Gb * 2 * group), lambda i, j, k: (i, k)),
+            pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),
+            pl.BlockSpec((Gb, V2, Ob), lambda i, j, k: (k, 0, j)),
+        ],
+        out_spec=pl.BlockSpec((Bb, Ob), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, O), jnp.float32),
+    )(x, scale, tables)
+
+
+def _call_with_stats(kernel, counters: bool, interpret: bool, out_dtype, *,
+                     grid, in_specs, out_spec, out_shape,
+                     num_scalar_prefetch: int = 0, scratch_shapes=(),
+                     name=None):
+    """``pallas_call`` of a kernel whose trailing (count, ratio) outputs
+    exist only under ``counters``.  The returned callable yields ``out``
+    cast to ``out_dtype`` — or ``(out, count, ratio)`` with the counters
+    read back as scalars."""
+    out_specs, out_shapes = [out_spec], [out_shape]
+    if counters:
+        specs, shapes = _stat_outputs()
+        out_specs += specs
+        out_shapes += shapes
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=num_scalar_prefetch, grid=grid,
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=list(scratch_shapes)),
+        out_shape=out_shapes,
         interpret=interpret,
-    )(x, scale, tables).astype(tables.dtype)
+        name=None if name is None else name + ("_sat" if counters else ""),
+    )
+
+    def run(*args):
+        res = call(*args)
+        out = res[0].astype(out_dtype)
+        if counters:
+            return out, res[1][0, 0], res[2][0, 0]
+        return out
+
+    return run
 
 
 # ----------------------------------------------------------------------------
@@ -311,57 +395,35 @@ def pcilt_fused_gemv_paired_pallas(
 # ----------------------------------------------------------------------------
 
 
-def _gemv_stacked_kernel(layer_ref, x_ref, scale_ref, tab_ref, out_ref, *,
-                         bits: int, zero_point: int, group: int,
-                         Gb: int, V: int):
-    @pl.when(pl.program_id(2) == 0)
-    def _zero():
-        out_ref[...] = jnp.zeros_like(out_ref)
+def _gemv_stacked_kernel(layer_ref, x_ref, scale_ref, pack_ref, tab_ref,
+                         out_ref, *stat_refs, bits: int, zero_point: int,
+                         V: int):
+    """One ``(Bb, Ob)`` output tile of the layer-stacked fetch.
 
-    codes = _quantize(x_ref[...], scale_ref[0, 0],
-                      bits=bits, zero_point=zero_point)  # [Bb, Gb*group]
-    off = _pack_flat(codes, bits=bits, group=group, Gseg=Gb)  # [Bb, Gb]
-    # tab_ref's block is the current layer's [1, Gb, V, Ob] slice — the
-    # scalar-prefetched layer index already selected it in the index map,
-    # so the kernel body is the plain fused fetch.
-    out_ref[...] += _flat_onehot_dot(off, tab_ref[0], V=V)
+    ``tab_ref``'s block is the current layer's ``[1, Gb*V, Ob]`` slice — the
+    scalar-prefetched layer index already selected it in the index map, so
+    the body is the plain fused fetch.
 
-
-def _gemv_stacked_sat_kernel(layer_ref, x_ref, scale_ref, tab_ref,
-                             out_ref, cnt_ref, ratio_ref, *,
-                             bits: int, zero_point: int, group: int,
-                             Gb: int, V: int):
-    """The counter-carrying variant of :func:`_gemv_stacked_kernel`.
-
-    Two extra ``[1, 1]`` outputs ride the call, block-resident across the
-    whole grid (constant index maps): the int32 saturation count and the f32
-    running ``max(|x|)/scale`` ratio.  The x block at ``(i, k)`` is revisited
-    once per output tile ``j``, so the count accumulates only on ``j == 0``
-    — every activation element counted exactly once; ``max`` is idempotent,
-    so the ratio accumulates on every step.  Zero-padded rows (the batch
-    pad) quantize to the in-range zero_point and contribute nothing.
+    With ``stat_refs`` (the counters variant), two ``STAT_BLOCK`` outputs
+    ride the call, block-resident across the whole grid: the int32
+    saturation count and the f32 running ``max(|x|)/scale`` ratio.  The x
+    block at ``(i, k)`` is revisited once per output tile ``j``, so the
+    count accumulates only on ``j == 0`` — every activation element counted
+    exactly once.  Zero-padded rows (the batch pad) quantize to the
+    in-range zero_point and contribute nothing.
     """
     i, j, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-
-    @pl.when((i == 0) & (j == 0) & (k == 0))
-    def _zero_stats():
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
-        ratio_ref[...] = jnp.zeros_like(ratio_ref)
 
     @pl.when(k == 0)
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    codes, cnt, ratio = _quantize_sat(x_ref[...], scale_ref[0, 0],
-                                      bits=bits, zero_point=zero_point)
-
-    @pl.when(j == 0)
-    def _count():
-        cnt_ref[0, 0] += cnt
-
-    ratio_ref[0, 0] = jnp.maximum(ratio_ref[0, 0], ratio)
-    off = _pack_flat(codes, bits=bits, group=group, Gseg=Gb)  # [Bb, Gb]
-    out_ref[...] += _flat_onehot_dot(off, tab_ref[0], V=V)
+    x, scale = x_ref[...], scale_ref[...]
+    q, codes = _quantize_f32(x, scale, bits=bits, zero_point=zero_point)
+    if stat_refs:
+        _update_stats(*stat_refs, q, x, scale, bits=bits,
+                      first=(i == 0) & (j == 0) & (k == 0), count=j == 0)
+    out_ref[...] += _onehot_fetch(codes, pack_ref, tab_ref, (0,), V=V)
 
 
 @functools.partial(
@@ -389,17 +451,16 @@ def pcilt_fused_gemv_stacked_pallas(
     the per-layer tables of a whole network stack live in one ``[L, G, V, O]``
     array that never moves, and the (traced) ``layer`` operand is
     **scalar-prefetched** so the BlockSpec index map stages exactly that
-    layer's ``[1, Gb, V, Ob]`` tiles — per grid step the staged bytes equal
-    the unstacked kernel's, and the ``lax.scan`` over layers never pays the
-    HBM copy a per-iteration ``dynamic_slice`` of the stacked tables would
-    materialize.  ``n == G * group``; ``tiles`` is ``(Bb, Gb, Ob)`` with
-    ``Gb | G``.
+    layer's ``[1, Gb*V, Ob]`` tiles (of the free ``[L, G*V, O]`` view) — per
+    grid step the staged bytes equal the unstacked kernel's, and the
+    ``lax.scan`` over layers never pays the HBM copy a per-iteration
+    ``dynamic_slice`` of the stacked tables would materialize.
+    ``n == G * group``; ``tiles`` is ``(Bb, Gb, Ob)`` with ``Gb | G``.
 
-    With ``counters=True`` (a static opt-in: the default trace is
-    byte-identical to before the counters existed) the call returns
-    ``(out, count, ratio)`` — the int32 number of activations the quantizer
-    clipped and the f32 ``max(|x|)/scale`` overshoot, reduced in VMEM by
-    :func:`_gemv_stacked_sat_kernel`.
+    With ``counters=True`` (a static opt-in: the default trace carries no
+    counter outputs) the call returns ``(out, count, ratio)`` — the int32
+    number of activations the quantizer clipped and the f32
+    ``max(|x|)/scale`` overshoot, reduced in VMEM by the same kernel.
     """
     B, n = x.shape
     L, G, V, O = tables.shape
@@ -408,49 +469,23 @@ def pcilt_fused_gemv_stacked_pallas(
             f"x trailing dim {n} != G*group = {G}*{group} "
             f"(x {x.shape}, stacked tables {tables.shape})")
     Bb, Gb, Ob = tiles
-    grid = (pl.cdiv(B, Bb), pl.cdiv(O, Ob), G // Gb)
-    in_specs = [
-        pl.BlockSpec((Bb, Gb * group), lambda i, j, k, l: (i, k)),
-        pl.BlockSpec((1, 1), lambda i, j, k, l: (0, 0)),
-        pl.BlockSpec((1, Gb, V, Ob), lambda i, j, k, l: (l[0], k, 0, j)),
-    ]
-    out_spec = pl.BlockSpec((Bb, Ob), lambda i, j, k, l: (i, j))
-    if counters:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=(
-                out_spec,
-                pl.BlockSpec((1, 1), lambda i, j, k, l: (0, 0)),
-                pl.BlockSpec((1, 1), lambda i, j, k, l: (0, 0)),
-            ),
-        )
-        out, cnt, ratio = pl.pallas_call(
-            functools.partial(_gemv_stacked_sat_kernel, bits=bits,
-                              zero_point=zero_point, group=group, Gb=Gb, V=V),
-            grid_spec=grid_spec,
-            out_shape=(
-                jax.ShapeDtypeStruct((B, O), jnp.float32),
-                jax.ShapeDtypeStruct((1, 1), jnp.int32),
-                jax.ShapeDtypeStruct((1, 1), jnp.float32),
-            ),
-            interpret=interpret,
-        )(layer, x, scale, tables)
-        return out.astype(tables.dtype), cnt[0, 0], ratio[0, 0]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_spec,
-    )
-    return pl.pallas_call(
+    pack = _pack_operand(Gb, group, bits, V)
+    return _call_with_stats(
         functools.partial(_gemv_stacked_kernel, bits=bits,
-                          zero_point=zero_point, group=group, Gb=Gb, V=V),
-        grid_spec=grid_spec,
+                          zero_point=zero_point, V=V),
+        counters, interpret, tables.dtype,
+        grid=(pl.cdiv(B, Bb), pl.cdiv(O, Ob), G // Gb),
+        in_specs=[
+            pl.BlockSpec((Bb, Gb * group), lambda i, j, k, l: (i, k)),
+            pl.BlockSpec((1, 1), lambda i, j, k, l: (0, 0)),
+            pl.BlockSpec(pack.shape, lambda i, j, k, l: (0, 0)),
+            pl.BlockSpec((1, Gb * V, Ob), lambda i, j, k, l: (l[0], k, j)),
+        ],
+        out_spec=pl.BlockSpec((Bb, Ob), lambda i, j, k, l: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, O), jnp.float32),
-        interpret=interpret,
-    )(layer, x, scale, tables).astype(tables.dtype)
+        num_scalar_prefetch=1,
+        name="pcilt_stacked_gemv",
+    )(layer, x, scale, pack, tables.reshape(L, G * V, O))
 
 
 # ----------------------------------------------------------------------------
@@ -460,51 +495,27 @@ def pcilt_fused_gemv_stacked_pallas(
 
 
 def _gemv_paired_stacked_kernel(layer_ref, x_ref, scale_ref, tab_ref,
-                                out_ref, *, bits: int, zero_point: int,
-                                group: int, Gb: int, V2: int):
-    @pl.when(pl.program_id(2) == 0)
-    def _zero():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    codes = _quantize(x_ref[...], scale_ref[0, 0],
-                      bits=bits, zero_point=zero_point)  # [Bb, Gb*2*group]
-    off = _pack_flat(codes, bits=bits, group=2 * group, Gseg=Gb)  # [Bb, Gb]
-    # The staged block is [Gb, L, V2, Ob] with a *constant* layer index in
-    # the BlockSpec map; folding L into the value axis keeps the segment
-    # index of the gather a constant iota (the batched-row-gather fast path)
-    # and moves the traced layer into the gathered *row* — the layout that
-    # makes the traced layer free instead of forcing a general gather.
-    Gb_, L, _, Ob = tab_ref.shape
-    tab = tab_ref[...].reshape(Gb_, L * V2, Ob)
-    out_ref[...] += _take_rows(off + layer_ref[0] * V2, tab)
-
-
-def _gemv_paired_stacked_sat_kernel(layer_ref, x_ref, scale_ref, tab_ref,
-                                    out_ref, cnt_ref, ratio_ref, *,
-                                    bits: int, zero_point: int,
-                                    group: int, Gb: int, V2: int):
-    """Counter-carrying :func:`_gemv_paired_stacked_kernel` (see
-    :func:`_gemv_stacked_sat_kernel` for the dedup/zeroing discipline)."""
+                                out_ref, *stat_refs, bits: int,
+                                zero_point: int, group: int, Gb: int,
+                                V2: int):
     i, j, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-
-    @pl.when((i == 0) & (j == 0) & (k == 0))
-    def _zero_stats():
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
-        ratio_ref[...] = jnp.zeros_like(ratio_ref)
 
     @pl.when(k == 0)
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    codes, cnt, ratio = _quantize_sat(x_ref[...], scale_ref[0, 0],
-                                      bits=bits, zero_point=zero_point)
-
-    @pl.when(j == 0)
-    def _count():
-        cnt_ref[0, 0] += cnt
-
-    ratio_ref[0, 0] = jnp.maximum(ratio_ref[0, 0], ratio)
-    off = _pack_flat(codes, bits=bits, group=2 * group, Gseg=Gb)  # [Bb, Gb]
+    x, scale = x_ref[...], scale_ref[...]
+    q, codes = _quantize_f32(x, scale, bits=bits, zero_point=zero_point)
+    if stat_refs:
+        _update_stats(*stat_refs, q, x, scale, bits=bits,
+                      first=(i == 0) & (j == 0) & (k == 0), count=j == 0)
+    off = _pack_flat(codes.astype(jnp.int32), bits=bits, group=2 * group,
+                     Gseg=Gb)  # [Bb, Gb]
+    # The staged block is [Gb, L, V2, Ob] with a *constant* layer index in
+    # the BlockSpec map; folding L into the value axis keeps the segment
+    # index of the gather a constant iota (the batched-row-gather fast path)
+    # and moves the traced layer into the gathered *row* — the layout that
+    # makes the traced layer free instead of forcing a general gather.
     Gb_, L, _, Ob = tab_ref.shape
     tab = tab_ref[...].reshape(Gb_, L * V2, Ob)
     out_ref[...] += _take_rows(off + layer_ref[0] * V2, tab)
@@ -558,50 +569,20 @@ def pcilt_fused_gemv_paired_stacked_pallas(
             f"{1 << (2 * bits * group)} (tables {tables.shape}, bits={bits}, "
             f"group={group})")
     Bb, Gb, Ob = tiles
-    grid = (pl.cdiv(B, Bb), pl.cdiv(O, Ob), G2 // Gb)
-    in_specs = [
-        pl.BlockSpec((Bb, Gb * 2 * group), lambda i, j, k, l: (i, k)),
-        pl.BlockSpec((1, 1), lambda i, j, k, l: (0, 0)),
-        pl.BlockSpec((Gb, L, V2, Ob), lambda i, j, k, l: (k, 0, 0, j)),
-    ]
-    out_spec = pl.BlockSpec((Bb, Ob), lambda i, j, k, l: (i, j))
-    if counters:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=(
-                out_spec,
-                pl.BlockSpec((1, 1), lambda i, j, k, l: (0, 0)),
-                pl.BlockSpec((1, 1), lambda i, j, k, l: (0, 0)),
-            ),
-        )
-        out, cnt, ratio = pl.pallas_call(
-            functools.partial(_gemv_paired_stacked_sat_kernel, bits=bits,
-                              zero_point=zero_point, group=group, Gb=Gb,
-                              V2=V2),
-            grid_spec=grid_spec,
-            out_shape=(
-                jax.ShapeDtypeStruct((B, O), jnp.float32),
-                jax.ShapeDtypeStruct((1, 1), jnp.int32),
-                jax.ShapeDtypeStruct((1, 1), jnp.float32),
-            ),
-            interpret=interpret,
-        )(layer, x, scale, tables)
-        return out.astype(tables.dtype), cnt[0, 0], ratio[0, 0]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_spec,
-    )
-    return pl.pallas_call(
+    return _call_with_stats(
         functools.partial(_gemv_paired_stacked_kernel, bits=bits,
                           zero_point=zero_point, group=group, Gb=Gb, V2=V2),
-        grid_spec=grid_spec,
+        counters, interpret, tables.dtype,
+        grid=(pl.cdiv(B, Bb), pl.cdiv(O, Ob), G2 // Gb),
+        in_specs=[
+            pl.BlockSpec((Bb, Gb * 2 * group), lambda i, j, k, l: (i, k)),
+            pl.BlockSpec((1, 1), lambda i, j, k, l: (0, 0)),
+            pl.BlockSpec((Gb, L, V2, Ob), lambda i, j, k, l: (k, 0, 0, j)),
+        ],
+        out_spec=pl.BlockSpec((Bb, Ob), lambda i, j, k, l: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, O), jnp.float32),
-        interpret=interpret,
-    )(layer, x, scale, tables).astype(tables.dtype)
+        num_scalar_prefetch=1,
+    )(layer, x, scale, tables)
 
 
 # ----------------------------------------------------------------------------
@@ -610,8 +591,8 @@ def pcilt_fused_gemv_paired_stacked_pallas(
 # ----------------------------------------------------------------------------
 
 
-def _gemv_plan_kernel(x_ref, scale_ref, plan_ref, tab_ref, out_ref, *,
-                      bits: int, zero_point: int, group: int,
+def _gemv_plan_kernel(x_ref, scale_ref, plan_ref, pack_ref, tab_ref, out_ref,
+                      *, bits: int, zero_point: int, group: int,
                       Gb: int, V: int):
     @pl.when(pl.program_id(2) == 0)
     def _zero():
@@ -624,9 +605,9 @@ def _gemv_plan_kernel(x_ref, scale_ref, plan_ref, tab_ref, out_ref, *,
     # forcing x=0 keeps the packed offset deterministic.
     xg = jnp.take(x_ref[...], jnp.maximum(pidx, 0), axis=1)  # [Bb, Gb*group]
     xg = jnp.where((pidx < 0)[None, :], jnp.zeros_like(xg), xg)
-    codes = _quantize(xg, scale_ref[0, 0], bits=bits, zero_point=zero_point)
-    off = _pack_flat(codes, bits=bits, group=group, Gseg=Gb)  # [Bb, Gb]
-    out_ref[...] += _flat_onehot_dot(off, tab_ref[...], V=V)
+    _, codes = _quantize_f32(xg, scale_ref[...], bits=bits,
+                             zero_point=zero_point)
+    out_ref[...] += _onehot_fetch(codes, pack_ref, tab_ref, V=V)
 
 
 @functools.partial(
@@ -663,6 +644,7 @@ def pcilt_fused_gemv_plan_pallas(
             f"plan_idx shape {plan_idx.shape} != (G, group) = "
             f"({G}, {group}) (tables {tables.shape})")
     Bb, Gb, Ob = tiles
+    pack = _pack_operand(Gb, group, bits, V)
     grid = (pl.cdiv(B, Bb), pl.cdiv(O, Ob), G // Gb)
     return pl.pallas_call(
         functools.partial(_gemv_plan_kernel, bits=bits,
@@ -672,12 +654,13 @@ def pcilt_fused_gemv_plan_pallas(
             pl.BlockSpec((Bb, n), lambda i, j, k: (i, 0)),
             pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),
             pl.BlockSpec((Gb, group), lambda i, j, k: (k, 0)),
-            pl.BlockSpec((Gb, V, Ob), lambda i, j, k: (k, 0, j)),
+            pl.BlockSpec(pack.shape, lambda i, j, k: (0, 0)),
+            pl.BlockSpec((Gb * V, Ob), lambda i, j, k: (k, j)),
         ],
         out_specs=pl.BlockSpec((Bb, Ob), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, O), jnp.float32),
         interpret=interpret,
-    )(x, scale, plan_idx, tables).astype(tables.dtype)
+    )(x, scale, plan_idx, pack, tables.reshape(G * V, O)).astype(tables.dtype)
 
 
 # ----------------------------------------------------------------------------
@@ -685,11 +668,11 @@ def pcilt_fused_gemv_plan_pallas(
 # ----------------------------------------------------------------------------
 
 
-def _strip_offsets(x_ref, scale_ref, seg_ref, *, bits: int, zero_point: int,
-                   group: int, kh: int, kw: int, stride: int,
-                   Gb: int, Hb: int, n_pad: int):
-    """Quantize this grid step's row strip, im2col it in VMEM, slice the
-    current group range, and pack offsets -> ``[Hb*Wo, Gb]``.
+def _strip_codes(x_ref, scale_ref, seg_ref, *, bits: int, zero_point: int,
+                 group: int, kh: int, kw: int, stride: int,
+                 Gb: int, Hb: int, n_pad: int):
+    """Quantize this grid step's row strip, im2col it in VMEM, and slice the
+    current group range -> f32 codes ``[Hb*Wo, Gb*group]``.
 
     ``seg_ref`` holds the segment offset of this device's table shard in the
     *global* segment space (``[1, 1]`` int32, 0 when unsharded): under
@@ -707,7 +690,8 @@ def _strip_offsets(x_ref, scale_ref, seg_ref, *, bits: int, zero_point: int,
     strip_h = (Hb - 1) * stride + kh
     row0 = pl.program_id(1) * (Hb * stride)
     strip = x_ref[0, pl.ds(row0, strip_h), :, :]  # [strip_h, Wp, C] from VMEM
-    codes = _quantize(strip, scale_ref[0, 0], bits=bits, zero_point=zero_point)
+    _, codes = _quantize_f32(strip, scale_ref[...].reshape(1, 1, 1),
+                             bits=bits, zero_point=zero_point)
 
     # In-VMEM im2col over the strip: static kh*kw slice loop (matches the
     # [kh, kw, C] patch flattening of core.lut_layers.im2col).  The full
@@ -728,11 +712,10 @@ def _strip_offsets(x_ref, scale_ref, seg_ref, *, bits: int, zero_point: int,
     # This grid step's group range in global segment space:
     # [seg0 + k*Gb, seg0 + (k+1)*Gb) — seg0 is the shard's segment offset.
     col0 = (seg_ref[0, 0] + pl.program_id(3) * Gb) * group
-    seg = jax.lax.dynamic_slice(patch, (0, col0), (Hb * Wo, Gb * group))
-    return _pack_flat(seg, bits=bits, group=group, Gseg=Gb)  # [Hb*Wo, Gb]
+    return jax.lax.dynamic_slice(patch, (0, col0), (Hb * Wo, Gb * group))
 
 
-def _conv_kernel(x_ref, scale_ref, seg_ref, tab_ref, out_ref, *,
+def _conv_kernel(x_ref, scale_ref, seg_ref, pack_ref, tab_ref, out_ref, *,
                  bits: int, zero_point: int, group: int,
                  kh: int, kw: int, stride: int,
                  Gb: int, V: int, Hb: int, n_pad: int):
@@ -740,11 +723,11 @@ def _conv_kernel(x_ref, scale_ref, seg_ref, tab_ref, out_ref, *,
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    off = _strip_offsets(x_ref, scale_ref, seg_ref,
+    codes = _strip_codes(x_ref, scale_ref, seg_ref,
                          bits=bits, zero_point=zero_point,
                          group=group, kh=kh, kw=kw, stride=stride,
                          Gb=Gb, Hb=Hb, n_pad=n_pad)
-    acc = _flat_onehot_dot(off, tab_ref[...], V=V)  # [Hb*Wo, Ob] f32
+    acc = _onehot_fetch(codes, pack_ref, tab_ref, V=V)  # [Hb*Wo, Ob] f32
     out_ref[...] += acc.reshape(out_ref.shape)
 
 
@@ -799,6 +782,7 @@ def pcilt_fused_conv2d_pallas(
     Ho = (Hp - kh) // stride + 1
     Wo = (Wp - kw) // stride + 1
     Hb, Gb, Ob = tiles
+    pack = _pack_operand(Gb, group, bits, V)
     grid = (B, Ho // Hb, pl.cdiv(O, Ob), G // Gb)
     return pl.pallas_call(
         functools.partial(_conv_kernel, bits=bits, zero_point=zero_point,
@@ -809,9 +793,11 @@ def pcilt_fused_conv2d_pallas(
             pl.BlockSpec((1, Hp, Wp, C), lambda b, r, j, k: (b, 0, 0, 0)),
             pl.BlockSpec((1, 1), lambda b, r, j, k: (0, 0)),
             pl.BlockSpec((1, 1), lambda b, r, j, k: (0, 0)),
-            pl.BlockSpec((Gb, V, Ob), lambda b, r, j, k: (k, 0, j)),
+            pl.BlockSpec(pack.shape, lambda b, r, j, k: (0, 0)),
+            pl.BlockSpec((Gb * V, Ob), lambda b, r, j, k: (k, j)),
         ],
         out_specs=pl.BlockSpec((1, Hb, Wo, Ob), lambda b, r, j, k: (b, r, 0, j)),
         out_shape=jax.ShapeDtypeStruct((B, Ho, Wo, O), jnp.float32),
         interpret=interpret,
-    )(x, scale, seg_offset, tables).astype(tables.dtype)
+    )(x, scale, seg_offset, pack, tables.reshape(G * V, O)).astype(
+        tables.dtype)

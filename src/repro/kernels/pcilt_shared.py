@@ -18,26 +18,25 @@ The kernels here stage **the pool and the pointers, never the dense tables**:
   activation one-hot into **pool space**: every segment pointing at pool row
   ``x`` with offset ``v`` fetches the *same* table cell, so the fetch-and-add
   over this grid step's ``Gb`` segments collapses to a multiplicity count
-  followed by one small contraction::
+  followed by one small contraction per offset value::
 
-      ohv[r, g, v]     = (off[r, g] == v)          # [R, Gb, V] — same build
-      sel[g, x]        = (seg_idx[g] == x)         # [Gb, X]    — tiny
-      counts[r, v, x]  = sum_g ohv[r, g, v] * sel[g, x]
-      out[r, :]       += counts.reshape(R, V*X) @ pool_t.reshape(V*X, Ob)
+      oh_v[r, g]       = (off[r, g] == v)          # [R, Gb] per v
+      sel[x, g]        = (seg_idx[g] == x)         # [X, Gb] — tiny
+      counts_v[r, x]   = sum_g oh_v[r, g] * sel[x, g]
+      out[r, :]       += sum_v counts_v @ pool_t[v]
 
   where ``pool_t`` is the pool staged **pre-transposed** to ``[V, X, Ob]``
-  (done once on the host by ``ops.py``) so the count layout lines up with no
-  in-kernel transpose.  The fetch contraction therefore shrinks from the
-  dense path's ``[R, Gb*V] x [Gb*V, Ob]`` to ``[R, X*V] x [X*V, Ob]`` —
-  fetch compute scales with the pool cardinality ``X``, not the segment
-  count ``G``, mirroring exactly how ext. 3 makes the table *memory* scale
-  with ``X``.  No data-dependent addressing reaches the memory system
-  (compares + two matmuls, TPU-friendly);
-* the activation side is identical to the dense-fused pipeline — quantize and
-  little-endian shift-or pack in VMEM (helpers imported from
-  ``pcilt_fused``) — and counts are small integers built in f32 (exact up to
-  2**24 ≫ any Gb), so ``path="shared"`` matches the gather reference to f32
-  summation-order tolerance.
+  (done once per call by the wrapper) so each offset value's ``[X, Ob]``
+  slab is a leading-axis slice.  The fetch contraction therefore scales
+  with the pool cardinality ``X``, not the segment count ``G``, mirroring
+  exactly how ext. 3 makes the table *memory* scale with ``X``.  No
+  data-dependent addressing reaches the memory system (compares + 2-D
+  matmuls, TPU-friendly);
+* the activation side is the dense-fused pipeline's — quantize in VMEM and
+  pack each segment's codes with one exact integer contraction (helpers
+  imported from ``pcilt_fused``) — and counts are small integers built in
+  f32 (exact up to 2**24 ≫ any Gb), so ``path="shared"`` matches the gather
+  reference to f32 summation-order tolerance.
 
 Tiling comes from the caller (``ops.py``) via the persistent autotune lookup
 table under the ``shared_gemv`` / ``shared_conv2d`` shape keys, which include
@@ -52,36 +51,41 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .pcilt_fused import _pack_flat, _quantize, _strip_offsets
+from .pcilt_fused import _precision, _quantize_f32, _strip_codes, pack_matrix
 
 __all__ = ["pcilt_shared_gemv_pallas", "pcilt_shared_conv2d_pallas"]
 
 
-def _pool_counts_dot(off, idx, pool_t, *, V: int, X: int):
-    """The pooled fetch: ``off [R, Gb]``, ``idx [Gb]``,
-    ``pool_t [V, X, Ob]`` (pre-transposed pool) -> f32 ``[R, Ob]``.
+def _pool_counts_dot(codes, pack_ref, idx_ref, pool_ref, *, V: int, X: int):
+    """The pooled fetch: f32 ``codes [R, Gb*group]``, pointer row
+    ``idx_ref [1, Gb]``, pre-transposed pool tile ``pool_ref [V, X, Ob]``
+    -> f32 ``[R, Ob]``.
 
     Every segment pointing at pool row ``x`` with offset ``v`` fetches the
     *same* cell, so the adder tree over this grid step's ``Gb`` segments is
-    ``counts @ pool``: count how many segments land on each ``(v, x)`` cell
-    (an ``[R*V, Gb] x [Gb, X]`` contraction over the dense-cost one-hot),
-    then one ``[R, V*X] x [V*X, Ob]`` MXU contraction — ``X/Gb`` of the
-    dense kernel's fetch FLOPs.  Counts are small integers built in f32
-    (exact up to 2**24 ≫ any Gb), so no precision is lost to the
-    multiplicity trick; bf16 pools are promoted to f32 for the contraction
-    like the dense path's ``preferred_element_type`` accumulation.
+    ``sum_v counts_v @ pool_t[v]``: count how many segments land on each
+    ``(v, x)`` cell (an ``[R, Gb] x [X, Gb]^T`` contraction of 0/1 values,
+    exact at any precision), then one ``[R, X] x [X, Ob]`` contraction per
+    offset value.  Counts are small integers in f32 (exact up to 2**24 ≫
+    any Gb); f32 pools contract at full precision.
     """
-    R, Gb = off.shape
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (R, Gb, V), 2)
-    ohv = (off[:, :, None] == lanes).astype(jnp.float32)  # [R, Gb, V]
-    sel = (idx[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (Gb, X), 1)).astype(jnp.float32)  # [Gb, X]
-    counts = jax.lax.dot_general(
-        ohv, sel, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)  # [R, V, X]
-    return jnp.dot(counts.reshape(R, V * X),
-                   pool_t.reshape(V * X, pool_t.shape[-1]).astype(jnp.float32),
-                   preferred_element_type=jnp.float32)
+    off = jnp.dot(codes, pack_ref[...],
+                  preferred_element_type=jnp.float32)  # [R, Gb] offsets
+    Gb = off.shape[1]
+    sel = (jax.lax.broadcasted_iota(jnp.int32, (X, Gb), 0) == idx_ref[...]
+           ).astype(jnp.float32)  # [X, Gb]
+    acc = None
+    for v in range(V):
+        oh = (off == float(v)).astype(jnp.float32)  # [R, Gb]
+        counts = jax.lax.dot_general(
+            oh, sel, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)  # [R, X]
+        pool_v = pool_ref[v]
+        part = jnp.dot(counts.astype(pool_v.dtype), pool_v,
+                       preferred_element_type=jnp.float32,
+                       precision=_precision(pool_v.dtype))
+        acc = part if acc is None else acc + part
+    return acc
 
 
 # ----------------------------------------------------------------------------
@@ -89,17 +93,16 @@ def _pool_counts_dot(off, idx, pool_t, *, V: int, X: int):
 # ----------------------------------------------------------------------------
 
 
-def _gemv_kernel(x_ref, scale_ref, idx_ref, pool_ref, out_ref, *,
-                 bits: int, zero_point: int, group: int,
-                 Gb: int, V: int, X: int):
+def _gemv_kernel(x_ref, scale_ref, pack_ref, idx_ref, pool_ref, out_ref, *,
+                 bits: int, zero_point: int, V: int, X: int):
     @pl.when(pl.program_id(2) == 0)
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    codes = _quantize(x_ref[...], scale_ref[0, 0],
-                      bits=bits, zero_point=zero_point)  # [Bb, Gb*group]
-    off = _pack_flat(codes, bits=bits, group=group, Gseg=Gb)  # [Bb, Gb]
-    out_ref[...] += _pool_counts_dot(off, idx_ref[0], pool_ref[...], V=V, X=X)
+    _, codes = _quantize_f32(x_ref[...], scale_ref[...], bits=bits,
+                             zero_point=zero_point)  # [Bb, Gb*group]
+    out_ref[...] += _pool_counts_dot(codes, pack_ref, idx_ref, pool_ref,
+                                     V=V, X=X)
 
 
 @functools.partial(
@@ -123,8 +126,8 @@ def pcilt_shared_gemv_pallas(
 
     ``n == G * group``; B, O are padded to tile multiples by ``ops.py``;
     ``tiles`` is a ``(Bb, Gb, Ob)`` tuple with ``Gb | G``.  The whole pool is
-    staged per output tile (pre-transposed to ``[V, X, Ob]`` so the count
-    layout needs no in-kernel transpose); only the ``[Gb]`` pointer block
+    staged per output tile (pre-transposed to ``[V, X, Ob]`` so each offset
+    value's slab is a leading-axis slice); only the ``[Gb]`` pointer block
     walks the G axis.
     """
     B, n = x.shape
@@ -136,21 +139,24 @@ def pcilt_shared_gemv_pallas(
             f"(x {x.shape}, seg_idx {seg_idx.shape}, pool {pool.shape})")
     pool_t = jnp.transpose(pool, (1, 0, 2))  # [V, X, O], once per call
     Bb, Gb, Ob = tiles
+    pack = pack_matrix(Gb, group, bits, 1)  # codes -> [Gb] offsets
     grid = (pl.cdiv(B, Bb), pl.cdiv(O, Ob), G // Gb)
     return pl.pallas_call(
         functools.partial(_gemv_kernel, bits=bits, zero_point=zero_point,
-                          group=group, Gb=Gb, V=V, X=X),
+                          V=V, X=X),
         grid=grid,
         in_specs=[
             pl.BlockSpec((Bb, Gb * group), lambda i, j, k: (i, k)),
             pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),
+            pl.BlockSpec(pack.shape, lambda i, j, k: (0, 0)),
             pl.BlockSpec((1, Gb), lambda i, j, k: (0, k)),
             pl.BlockSpec((V, X, Ob), lambda i, j, k: (0, 0, j)),
         ],
         out_specs=pl.BlockSpec((Bb, Ob), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, O), jnp.float32),
         interpret=interpret,
-    )(x, scale, seg_idx, pool_t).astype(pool.dtype)
+        name="pcilt_shared_gemv",
+    )(x, scale, pack, seg_idx, pool_t).astype(pool.dtype)
 
 
 # ----------------------------------------------------------------------------
@@ -158,19 +164,19 @@ def pcilt_shared_gemv_pallas(
 # ----------------------------------------------------------------------------
 
 
-def _conv_kernel(x_ref, scale_ref, seg_ref, idx_ref, pool_ref, out_ref, *,
-                 bits: int, zero_point: int, group: int,
+def _conv_kernel(x_ref, scale_ref, seg_ref, pack_ref, idx_ref, pool_ref,
+                 out_ref, *, bits: int, zero_point: int, group: int,
                  kh: int, kw: int, stride: int,
                  Gb: int, V: int, X: int, Hb: int, n_pad: int):
     @pl.when(pl.program_id(3) == 0)
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    off = _strip_offsets(x_ref, scale_ref, seg_ref,
+    codes = _strip_codes(x_ref, scale_ref, seg_ref,
                          bits=bits, zero_point=zero_point,
                          group=group, kh=kh, kw=kw, stride=stride,
-                         Gb=Gb, Hb=Hb, n_pad=n_pad)  # [Hb*Wo, Gb]
-    acc = _pool_counts_dot(off, idx_ref[0], pool_ref[...], V=V, X=X)
+                         Gb=Gb, Hb=Hb, n_pad=n_pad)  # [Hb*Wo, Gb*group]
+    acc = _pool_counts_dot(codes, pack_ref, idx_ref, pool_ref, V=V, X=X)
     out_ref[...] += acc.reshape(out_ref.shape)  # [Hb*Wo, Ob] f32
 
 
@@ -221,6 +227,7 @@ def pcilt_shared_conv2d_pallas(
     Ho = (Hp - kh) // stride + 1
     Wo = (Wp - kw) // stride + 1
     Hb, Gb, Ob = tiles
+    pack = pack_matrix(Gb, group, bits, 1)  # codes -> [Gb] offsets
     grid = (B, Ho // Hb, pl.cdiv(O, Ob), G // Gb)
     return pl.pallas_call(
         functools.partial(_conv_kernel, bits=bits, zero_point=zero_point,
@@ -231,10 +238,11 @@ def pcilt_shared_conv2d_pallas(
             pl.BlockSpec((1, Hp, Wp, C), lambda b, r, j, k: (b, 0, 0, 0)),
             pl.BlockSpec((1, 1), lambda b, r, j, k: (0, 0)),
             pl.BlockSpec((1, 1), lambda b, r, j, k: (0, 0)),
+            pl.BlockSpec(pack.shape, lambda b, r, j, k: (0, 0)),
             pl.BlockSpec((1, Gb), lambda b, r, j, k: (0, k)),
             pl.BlockSpec((V, X, Ob), lambda b, r, j, k: (0, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, Hb, Wo, Ob), lambda b, r, j, k: (b, r, 0, j)),
         out_shape=jax.ShapeDtypeStruct((B, Ho, Wo, O), jnp.float32),
         interpret=interpret,
-    )(x, scale, seg_offset, seg_idx, pool_t).astype(pool.dtype)
+    )(x, scale, seg_offset, pack, seg_idx, pool_t).astype(pool.dtype)

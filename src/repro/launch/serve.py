@@ -207,14 +207,19 @@ class Engine:
                     "Engine(pcilt=True) requires cfg.pcilt (a configs.base."
                     "PCILTConfig) — set cfg = dataclasses.replace(cfg, "
                     "pcilt=PCILTConfig(...)) before constructing")
-            ctx = make_ctx(mesh, None, decode=True)
+            # Under a mesh only the tables' segment axis is sharded (each
+            # projection psums its partial fetch sums); activations stay
+            # replicated, so calibration and every other op compute what
+            # one device computes.
+            ctx = make_ctx(None, None, decode=True)
             if pcilt_bundle is not None:
                 self.pdecode = PCILTMambaDecode(self.model, pcilt_bundle, ctx)
             else:
                 calib = jax.random.randint(jax.random.PRNGKey(2), (2, 16), 0,
                                            cfg.vocab)
                 self.pdecode = convert_mamba_decode(
-                    self.model, self.params, calib, ctx, head="shared")
+                    self.model, self.params, calib, ctx, head="shared",
+                    mesh=mesh)
             self.monitor = HealthMonitor(self.pdecode, self.params,
                                          oracle_every=oracle_every)
 
@@ -730,13 +735,11 @@ def _chaos_plan(eng: Engine, injector):
     def corrupt_proj(e):
         tabs = e.pdecode.pcilt["proj"]["tables"]
         tabs["wx"] = injector.corrupt_table(tabs["wx"], n_flips=2)
-        e.pdecode.rehoist()  # jit closed over the old arrays
 
     def flip_head(e):
         head = e.pdecode.pcilt["head"]
         head["seg_idx"] = injector.flip_seg_idx(
             head["seg_idx"], n_pool=head["pool"].shape[0])
-        e.pdecode.rehoist()
 
     # keyed on the monotone step counter (prefill + decode steps) so every
     # entry fires even when requests finish during neighbors' prefill ticks
@@ -770,9 +773,8 @@ def _chaos_drift_plan(eng: Engine, injector):
                                              rows=[DRIFT_LAYER])
         mixer["norm"] = norm
         blocks["mixer"] = mixer
-        # params are a step *argument* (not closed over like tables), so no
-        # rehoist — and they are deliberately outside the checkpoint ring:
-        # a rollback must NOT undo the drift, the workload really moved
+        # params are deliberately outside the checkpoint ring: a rollback
+        # must NOT undo the drift, the workload really moved
         e.params = dict(e.params, blocks=blocks)
 
     return {DRIFT_STEP: [drift_norm]}
@@ -783,6 +785,28 @@ def _make_requests(cfg, n: int, max_new: int, deadline: Optional[float],
     rng = np.random.default_rng(seed)
     return [Request(i, rng.integers(2, cfg.vocab, size=rng.integers(4, 12)),
                     max_new, deadline_s=deadline) for i in range(n)]
+
+
+def serve_config(arch: str, *, full: bool = False, pcilt: bool = False):
+    """The config ``main`` serves: the published shape with ``full``, the
+    smoke shape otherwise.  Under ``pcilt`` the decode runs f32, with the
+    config's own table format (``cfg.pcilt``) or, where it has none,
+    INT4 g2."""
+    import dataclasses as dc
+
+    from repro.configs.base import PCILTConfig
+
+    cfg = get_config(arch) if full else get_smoke_config(arch)
+    if cfg.n_img_tokens or cfg.encoder_layers:
+        raise SystemExit("serve demo targets text decoder archs")
+    if pcilt:
+        if cfg.ssm is None:
+            raise SystemExit("--pcilt serves the converted Mamba decode "
+                             "path; pick an [ssm] arch (e.g. mamba2-130m)")
+        cfg = dc.replace(cfg, pcilt=cfg.pcilt or PCILTConfig(act_bits=4,
+                                                             group=2),
+                         dtype=jnp.float32)
+    return cfg
 
 
 def main(argv=None):
@@ -837,21 +861,11 @@ def main(argv=None):
     if args.chaos_drift and args.no_sentinel:
         raise SystemExit("--chaos-drift needs the sentinel; drop "
                          "--no-sentinel")
-    cfg = get_config(args.arch) if args.full else get_smoke_config(args.arch)
-    if cfg.n_img_tokens or cfg.encoder_layers:
-        raise SystemExit("serve demo targets text decoder archs")
+    cfg = serve_config(args.arch, full=args.full, pcilt=args.pcilt)
     if args.pcilt:
-        import dataclasses as dc
         import os
         import tempfile
 
-        from repro.configs.base import PCILTConfig
-
-        if cfg.ssm is None:
-            raise SystemExit("--pcilt serves the converted Mamba decode "
-                             "path; pick an [ssm] arch (e.g. mamba2-130m)")
-        cfg = dc.replace(cfg, pcilt=PCILTConfig(act_bits=4, group=2),
-                         dtype=jnp.float32)
         if args.chaos and "REPRO_PCILT_TUNE_CACHE" not in os.environ:
             # the chaos plan garbles the autotune cache file — never the
             # user's real one
@@ -1125,4 +1139,7 @@ def _verify_chaos_traffic_contract(cfg, args, eng, reqs, stats, injector,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -188,6 +188,8 @@ class MambaLM:
         quantized kernel itself rides along as the exact dense oracle the
         demoted path evaluates (``fetch(x) == fake_quant(x) @ kernel_q`` on
         the activation grid — zero-padded alignment rows contribute 0).
+        Every logit is an integer multiple of ``step = act scale x weight
+        scale``; both paths snap to that grid (:meth:`_head_logits`).
         """
         from repro.core import (QuantSpec, build_shared_grouped_tables,
                                 fake_quant, scale_from_amax)
@@ -211,36 +213,52 @@ class MambaLM:
         return {"pool": shared.pool, "seg_idx": shared.seg_idx,
                 "group": group, "spec": spec,
                 "scale": jnp.asarray(head_scale, jnp.float32),
+                "step": jnp.asarray(head_scale * w_scale, jnp.float32),
                 "kernel_q": kq, "n": n + pad}
 
-    def _head_logits(self, head, x, ok=None):
+    def _head_logits(self, head, x, ok=None, mesh=None):
         """Last-position logits through the shared-pool PCILT head.
 
         ``x [B, d]`` -> ``[B, padded_vocab]``.  ``ok`` (traced bool) demotes
         the fetch to the exact fake-quant dense oracle under ``lax.cond`` —
         the response to a corrupted pool entry or re-aimed ``seg_idx``
-        pointer."""
+        pointer.  ``mesh`` (the projections' table mesh) runs the fetch
+        kernel replicated on each of its devices.
+
+        Quantized activations times quantized weights put every logit on the
+        grid ``k * step``, and many logits tie exactly.  Both paths snap
+        their f32 sums back to the grid, so the fetch and the oracle return
+        the same bits and break ties (greedy argmax) the same way — their
+        f32 summation orders differ by far less than half a step."""
         from repro.core import fake_quant, pcilt_linear
+        from repro.core.lut_layers import replicated_on
         from repro.core.pcilt import SharedGroupedTables
 
         cfg = self.cfg
+
+        def shared_fetch(xx, pool, seg_idx, scale):
+            shared = SharedGroupedTables(pool=pool, seg_idx=seg_idx,
+                                         group=head["group"])
+            return pcilt_linear(xx.astype(jnp.float32), shared, head["spec"],
+                                scale, head["group"], path="shared")
 
         def _fetch(xx):
             pad = head["n"] - xx.shape[-1]
             if pad:  # group-alignment slots (zero weights -> zero tables)
                 xx = jnp.concatenate(
                     [xx, jnp.zeros((*xx.shape[:-1], pad), xx.dtype)], -1)
-            shared = SharedGroupedTables(pool=head["pool"],
-                                         seg_idx=head["seg_idx"],
-                                         group=head["group"])
-            return pcilt_linear(
-                xx.astype(jnp.float32), shared, head["spec"], head["scale"],
-                head["group"], path="shared").astype(cfg.dtype)
+            return snap(replicated_on(mesh, shared_fetch)(
+                xx, head["pool"], head["seg_idx"], head["scale"]))
 
         def _oracle(xx):
             xq = fake_quant(xx.astype(jnp.float32), head["spec"],
                             head["scale"])
-            return (xq @ head["kernel_q"]).astype(cfg.dtype)
+            return snap(jnp.dot(xq, head["kernel_q"],
+                                precision=jax.lax.Precision.HIGHEST))
+
+        def snap(y):
+            step = head["step"]
+            return (jnp.round(y / step) * step).astype(cfg.dtype)
 
         if ok is None:
             return _fetch(x)
@@ -362,6 +380,9 @@ class MambaLM:
         pos = cache["pos"]
         x = self._embed(params, ctx, tokens)
         proj = None if pcilt is None else pcilt.get("proj")
+        # the projections' table mesh: every other kernel of the step runs
+        # replicated on it (core.lut_layers.replicated_on)
+        mesh = None if proj is None else proj.get("mesh")
 
         def body(h, inp):
             p, st = inp[0], inp[1]
@@ -369,7 +390,7 @@ class MambaLM:
             pc = None
             if pcilt is not None:
                 pc = {"tables": inp[2], "scale": pcilt["scale"],
-                      "spec": pcilt["spec"]}
+                      "spec": pcilt["spec"], "mesh": mesh}
                 if "ok" in per:
                     pc["ok"] = per["ok"]
                 if proj is not None:
@@ -408,7 +429,7 @@ class MambaLM:
         if head is None:
             logits = self._logits(params, x)[:, -1]
         else:
-            logits = self._head_logits(head, x[:, -1], head_ok)
+            logits = self._head_logits(head, x[:, -1], head_ok, mesh)
         new_cache = dict(cache, layers=new_states, pos=pos + 1)
         if with_stats:
             return logits, new_cache, sat

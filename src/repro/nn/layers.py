@@ -18,7 +18,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .module import ParamSpec, ShardingRules, logical_to_partition_spec
 
-from repro.compat import shard_map
 
 
 
@@ -70,11 +69,13 @@ def dense_spec(d_in: int, d_out, axes, bias: bool = False, dtype=jnp.float32,
     return p
 
 
-def dense(params, x: jax.Array, compute_dtype=jnp.bfloat16) -> jax.Array:
+def dense(params, x: jax.Array, compute_dtype=jnp.bfloat16,
+          precision=None) -> jax.Array:
     """x [..., d_in] @ kernel [d_in, *rest] -> [..., *rest]."""
     k = params["kernel"].astype(compute_dtype)
     kernel_2d = k.reshape(k.shape[0], -1)
-    y = (x.astype(compute_dtype) @ kernel_2d).reshape(*x.shape[:-1], *k.shape[1:])
+    y = jnp.matmul(x.astype(compute_dtype), kernel_2d, precision=precision
+                   ).reshape(*x.shape[:-1], *k.shape[1:])
     if "bias" in params:
         y = y + params["bias"].astype(compute_dtype)
     return y
@@ -118,7 +119,7 @@ def row_parallel(ctx: Ctx, x: jax.Array, w: jax.Array, eq: str,
         return jax.lax.psum_scatter(y, "model", scatter_dimension=1,
                                     tiled=True)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=ctx.mesh, in_specs=(x_spec, w_spec),
         out_specs=P(dp, "model", None), check_vma=False,
     )(x, w)

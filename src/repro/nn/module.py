@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import zlib
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -219,20 +220,21 @@ def _init_one(spec: ParamSpec, key: jax.Array) -> jax.Array:
 
 
 def flatten_with_path(tree, is_leaf=None):
-    """``jax.tree.flatten_with_path``, version-tolerant (see ``repro.compat``)."""
-    from repro.compat import tree_flatten_with_path
-
-    return tree_flatten_with_path(tree, is_leaf=is_leaf)
+    """``jax.tree.flatten_with_path``."""
+    return jax.tree.flatten_with_path(tree, is_leaf=is_leaf)
 
 
 def materialize(specs, key: jax.Array):
-    """Concrete params; per-leaf keys derived by path so order is stable."""
+    """Concrete params; per-leaf keys derived from a CRC-32 of the leaf's
+    path, so the same key gives the same values in every process (Python's
+    ``hash`` of a string is salted per process)."""
     leaves, treedef = flatten_with_path(
         specs, is_leaf=lambda x: isinstance(x, ParamSpec)
     )
     out = []
     for path, spec in leaves:
-        sub = jax.random.fold_in(key, abs(hash(jax.tree_util.keystr(path))) % (2**31))
+        digest = zlib.crc32(jax.tree_util.keystr(path).encode())
+        sub = jax.random.fold_in(key, digest % (2**31))
         out.append(_init_one(spec, sub))
     return jax.tree.unflatten(treedef, out)
 

@@ -28,7 +28,6 @@ from jax.sharding import PartitionSpec as P
 from .layers import Ctx, dense
 from .module import ParamSpec
 
-from repro.compat import axis_size, shard_map
 
 
 
@@ -86,7 +85,7 @@ def _moe_body(params, cfg, x_local, model_axis: Optional[str],
     E = m.padded_experts
     tp = 1
     if model_axis is not None:
-        tp = axis_size(model_axis)
+        tp = jax.lax.axis_size(model_axis)
     E_loc = E // tp
     t, d = x_local.shape
 
@@ -195,7 +194,7 @@ def moe_apply(params, cfg, ctx: Ctx, x: jax.Array) -> Tuple[jax.Array, Dict]:
         y, aux = _moe_body(p, cfg, xl.reshape(-1, d), "model", dp_axes, use_a2a)
         return y.reshape(bl, sl, d), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(wspecs, x_spec),

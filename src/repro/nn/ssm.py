@@ -119,8 +119,11 @@ def _proj(params, name, x, cfg, proj, with_stats: bool = False):
     path = proj.get("path", "fused")
 
     def _oracle(xx):
+        # full precision, like the table build: the oracle is exact on the
+        # quantized grid only if the contraction does not round to bf16
         xq = fake_quant(xx.astype(jnp.float32), proj["spec"], scale)
-        out = dense(params[name], xq, jnp.float32).astype(cfg.dtype)
+        out = dense(params[name], xq, jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST).astype(cfg.dtype)
         if with_stats:
             _, count, ratio = quantize_with_stats(xx, proj["spec"], scale)
             return out, count, ratio
@@ -208,12 +211,16 @@ def _conv1d(params, cfg, x, conv_state=None, pcilt=None,
         if pcilt is not None:
             from repro.core import (fake_quant, pcilt_depthwise_conv1d,
                                     quantize_with_stats)
+            from repro.core.lut_layers import replicated_on
 
             def _fetch(win):
-                out = pcilt_depthwise_conv1d(
-                    win, params["conv_w"], pcilt["spec"],
-                    pcilt["scale"], tables=pcilt["tables"], path="fused",
-                    padding="VALID", return_stats=with_stats)  # [B, 1, C]
+                out = replicated_on(
+                    pcilt.get("mesh"),
+                    lambda w, f, s, t: pcilt_depthwise_conv1d(
+                        w, f, pcilt["spec"], s, tables=t, path="fused",
+                        padding="VALID", return_stats=with_stats),
+                )(win, params["conv_w"], pcilt["scale"],
+                  pcilt["tables"])  # [B, 1, C]
                 if with_stats:
                     out, count, ratio = out
                     return out.astype(x.dtype), count, ratio
@@ -223,7 +230,8 @@ def _conv1d(params, cfg, x, conv_state=None, pcilt=None,
                 wq = fake_quant(win.astype(jnp.float32), pcilt["spec"],
                                 pcilt["scale"])
                 out = jnp.einsum(
-                    "bkc,kc->bc", wq, params["conv_w"].astype(jnp.float32)
+                    "bkc,kc->bc", wq, params["conv_w"].astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST,
                 )[:, None].astype(x.dtype)
                 if with_stats:
                     _, count, ratio = quantize_with_stats(

@@ -23,14 +23,13 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
 
 __all__ = ["compressed_pmean", "compress_grads_tree"]
 
 
 def _int8_pmean(x: jax.Array, axis: str) -> Tuple[jax.Array, jax.Array]:
     """Mean over ``axis`` via int8 two-phase reduce.  Returns (mean, residual)."""
-    n_shards = axis_size(axis)
+    n_shards = jax.lax.axis_size(axis)
     n = x.size
     pad = (-n) % n_shards
     flat = jnp.pad(x.reshape(-1), (0, pad)).reshape(n_shards, -1)

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 
 
 
@@ -77,7 +76,7 @@ def pipeline_apply(
         # replicate the last stage's bank to every stage
         return jax.lax.psum(jnp.where(sid == S - 1, out, 0.0), axis)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P()), out_specs=P(),
         check_vma=False,

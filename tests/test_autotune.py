@@ -134,15 +134,19 @@ def test_concurrent_saves_keep_newest_per_key(tune_cache):
 
 
 def test_failed_tune_records_null_not_nan(tune_cache):
-    """Regression: an all-candidates-failed tune must write valid JSON
+    """An all-candidates-failed tune raises (a kernel the backend cannot
+    run at any tiling must not be hidden behind an untimed fallback) and
+    records nothing; an untimed entry that is recorded is strict JSON
     (us: null), never a bare NaN token that breaks strict parsers/jq."""
     cands = [atn.TileConfig(Bb=8, Gb=1, Ob=128)]
 
     def bench(cfg):
         raise RuntimeError("no candidate can run")
 
-    got = atn.tune("k|dtype=float32|backend=cpu", cands, bench)
-    assert got == cands[0]  # heuristic fallback still dispatches
+    with pytest.raises(RuntimeError, match="none of 1 candidate"):
+        atn.tune("k|dtype=float32|backend=cpu", cands, bench)
+    assert not os.path.exists(tune_cache)
+    atn.get_cache().record("k|dtype=float32|backend=cpu", cands[0], None, 0)
     raw = open(tune_cache).read()
     assert "NaN" not in raw
     entry = json.loads(raw)["k|dtype=float32|backend=cpu"]  # strict parse ok

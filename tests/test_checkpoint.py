@@ -4,6 +4,7 @@ import os
 import time
 
 import jax
+from jax.sharding import AxisType
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -74,7 +75,8 @@ def test_elastic_restore_with_shardings(tmp_path):
 
     t = _tree()
     save(str(tmp_path), 3, t)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(AxisType.Auto,) * 1)
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), t)
     got, _ = restore(str(tmp_path), 3, t, shardings=sh)
     for a, b in zip(jax.tree.leaves(t), jax.tree.leaves(got)):

@@ -29,6 +29,7 @@ def run8(code: str, devices: int = 8):
 
 def test_moe_distributed_matches_local():
     run8("""
+    from jax.sharding import AxisType
     import dataclasses
     import jax, numpy as np
     import jax.numpy as jnp
@@ -50,7 +51,8 @@ def test_moe_distributed_matches_local():
 
     y_local, aux_local = moe_apply(params, cfg, Ctx(), x)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     ctx = Ctx(mesh=mesh, rules=ShardingRules.for_mesh(mesh))
     sh = shardings(spec, mesh)
     params_d = jax.tree.map(jax.device_put, params, sh)
@@ -66,20 +68,21 @@ def test_moe_distributed_matches_local():
 
 def test_compressed_pmean_int8_and_bf16():
     run8("""
+    from jax.sharding import AxisType
     import jax, numpy as np
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
     from repro.optim import compressed_pmean
-    from repro.compat import shard_map
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",),
+                         axis_types=(AxisType.Auto,) * 1)
     x = jax.random.normal(jax.random.PRNGKey(0), (8, 1024))
 
     for scheme, tol in (("int8", 3e-2), ("bf16", 1e-2), ("none", 1e-6)):
         def body(xl):
             r, resid = compressed_pmean(xl[0], "data", scheme)
             return r
-        got = jax.jit(shard_map(body, mesh=mesh, in_specs=P("data"),
+        got = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("data"),
                                     out_specs=P(), check_vma=False))(x)
         want = x.mean(0)
         err = float(jnp.abs(got - want).max() / (jnp.abs(want).max() + 1e-9))
@@ -91,8 +94,8 @@ def test_compressed_pmean_int8_and_bf16():
         return compressed_pmean(xl[0], "data", "int8")[0]
     def red32(xl):
         return compressed_pmean(xl[0], "data", "none")[0]
-    c8 = jax.jit(shard_map(red8, mesh=mesh, in_specs=P("data"), out_specs=P(), check_vma=False)).lower(x).compile()
-    c32 = jax.jit(shard_map(red32, mesh=mesh, in_specs=P("data"), out_specs=P(), check_vma=False)).lower(x).compile()
+    c8 = jax.jit(jax.shard_map(red8, mesh=mesh, in_specs=P("data"), out_specs=P(), check_vma=False)).lower(x).compile()
+    c32 = jax.jit(jax.shard_map(red32, mesh=mesh, in_specs=P("data"), out_specs=P(), check_vma=False)).lower(x).compile()
     b8 = analyze_hlo(c8.as_text())["collective_bytes"]
     b32 = analyze_hlo(c32.as_text())["collective_bytes"]
     assert b8 < 0.75 * b32, (b8, b32)
@@ -102,6 +105,7 @@ def test_compressed_pmean_int8_and_bf16():
 
 def test_elastic_checkpoint_remesh():
     run8("""
+    from jax.sharding import AxisType
     import os, tempfile
     import jax, numpy as np
     import jax.numpy as jnp
@@ -109,7 +113,8 @@ def test_elastic_checkpoint_remesh():
     from repro.checkpoint import save, restore
 
     tree = {"w": jnp.arange(64.0).reshape(8, 8), "b": jnp.arange(8.0)}
-    mesh1 = jax.make_mesh((4, 2), ("data", "model"))
+    mesh1 = jax.make_mesh((4, 2), ("data", "model"),
+                          axis_types=(AxisType.Auto,) * 2)
     sh1 = {"w": NamedSharding(mesh1, P("data", "model")),
            "b": NamedSharding(mesh1, P("model"))}
     t1 = jax.tree.map(jax.device_put, tree, sh1)
@@ -119,7 +124,8 @@ def test_elastic_checkpoint_remesh():
 
     # restore onto a different mesh topology
     for shape, axes in (((2, 4), ("data", "model")), ((8, 1), ("data", "model"))):
-        mesh2 = jax.make_mesh(shape, axes)
+        mesh2 = jax.make_mesh(shape, axes,
+                              axis_types=(AxisType.Auto,) * len(shape))
         sh2 = {"w": NamedSharding(mesh2, P("data", "model")),
                "b": NamedSharding(mesh2, P("model") if shape[1] > 1 else P())}
         got, _ = restore(d, 1, tree, shardings=sh2)
@@ -131,6 +137,7 @@ def test_elastic_checkpoint_remesh():
 
 def test_sharded_train_step_matches_single_device():
     run8("""
+    from jax.sharding import AxisType
     import jax, numpy as np
     import jax.numpy as jnp
     from repro.configs import get_smoke_config
@@ -153,7 +160,8 @@ def test_sharded_train_step_matches_single_device():
 
     p1, o1, m1 = jax.jit(make_train_step(cfg, None, ocfg))(params, opt, batch)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     sh = shardings(specs, mesh)
     params_d = jax.tree.map(jax.device_put, params, sh)
     opt_d = adamw_init(params_d, ocfg)
@@ -181,6 +189,7 @@ def test_production_mesh_shapes():
 
 def test_rowrs_explicit_reduce_scatter_matches_base():
     run8("""
+    from jax.sharding import AxisType
     import dataclasses
     import jax, numpy as np
     import jax.numpy as jnp
@@ -197,7 +206,8 @@ def test_rowrs_explicit_reduce_scatter_matches_base():
     ocfg = AdamWConfig(lr=1e-3)
     batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, cfg.vocab),
              "labels": jax.random.randint(jax.random.PRNGKey(2), (8, 16), 0, cfg.vocab)}
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     params_d = jax.tree.map(jax.device_put, params, shardings(specs, mesh))
     p1, o1, m1 = jax.jit(make_train_step(cfg, mesh, ocfg))(
         params_d, adamw_init(params_d, ocfg), batch)
@@ -213,6 +223,7 @@ def test_rowrs_explicit_reduce_scatter_matches_base():
 
 def test_kvshard_decode_matches_base():
     run8("""
+    from jax.sharding import AxisType
     import jax, numpy as np
     import jax.numpy as jnp
     from repro.configs import get_smoke_config
@@ -230,7 +241,8 @@ def test_kvshard_decode_matches_base():
     cache = dict(cache, pos=jnp.asarray(T - 1, jnp.int32))
     tok = jax.random.randint(jax.random.PRNGKey(2), (B, 1), 0, cfg.vocab)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     params_d = jax.tree.map(jax.device_put, params, shardings(specs, mesh))
     l1, _ = jax.jit(make_decode_step(cfg, mesh))(params_d, cache, tok)
     l2, _ = jax.jit(make_decode_step(
@@ -243,12 +255,14 @@ def test_kvshard_decode_matches_base():
 
 def test_pipeline_parallel_matches_sequential():
     run8("""
+    from jax.sharding import AxisType
     import jax, numpy as np
     import jax.numpy as jnp
     from repro.runtime.pipeline import pipeline_apply
 
     S, M, B, D = 4, 6, 2, 8
-    mesh = jax.make_mesh((S,), ("stage",))
+    mesh = jax.make_mesh((S,), ("stage",),
+                         axis_types=(AxisType.Auto,) * 1)
     key = jax.random.PRNGKey(0)
     ws = jax.random.normal(key, (S, D, D)) * 0.3
     x = jax.random.normal(jax.random.fold_in(key, 1), (M, B, D))

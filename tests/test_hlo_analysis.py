@@ -1,10 +1,10 @@
 """The trip-count-aware HLO analyzer against analytically-known costs."""
 
 import jax
+from jax.sharding import AxisType
 import jax.numpy as jnp
 
 from repro.launch.hlo_analysis import analyze_hlo
-from repro.compat import shard_map
 
 
 def _scan_matmul(L=8, d=128, b=64):
@@ -44,7 +44,8 @@ def test_collectives_weighted_by_trips():
 
     if len(jax.devices()) < 1:
         return
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(AxisType.Auto,) * 1)
 
     def body(xl):
         def step(c, _):
@@ -52,7 +53,7 @@ def test_collectives_weighted_by_trips():
         y, _ = jax.lax.scan(step, xl, None, length=5)
         return y
 
-    f = shard_map(body, mesh=mesh, in_specs=P(), out_specs=P())
+    f = jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P())
     c = jax.jit(f).lower(jnp.zeros((4, 4))).compile()
     a = analyze_hlo(c.as_text())
     # psum of 64B fp32 × 5 trips (single-device AR may be optimized away;

@@ -119,3 +119,33 @@ def test_long_500k_eligibility():
     for a in ("qwen3-0.6b", "deepseek-coder-33b", "whisper-medium",
               "llama4-maverick-400b-a17b"):
         assert not eligible[a]
+
+
+def test_materialize_is_stable_across_processes():
+    """Per-leaf init keys come from a digest of the leaf path, not Python's
+    per-process salted ``hash``: two processes with different hash seeds
+    materialize the same parameters from the same key."""
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "import jax, numpy as np\n"
+        "from repro.configs import get_smoke_config\n"
+        "from repro.models import build_model\n"
+        "from repro.nn import materialize\n"
+        "m = build_model(get_smoke_config('mamba2-130m'))\n"
+        "p = materialize(m.param_specs(), jax.random.PRNGKey(0))\n"
+        "print(repr([float(np.abs(np.asarray(l, np.float64)).sum())\n"
+        "            for l in jax.tree.leaves(p)]))\n")
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    sums = []
+    for seed in ("1", "2"):
+        env = dict(os.environ, PYTHONHASHSEED=seed, JAX_PLATFORMS="cpu",
+                   PYTHONPATH=src)
+        r = subprocess.run([sys.executable, "-c", code], env=env,
+                           capture_output=True, text=True, timeout=300)
+        assert r.returncode == 0, r.stderr
+        sums.append(r.stdout.strip().splitlines()[-1])
+    assert sums[0] == sums[1]
