@@ -146,6 +146,8 @@ class Checkpointer:
                           ignore_errors=True)
 
     def restore_latest(self, like_tree, shardings=None):
+        # a save still being written is the latest checkpoint: let it land
+        self.wait()
         step = latest_step(self.root)
         if step is None:
             return None, None, None

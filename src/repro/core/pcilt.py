@@ -71,6 +71,7 @@ import jax.numpy as jnp
 
 from .quantization import QuantSpec, code_values
 from .offsets import SegmentPlan, offset_grid
+from repro.runtime.tracing import span
 
 __all__ = [
     "mul_fn",
@@ -90,6 +91,7 @@ __all__ = [
     "shared_table_bytes",
     "shared_pool_bytes",
     "build_cost_multiplies",
+    "host_copy",
     "table_checksum",
     "stacked_checksums",
 ]
@@ -635,10 +637,20 @@ def build_cost_multiplies(n_weights: int, act_bits: int) -> int:
 # ----------------------------------------------------------------------------
 
 
+def host_copy(arr) -> np.ndarray:
+    """``arr`` on the host (gathers sharded arrays); a device array keeps
+    the copy for later calls, so only the first one transfers."""
+    if isinstance(arr, np.ndarray):
+        return arr
+    with span("integrity.host_copy", bytes=int(arr.nbytes)):
+        return np.asarray(arr)
+
+
 def table_checksum(arr) -> int:
     """CRC-32 over the raw bytes of a table array (gathers sharded arrays)."""
-    a = np.ascontiguousarray(np.asarray(arr))
-    return zlib.crc32(a.tobytes())
+    a = host_copy(arr)
+    with span("integrity.crc32", bytes=int(a.nbytes)):
+        return zlib.crc32(np.ascontiguousarray(a).tobytes())
 
 
 def stacked_checksums(arr, axis: int = 0) -> List[int]:
@@ -646,7 +658,7 @@ def stacked_checksums(arr, axis: int = 0) -> List[int]:
     ``axis``, so verification localizes a breach to the layer that must be
     demoted.  Dense stacks are layer-major (``[L, G, V, O]``, ``axis=0``);
     paired stacks are segment-major (``[G2, L, V**2, O]``, ``axis=1``)."""
-    a = np.asarray(arr)
+    a = host_copy(arr)
     if axis:
         a = np.moveaxis(a, axis, 0)
     return [table_checksum(a[i]) for i in range(a.shape[0])]

@@ -27,10 +27,11 @@ from .quantization import (QuantSpec, calibrate, fake_quant, quantize,
                            dequantize, scale_from_amax)
 from .pcilt import (SharedGroupedTables, ShardedSharedPool,
                     build_grouped_tables, build_shared_grouped_tables,
-                    shard_shared_grouped_tables, stacked_checksums,
-                    table_checksum)
+                    host_copy, shard_shared_grouped_tables,
+                    stacked_checksums, table_checksum)
 from .lut_layers import (build_dwconv_tables, mesh_shard_count, pcilt_conv2d,
                          pcilt_depthwise_conv1d, pcilt_linear)
+from repro.runtime.tracing import span
 
 log = logging.getLogger("repro.serving")
 
@@ -603,10 +604,14 @@ class PCILTMambaDecode:
         key = (rows, stats)
         f = self._execs.get(key)
         if f is None:
-            f = jax.jit(
-                lambda p, c, t, ok, hok, arrays: self.model.decode_step(
+            # a named function, so the compiled module has a stable name
+            # (``jit_pcilt_decode_step``) in device traces
+            def pcilt_decode_step(p, c, t, ok, hok, arrays):
+                return self.model.decode_step(
                     p, c, t, self.ctx, pcilt=self._rebuild(arrays),
-                    layer_ok=ok, head_ok=hok, with_stats=stats))
+                    layer_ok=ok, head_ok=hok, with_stats=stats)
+
+            f = jax.jit(pcilt_decode_step)
             self._execs[key] = f
         return f
 
@@ -659,16 +664,33 @@ class PCILTMambaDecode:
         integ = self.pcilt["integrity"]
         bad: List[Tuple] = []
         if table_checksum(
-                np.asarray(self.pcilt["tables"])[layer]) != integ["conv"][layer]:
+                host_copy(self.pcilt["tables"])[layer]) != integ["conv"][layer]:
             bad.append(("conv", int(layer)))
         proj = self.pcilt.get("proj")
         if proj is not None:
             for name, t in proj["tables"].items():
-                sl = (np.asarray(t)[:, layer] if proj.get("paired")
-                      else np.asarray(t)[layer])
+                sl = (host_copy(t)[:, layer] if proj.get("paired")
+                      else host_copy(t)[layer])
                 if table_checksum(sl) != integ["proj"][name][layer]:
                     bad.append((name, int(layer)))
         return bad
+
+    def layer_check_bytes(self) -> int:
+        """Bytes :meth:`verify_layer` hashes: one layer's slice of the conv
+        table and of each projection table."""
+        L = self.pcilt["tables"].shape[0]
+        stacks = [self.pcilt["tables"]]
+        proj = self.pcilt.get("proj")
+        if proj is not None:
+            stacks += list(proj["tables"].values())
+        return sum(int(t.nbytes) // L for t in stacks)
+
+    def head_check_bytes(self) -> int:
+        """Bytes :meth:`verify_head` hashes (0 = no head)."""
+        head = self.pcilt.get("head")
+        if head is None:
+            return 0
+        return int(head["pool"].nbytes) + int(head["seg_idx"].nbytes)
 
     def verify_head(self) -> List[Tuple]:
         """Checksum the shared-pool logits head (pool values + ``seg_idx``
@@ -835,7 +857,14 @@ class HealthMonitor:
         #: newest tick each layer passed verification at (-1 = never)
         self.last_verified = np.full(self.n_layers, -1, np.int64)
         self.head_last_verified = -1
+        #: clean layer checks (what ``oracle_every`` counts)
         self.checks = 0
+        #: ``on_tick``'s work: layer and head CRC checks, dense-oracle
+        #: probes, and the table bytes those CRCs hashed
+        self.layer_checks = 0
+        self.head_checks = 0
+        self.oracle_probes = 0
+        self.crc_bytes = 0
         self.events: List[Dict] = []
         rng = np.random.default_rng(seed)
         d_inner = cfg.ssm.expand * cfg.d_model
@@ -951,36 +980,56 @@ class HealthMonitor:
         instant ``"saturated"`` classification demotes on the very tick
         whose outputs it indicts."""
         tick = int(tick)
-        breaches: List[Dict] = []
-        if sat is not None:
-            breaches.extend(self.observe_saturation(tick, sat, rows))
-        candidates = [l for l in range(self.n_layers) if self.layer_ok[l]]
-        if candidates:
-            l = candidates[tick % len(candidates)]
-            bad = self.decode.verify_layer(l)
-            if bad:
-                breaches.append(self.demote(
-                    "layer", l, tick, f"checksum breach: {bad}"))
-            else:
-                self.checks += 1
-                if self.oracle_every and \
-                        self.checks % self.oracle_every == 0:
-                    name = self._next_probe_name()
-                    if not self._oracle_check(l, name):
-                        breaches.append(self.demote(
-                            "layer", l, tick,
-                            f"dense-oracle divergence ({name})"))
-            if self.layer_ok[l]:
-                self.last_verified[l] = tick
-        if self.head_ok and self.decode.pcilt.get("head") is not None and \
-                tick % max(self.n_layers, 1) == 0:
-            bad = self.decode.verify_head()
-            if bad:
-                breaches.append(self.demote(
-                    "head", None, tick, f"checksum breach: {bad}"))
-            else:
-                self.head_last_verified = tick
-        return breaches
+        with span("monitor", tick=tick):
+            breaches: List[Dict] = []
+            if sat is not None:
+                with span("monitor.saturation"):
+                    breaches.extend(self.observe_saturation(tick, sat, rows))
+            candidates = [l for l in range(self.n_layers) if self.layer_ok[l]]
+            if candidates:
+                l = candidates[tick % len(candidates)]
+                nbytes = self.decode.layer_check_bytes()
+                with span("monitor.crc_layer", layer=l, bytes=nbytes):
+                    bad = self.decode.verify_layer(l)
+                self.layer_checks += 1
+                self.crc_bytes += nbytes
+                if bad:
+                    breaches.append(self.demote(
+                        "layer", l, tick, f"checksum breach: {bad}"))
+                else:
+                    self.checks += 1
+                    if self.oracle_every and \
+                            self.checks % self.oracle_every == 0:
+                        name = self._next_probe_name()
+                        with span("monitor.oracle", layer=l, proj=name):
+                            ok = self._oracle_check(l, name)
+                        self.oracle_probes += 1
+                        if not ok:
+                            breaches.append(self.demote(
+                                "layer", l, tick,
+                                f"dense-oracle divergence ({name})"))
+                if self.layer_ok[l]:
+                    self.last_verified[l] = tick
+            if self.head_ok and self.decode.pcilt.get("head") is not None and \
+                    tick % max(self.n_layers, 1) == 0:
+                nbytes = self.decode.head_check_bytes()
+                with span("monitor.crc_head", bytes=nbytes):
+                    bad = self.decode.verify_head()
+                self.head_checks += 1
+                self.crc_bytes += nbytes
+                if bad:
+                    breaches.append(self.demote(
+                        "head", None, tick, f"checksum breach: {bad}"))
+                else:
+                    self.head_last_verified = tick
+            return breaches
+
+    def counters(self) -> Dict[str, int]:
+        """Totals of the health passes run so far (``Engine`` stats)."""
+        return {"layer_checks": self.layer_checks,
+                "head_checks": self.head_checks,
+                "oracle_probes": self.oracle_probes,
+                "crc_bytes": self.crc_bytes}
 
     # -- calibration-drift sentinel ------------------------------------------
 

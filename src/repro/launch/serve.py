@@ -98,7 +98,7 @@ from repro.configs import get_config, get_smoke_config
 from repro.models import build_model
 from repro.nn.module import materialize, shape_structs
 from repro.launch.steps import make_decode_step, make_prefill_step, make_ctx
-from repro.runtime import StepWatchdog, WallClock
+from repro.runtime import StepWatchdog, WallClock, span
 
 log = logging.getLogger("repro.serve")
 
@@ -188,6 +188,10 @@ class Engine:
         self.slot_evictions = 0
         self.telemetry: List[Dict] = []
         self._tick_ema: Optional[float] = None
+        #: True while ``_prefill_into_slot`` steps a prompt (span phase)
+        self._in_refill = False
+        #: ``prefill_ticks`` at the last telemetry record
+        self._refills_recorded = 0
 
         self.pdecode = None
         self.monitor = None
@@ -226,8 +230,10 @@ class Engine:
     # -- stepping ------------------------------------------------------------
 
     def _raw_step(self):
-        toks = jnp.asarray(self.tokens)
-        if self.pdecode is not None:
+        with span("step.dispatch"):
+            toks = jnp.asarray(self.tokens)
+            if self.pdecode is None:
+                return self.decode(self.params, self.cache, toks)
             lmask, hmask = self.monitor.ok_masks()
             if self.sentinel:
                 logits, new_cache, self._last_sat = self.pdecode.step(
@@ -240,33 +246,39 @@ class Engine:
                 neg = jnp.full((self.cfg.padded_vocab - self.cfg.vocab,),
                                -1e30, logits.dtype)
                 logits = logits.at[..., self.cfg.vocab:].set(neg)
-        else:
-            logits, new_cache = self.decode(self.params, self.cache, toks)
-        return logits, new_cache
+            return logits, new_cache
 
     def _step(self):
-        # chaos clock: fire every due injection exactly once, before the
-        # forward — a raise here surfaces as a step fault (restore + replay)
-        for k in sorted(k for k in self.chaos if k <= self.steps):
-            for act in self.chaos.pop(k):
-                act(self)
-        self.steps += 1
-        if self.step_cost_s is not None:
-            self.clock.sleep(self.step_cost_s)  # simulated service time
-        logits, new_cache = self._raw_step()
-        # finite gate BEFORE committing: NaN/Inf outputs (poisoned state,
-        # numerical blowup) trigger restore-and-replay, never a sampled token.
-        # The recurrent state must be gated too, not just the logits: the
-        # PCILT path quantizes activations to integer table indices, which
-        # *launders* NaN into a valid (wrong) lookup — poisoned ssd state
-        # yields finite logits while the corruption persists in the cache.
-        checks = [jnp.all(jnp.isfinite(logits))]
-        checks += [jnp.all(jnp.isfinite(l)) for l in jax.tree.leaves(new_cache)
-                   if jnp.issubdtype(l.dtype, jnp.floating)]
-        if not bool(jnp.all(jnp.stack(checks))):
-            raise RuntimeError("non-finite decode outputs or state (NaN/Inf)")
-        self.cache = new_cache
-        return np.asarray(jnp.argmax(logits, axis=-1))
+        phase = "prefill" if self._in_refill else "decode"
+        with span("step", phase=phase, step=self.steps):
+            # chaos clock: fire every due injection exactly once, before the
+            # forward — a raise here surfaces as a step fault (restore +
+            # replay)
+            for k in sorted(k for k in self.chaos if k <= self.steps):
+                for act in self.chaos.pop(k):
+                    act(self)
+            self.steps += 1
+            if self.step_cost_s is not None:
+                self.clock.sleep(self.step_cost_s)  # simulated service time
+            logits, new_cache = self._raw_step()
+            # finite gate BEFORE committing: NaN/Inf outputs (poisoned state,
+            # numerical blowup) trigger restore-and-replay, never a sampled
+            # token.  The recurrent state must be gated too, not just the
+            # logits: the PCILT path quantizes activations to integer table
+            # indices, which *launders* NaN into a valid (wrong) lookup —
+            # poisoned ssd state yields finite logits while the corruption
+            # persists in the cache.
+            with span("step.gate"):
+                checks = [jnp.all(jnp.isfinite(logits))]
+                checks += [jnp.all(jnp.isfinite(l))
+                           for l in jax.tree.leaves(new_cache)
+                           if jnp.issubdtype(l.dtype, jnp.floating)]
+                if not bool(jnp.all(jnp.stack(checks))):
+                    raise RuntimeError(
+                        "non-finite decode outputs or state (NaN/Inf)")
+            self.cache = new_cache
+            with span("step.sample"):
+                return np.asarray(jnp.argmax(logits, axis=-1))
 
     def _prefill_into_slot(self, slot: int, req: Request):
         """Feed the prompt through decode steps (teacher-forced prefill).
@@ -280,23 +292,30 @@ class Engine:
         committed, not dropped (dropping them skipped every token a slot
         sampled while a neighbor prefilled).  The step that consumes the
         final prompt token emits the request's first generated token."""
-        req.outcome = "active"
-        req.t_admit = self.clock.time()
-        # an idle slot still steps with the batch (its outputs dropped), so
-        # its recurrent state is garbage by now — start from a clean slate or
-        # the request's tokens depend on what the slot did while unowned
-        self._reset_slot(slot)
-        last = 0
-        for t in req.prompt:
-            self.tokens[slot, 0] = int(t)
-            out = self._step()
-            self.prefill_ticks += 1
-            self._commit_tokens(out, skip=slot)
-            last = int(out[slot])
-        self.active[slot] = req
-        req.out.append(last)
-        self.tokens[slot, 0] = last
-        self._finish_if_done(slot)
+        with span("refill", rid=req.rid, slot=slot,
+                  prompt_len=len(req.prompt)):
+            req.outcome = "active"
+            req.t_admit = self.clock.time()
+            # an idle slot still steps with the batch (its outputs dropped),
+            # so its recurrent state is garbage by now — start from a clean
+            # slate or the request's tokens depend on what the slot did while
+            # unowned
+            self._reset_slot(slot)
+            last = 0
+            self._in_refill = True
+            try:
+                for t in req.prompt:
+                    self.tokens[slot, 0] = int(t)
+                    out = self._step()
+                    self.prefill_ticks += 1
+                    self._commit_tokens(out, skip=slot)
+                    last = int(out[slot])
+            finally:
+                self._in_refill = False
+            self.active[slot] = req
+            req.out.append(last)
+            self.tokens[slot, 0] = last
+            self._finish_if_done(slot)
 
     def _commit_tokens(self, nxt, skip: Optional[int] = None):
         # tainted = some layer was online-recalibrated: tokens are correct
@@ -304,15 +323,19 @@ class Engine:
         # conversion, so they carry the degraded marking too
         degraded_now = self.monitor is not None and (
             self.monitor.degraded or self.monitor.tainted)
-        for s, req in enumerate(self.active):
-            if req is None or s == skip:
-                continue
-            tok = int(nxt[s])
-            req.out.append(tok)
-            self.tokens[s, 0] = tok
-            if degraded_now:
-                req.degraded = True
-            self._finish_if_done(s)
+        with span("commit") as sp:
+            committed = 0
+            for s, req in enumerate(self.active):
+                if req is None or s == skip:
+                    continue
+                tok = int(nxt[s])
+                req.out.append(tok)
+                self.tokens[s, 0] = tok
+                committed += 1
+                if degraded_now:
+                    req.degraded = True
+                self._finish_if_done(s)
+            sp.set_metadata(tokens=committed)
 
     def _finish_if_done(self, s: int):
         req = self.active[s]
@@ -339,20 +362,21 @@ class Engine:
     def _checkpoint(self):
         """Snapshot the full engine state (jax arrays are immutable — holding
         the refs *is* the snapshot; host-side state is copied)."""
-        self.ckpts.append({
-            "tick": self.tick,
-            "cache": self.cache,
-            "tokens": self.tokens.copy(),
-            "active": list(self.active),
-            "queue": list(self.queue),
-            "pending": list(self._pending),
-            "queue_evictions": self.queue_evictions,
-            "slot_evictions": self.slot_evictions,
-            "reqs": {r.rid: (list(r.out), r.done, r.outcome, r.retries,
-                             r.degraded, r.t_admit, r.not_before,
-                             r.t_arrive, r.t_enqueue, r.t_done)
-                     for r in self._requests},
-        })
+        with span("checkpoint"):
+            self.ckpts.append({
+                "tick": self.tick,
+                "cache": self.cache,
+                "tokens": self.tokens.copy(),
+                "active": list(self.active),
+                "queue": list(self.queue),
+                "pending": list(self._pending),
+                "queue_evictions": self.queue_evictions,
+                "slot_evictions": self.slot_evictions,
+                "reqs": {r.rid: (list(r.out), r.done, r.outcome, r.retries,
+                                 r.degraded, r.t_admit, r.not_before,
+                                 r.t_arrive, r.t_enqueue, r.t_done)
+                         for r in self._requests},
+            })
 
     def _restore(self, target_tick: int):
         """Restore the newest checkpoint at or before ``target_tick``
@@ -469,60 +493,63 @@ class Engine:
     # -- deadlines -----------------------------------------------------------
 
     def _enforce_deadlines(self):
-        now = self.clock.time()
-        for s, req in enumerate(self.active):
-            if req is None or req.deadline_s is None:
-                continue
-            if now - req.t_admit <= req.deadline_s:
-                continue
-            self.active[s] = None
-            self._reset_slot(s)
-            self.slot_evictions += 1
-            req.out = []
-            req.degraded = False
-            req.retries += 1
-            if req.retries > req.max_retries:
-                req.done = True
-                req.outcome = "failed"
-                req.t_done = now
-                log.error("req %d failed: deadline %.3fs exceeded %d times",
-                          req.rid, req.deadline_s, req.retries)
-            else:
-                req.not_before = now + 0.05 * (2 ** (req.retries - 1))
-                req.outcome = "queued"
-                # the fresh attempt's deadline window opens when the backoff
-                # expires — clocking it from the requeue instant would let a
-                # backoff longer than the deadline evict the request forever
-                req.t_enqueue = req.not_before
-                self.queue.append(req)
-                log.warning("req %d missed deadline; requeued (retry %d/%d, "
-                            "backoff %.3fs)", req.rid, req.retries,
-                            req.max_retries, req.not_before - now)
-        # queue-side enforcement: a request past its attempt deadline while
-        # *still queued* is evicted here — before it burns prefill ticks on
-        # an attempt that cannot meet its deadline anyway
-        still: List[Request] = []
-        for req in self.queue:
-            if req.deadline_s is None or now - req.t_enqueue <= req.deadline_s:
-                still.append(req)
-                continue
-            self.queue_evictions += 1
-            req.retries += 1
-            if req.retries > req.max_retries:
-                req.done = True
-                req.outcome = "failed"
-                req.t_done = now
-                log.error("req %d failed: deadline %.3fs expired in queue "
-                          "(%d attempts)", req.rid, req.deadline_s,
-                          req.retries)
-            else:
-                req.not_before = now + 0.05 * (2 ** (req.retries - 1))
-                req.t_enqueue = req.not_before  # window opens post-backoff
-                still.append(req)
-                log.warning("req %d deadline expired while queued; attempt "
-                            "window reset (retry %d/%d)", req.rid,
-                            req.retries, req.max_retries)
-        self.queue = still
+        with span("deadlines"):
+            now = self.clock.time()
+            for s, req in enumerate(self.active):
+                if req is None or req.deadline_s is None:
+                    continue
+                if now - req.t_admit <= req.deadline_s:
+                    continue
+                self.active[s] = None
+                self._reset_slot(s)
+                self.slot_evictions += 1
+                req.out = []
+                req.degraded = False
+                req.retries += 1
+                if req.retries > req.max_retries:
+                    req.done = True
+                    req.outcome = "failed"
+                    req.t_done = now
+                    log.error("req %d failed: deadline %.3fs exceeded %d "
+                              "times", req.rid, req.deadline_s, req.retries)
+                else:
+                    req.not_before = now + 0.05 * (2 ** (req.retries - 1))
+                    req.outcome = "queued"
+                    # the fresh attempt's deadline window opens when the
+                    # backoff expires — clocking it from the requeue instant
+                    # would let a backoff longer than the deadline evict the
+                    # request forever
+                    req.t_enqueue = req.not_before
+                    self.queue.append(req)
+                    log.warning("req %d missed deadline; requeued (retry "
+                                "%d/%d, backoff %.3fs)", req.rid, req.retries,
+                                req.max_retries, req.not_before - now)
+            # queue-side enforcement: a request past its attempt deadline
+            # while *still queued* is evicted here — before it burns prefill
+            # ticks on an attempt that cannot meet its deadline anyway
+            still: List[Request] = []
+            for req in self.queue:
+                if req.deadline_s is None or \
+                        now - req.t_enqueue <= req.deadline_s:
+                    still.append(req)
+                    continue
+                self.queue_evictions += 1
+                req.retries += 1
+                if req.retries > req.max_retries:
+                    req.done = True
+                    req.outcome = "failed"
+                    req.t_done = now
+                    log.error("req %d failed: deadline %.3fs expired in queue "
+                              "(%d attempts)", req.rid, req.deadline_s,
+                              req.retries)
+                else:
+                    req.not_before = now + 0.05 * (2 ** (req.retries - 1))
+                    req.t_enqueue = req.not_before  # window opens post-backoff
+                    still.append(req)
+                    log.warning("req %d deadline expired while queued; "
+                                "attempt window reset (retry %d/%d)", req.rid,
+                                req.retries, req.max_retries)
+            self.queue = still
 
     # -- main loop -----------------------------------------------------------
 
@@ -548,6 +575,81 @@ class Engine:
                          key=lambda p: p[0])
         return self._serve(pending)
 
+    def _serve_tick(self, watchdog: StepWatchdog):
+        """One iteration of the serving loop: admit and refill free slots,
+        then one decode step, its health pass, commit, deadlines, telemetry
+        and checkpoint (a tick with no active slot waits instead)."""
+        t_tick = self.clock.time()
+        now = t_tick
+        with span("admit") as sp:
+            self._admit_arrivals(now)
+            admitted = 0
+            for s in range(self.slots):
+                if self.active[s] is not None or not self.queue:
+                    continue
+                i = self._edf_pick(now)
+                if i is None:
+                    break  # every queued request is backing off
+                self._prefill_into_slot(s, self.queue.pop(i))
+                admitted += 1
+            sp.set_metadata(admitted=admitted)
+        if not any(r is not None for r in self.active):
+            if self.queue:
+                self.clock.sleep(0.005)  # wait out shortest backoff
+                self._enforce_deadlines()  # backoff may outlive one
+            elif self._pending:
+                nxt = min(t for t, _ in self._pending)
+                self.clock.sleep(max(nxt - now, 1e-9))
+            return
+        t_step = self.clock.time()
+        nxt = self._step()
+        t_monitor = self.clock.time()
+        if self.monitor is not None:
+            breaches = self.monitor.on_tick(
+                self.tick, sat=self._last_sat, rows=self.slots)
+            if breaches:
+                # commits since the breached layer was last verified may be
+                # corrupt — rewind there and replay demoted.  Drift is
+                # different: committed tokens were produced inside the
+                # calibrated range (the counters fired on *this* tick's
+                # activations), so it indicts only the current,
+                # not-yet-committed tick.
+                lv = [int(self.monitor.last_verified[e["layer"]])
+                      for e in breaches
+                      if e["layer"] is not None and e["kind"] != "drift"]
+                lv += [int(self.monitor.head_last_verified)
+                       for e in breaches if e["kind"] == "head"]
+                lv += [self.tick for e in breaches if e["kind"] == "drift"]
+                raise _Degraded(max(min(lv), 0), breaches)
+        t_commit = self.clock.time()
+        self._commit_tokens(nxt)
+        self._enforce_deadlines()
+        dt = self.clock.time() - t_tick
+        watchdog.observe(self.tick, dt)
+        self._tick_ema = (dt if self._tick_ema is None
+                          else 0.9 * self._tick_ema + 0.1 * dt)
+        occupied = sum(r is not None for r in self.active)
+        entry = {
+            "tick": self.tick,
+            "t": self.clock.time(),
+            "queue_depth": len(self.queue),
+            "pending": len(self._pending),
+            "active_slots": occupied,
+            "occupancy": occupied / self.slots,
+            "queue_evictions": self.queue_evictions,
+            "slot_evictions": self.slot_evictions,
+            "tick_s": dt,
+            "refill_steps": self.prefill_ticks - self._refills_recorded,
+            "step_s": t_monitor - t_step,
+            "monitor_s": t_commit - t_monitor,
+        }
+        self._refills_recorded = self.prefill_ticks
+        if self.sentinel and self.monitor is not None:
+            entry["saturation"] = self.monitor.saturation_summary()
+        self.telemetry.append(entry)
+        self.tick += 1
+        self._checkpoint()
+
     def _serve(self, pending: List[Tuple[float, Request]]):
         self._requests = [r for _, r in pending]
         self._pending = list(pending)
@@ -557,6 +659,7 @@ class Engine:
         t0 = self.clock.time()
         self.tick = 0
         self.prefill_ticks = 0
+        self._refills_recorded = 0
         self.queue_evictions = 0
         self.slot_evictions = 0
         self.telemetry = []
@@ -566,85 +669,35 @@ class Engine:
         watchdog = StepWatchdog()
         while (self._pending or self.queue
                or any(r is not None for r in self.active)):
-            try:
-                t_tick = self.clock.time()
-                now = t_tick
-                self._admit_arrivals(now)
-                for s in range(self.slots):
-                    if self.active[s] is not None or not self.queue:
-                        continue
-                    i = self._edf_pick(now)
-                    if i is None:
-                        break  # every queued request is backing off
-                    self._prefill_into_slot(s, self.queue.pop(i))
-                if not any(r is not None for r in self.active):
-                    if self.queue:
-                        self.clock.sleep(0.005)  # wait out shortest backoff
-                        self._enforce_deadlines()  # backoff may outlive one
-                    elif self._pending:
-                        nxt = min(t for t, _ in self._pending)
-                        self.clock.sleep(max(nxt - now, 1e-9))
-                    continue
-                nxt = self._step()
-                if self.monitor is not None:
-                    breaches = self.monitor.on_tick(
-                        self.tick, sat=self._last_sat, rows=self.slots)
-                    if breaches:
-                        # commits since the breached layer was last verified
-                        # may be corrupt — rewind there and replay demoted.
-                        # Drift is different: committed tokens were produced
-                        # inside the calibrated range (the counters fired on
-                        # *this* tick's activations), so it indicts only the
-                        # current, not-yet-committed tick.
-                        lv = [int(self.monitor.last_verified[e["layer"]])
-                              for e in breaches
-                              if e["layer"] is not None
-                              and e["kind"] != "drift"]
-                        lv += [int(self.monitor.head_last_verified)
-                               for e in breaches if e["kind"] == "head"]
-                        lv += [self.tick for e in breaches
-                               if e["kind"] == "drift"]
-                        raise _Degraded(max(min(lv), 0), breaches)
-                self._commit_tokens(nxt)
-                self._enforce_deadlines()
-                dt = self.clock.time() - t_tick
-                watchdog.observe(self.tick, dt)
-                self._tick_ema = (dt if self._tick_ema is None
-                                  else 0.9 * self._tick_ema + 0.1 * dt)
-                occupied = sum(r is not None for r in self.active)
-                entry = {
-                    "tick": self.tick,
-                    "t": self.clock.time(),
-                    "queue_depth": len(self.queue),
-                    "pending": len(self._pending),
-                    "active_slots": occupied,
-                    "occupancy": occupied / self.slots,
-                    "queue_evictions": self.queue_evictions,
-                    "slot_evictions": self.slot_evictions,
-                    "tick_s": dt,
-                }
-                if self.sentinel and self.monitor is not None:
-                    entry["saturation"] = self.monitor.saturation_summary()
-                self.telemetry.append(entry)
-                self.tick += 1
-                self._checkpoint()
-            except _Degraded as d:
-                self.rollbacks += 1
-                log.warning("rolling back to tick <= %d after %d breach(es)",
-                            d.target_tick, len(d.events))
-                self._restore(d.target_tick)
-                if self.monitor is not None and self.monitor.drift_pending:
-                    # online recalibration between ticks: rebuild the drifted
-                    # layer's tables at the observed range and repromote (or
-                    # record the typed sticky event), then replay
-                    self.monitor.recalibrate_pending(self.tick)
-            except Exception as e:  # noqa: BLE001 — any tick fault
-                self.restarts += 1
-                log.error("decode tick %d failed (%s); restart %d/%d",
-                          self.tick, e, self.restarts, self.max_restarts)
-                if self.restarts > self.max_restarts:
-                    raise
-                self._restore(self.tick)
+            with span("tick", tick=self.tick, queue=len(self.queue),
+                      active=sum(r is not None for r in self.active)):
+                try:
+                    self._serve_tick(watchdog)
+                except _Degraded as d:
+                    self.rollbacks += 1
+                    log.warning("rolling back to tick <= %d after %d "
+                                "breach(es)", d.target_tick, len(d.events))
+                    with span("recover", kind="rollback",
+                              target_tick=d.target_tick):
+                        self._restore(d.target_tick)
+                    if self.monitor is not None and \
+                            self.monitor.drift_pending:
+                        # online recalibration between ticks: rebuild the
+                        # drifted layer's tables at the observed range and
+                        # repromote (or record the typed sticky event), then
+                        # replay
+                        with span("recover", kind="recalibrate",
+                                  target_tick=self.tick):
+                            self.monitor.recalibrate_pending(self.tick)
+                except Exception as e:  # noqa: BLE001 — any tick fault
+                    self.restarts += 1
+                    log.error("decode tick %d failed (%s); restart %d/%d",
+                              self.tick, e, self.restarts, self.max_restarts)
+                    if self.restarts > self.max_restarts:
+                        raise
+                    with span("recover", kind="restart",
+                              target_tick=self.tick):
+                        self._restore(self.tick)
         dt = self.clock.time() - t0
         # outcome accounting from final request state — replays through the
         # checkpoint ring can never double-count
@@ -674,6 +727,7 @@ class Engine:
         }
         if self.monitor is not None:
             stats["health_events"] = list(self.monitor.events)
+            stats.update(self.monitor.counters())
             if self.sentinel:
                 stats["saturation"] = self.monitor.saturation_summary()
                 stats["recalibrations"] = int(

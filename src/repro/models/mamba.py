@@ -378,7 +378,8 @@ class MambaLM:
                 "with_stats reports the PCILT quantizers' saturation — it "
                 "requires a pcilt bundle (got pcilt=None)")
         pos = cache["pos"]
-        x = self._embed(params, ctx, tokens)
+        with jax.named_scope("embed"):
+            x = self._embed(params, ctx, tokens)
         proj = None if pcilt is None else pcilt.get("proj")
         # the projections' table mesh: every other kernel of the step runs
         # replicated on it (core.lut_layers.replicated_on)
@@ -422,14 +423,19 @@ class MambaLM:
                 per["ok"] = jnp.asarray(layer_ok, bool)
             if per:
                 xs = xs + (per,)
-        x, ys = jax.lax.scan(body, x, xs)
+        # device scopes (op_name metadata, so a trace names each op's part
+        # of the step): embed, blocks (in_proj, conv, ssd, out_proj inside
+        # each layer), head
+        with jax.named_scope("blocks"):
+            x, ys = jax.lax.scan(body, x, xs)
         new_states, sat = ys if with_stats else (ys, None)
         x = rmsnorm(params["ln_f"], x, cfg.norm_eps)
         head = None if pcilt is None else pcilt.get("head")
-        if head is None:
-            logits = self._logits(params, x)[:, -1]
-        else:
-            logits = self._head_logits(head, x[:, -1], head_ok, mesh)
+        with jax.named_scope("head"):
+            if head is None:
+                logits = self._logits(params, x)[:, -1]
+            else:
+                logits = self._head_logits(head, x[:, -1], head_ok, mesh)
         new_cache = dict(cache, layers=new_states, pos=pos + 1)
         if with_stats:
             return logits, new_cache, sat

@@ -447,41 +447,45 @@ def mamba_decode(
     d_inner, H, _ = _dims(cfg)
     proj = None if pcilt is None else pcilt.get("proj")
     stats = {}
-    z = _proj(params, "wz", x, cfg, proj)
-    xi = _proj(params, "wx", x, cfg, proj, with_stats=with_stats)
-    if with_stats:
-        xi, count, ratio = xi
-        stats["in"] = {"count": count, "ratio": ratio}
-    Bi = _proj(params, "wB", x, cfg, proj)
-    Ci = _proj(params, "wC", x, cfg, proj)
-    dt = _proj(params, "wdt", x, cfg, proj).astype(jnp.float32)
+    with jax.named_scope("in_proj"):
+        z = _proj(params, "wz", x, cfg, proj)
+        xi = _proj(params, "wx", x, cfg, proj, with_stats=with_stats)
+        if with_stats:
+            xi, count, ratio = xi
+            stats["in"] = {"count": count, "ratio": ratio}
+        Bi = _proj(params, "wB", x, cfg, proj)
+        Ci = _proj(params, "wC", x, cfg, proj)
+        dt = _proj(params, "wdt", x, cfg, proj).astype(jnp.float32)
 
-    xBC = jnp.concatenate([xi, Bi, Ci], axis=-1)
-    conv = _conv1d(params, cfg, xBC, state["conv"], pcilt=pcilt,
-                   with_stats=with_stats)
-    if with_stats:
-        xBC, conv_state, count, ratio = conv
-        stats["conv"] = {"count": count, "ratio": ratio}
-    else:
-        xBC, conv_state = conv
-    xBC = jax.nn.silu(xBC)
-    xi, Bi, Ci = jnp.split(
-        xBC, [d_inner, d_inner + s.n_groups * s.d_state], axis=-1
-    )
+    with jax.named_scope("conv"):
+        xBC = jnp.concatenate([xi, Bi, Ci], axis=-1)
+        conv = _conv1d(params, cfg, xBC, state["conv"], pcilt=pcilt,
+                       with_stats=with_stats)
+        if with_stats:
+            xBC, conv_state, count, ratio = conv
+            stats["conv"] = {"count": count, "ratio": ratio}
+        else:
+            xBC, conv_state = conv
+        xBC = jax.nn.silu(xBC)
+        xi, Bi, Ci = jnp.split(
+            xBC, [d_inner, d_inner + s.n_groups * s.d_state], axis=-1
+        )
 
-    dt = jax.nn.softplus(dt + params["dt_bias"].astype(jnp.float32))[:, 0]  # [B,H]
-    A = -jnp.exp(params["A_log"].astype(jnp.float32))
-    xh, Bm, Cm = _split_heads(cfg, ctx, xi, Bi, Ci, dt)
-    xh1, Bm1, Cm1 = xh[:, 0].astype(jnp.float32), Bm[:, 0].astype(jnp.float32), Cm[:, 0].astype(jnp.float32)
+    with jax.named_scope("ssd"):
+        dt = jax.nn.softplus(dt + params["dt_bias"].astype(jnp.float32))[:, 0]  # [B,H]
+        A = -jnp.exp(params["A_log"].astype(jnp.float32))
+        xh, Bm, Cm = _split_heads(cfg, ctx, xi, Bi, Ci, dt)
+        xh1, Bm1, Cm1 = xh[:, 0].astype(jnp.float32), Bm[:, 0].astype(jnp.float32), Cm[:, 0].astype(jnp.float32)
 
-    dA = jnp.exp(dt * A[None])                        # [B,H]
-    h = state["ssd"].astype(jnp.float32)
-    h = h * dA[..., None, None] + jnp.einsum(
-        "bhn,bhp->bhnp", Bm1 * dt[..., None], xh1
-    )
-    y = jnp.einsum("bhn,bhnp->bhp", Cm1, h)[:, None]  # [B,1,H,P]
-    out = _finish(params, cfg, ctx, y.astype(cfg.dtype), xh, z, proj=proj,
-                  with_stats=with_stats)
+        dA = jnp.exp(dt * A[None])                        # [B,H]
+        h = state["ssd"].astype(jnp.float32)
+        h = h * dA[..., None, None] + jnp.einsum(
+            "bhn,bhp->bhnp", Bm1 * dt[..., None], xh1
+        )
+        y = jnp.einsum("bhn,bhnp->bhp", Cm1, h)[:, None]  # [B,1,H,P]
+    with jax.named_scope("out_proj"):
+        out = _finish(params, cfg, ctx, y.astype(cfg.dtype), xh, z, proj=proj,
+                      with_stats=with_stats)
     new_state = {"conv": conv_state.astype(state["conv"].dtype),
                  "ssd": h.astype(state["ssd"].dtype)}
     if with_stats:
