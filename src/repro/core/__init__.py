@@ -37,6 +37,8 @@ from .pcilt import (
     shared_pool_bytes,
     build_cost_multiplies,
     table_checksum,
+    table_checksums,
+    slice_checksums,
     stacked_checksums,
 )
 from .lut_layers import (

@@ -62,7 +62,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import zlib
 from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -91,8 +90,9 @@ __all__ = [
     "shared_table_bytes",
     "shared_pool_bytes",
     "build_cost_multiplies",
-    "host_copy",
     "table_checksum",
+    "table_checksums",
+    "slice_checksums",
     "stacked_checksums",
 ]
 
@@ -630,35 +630,124 @@ def build_cost_multiplies(n_weights: int, act_bits: int) -> int:
 # ----------------------------------------------------------------------------
 # Table integrity (serving resilience).  Tables are immutable deployment
 # artifacts — any in-memory difference from the conversion-time bytes is
-# corruption.  CRC-32 detects *every* error burst of <= 32 bits, so a single
-# flipped table entry (float32/bfloat16 value, int32 seg_idx pointer) can
-# never be missed — the zero-false-negative property the chaos suite
-# unit-tests.
+# corruption.  The checksum is a jitted reduction over the device array
+# itself, so it reads the bytes the kernels read, and it never copies a
+# table to the host.  Its record holds two 32-bit lanes over the array's
+# bytes viewed as little-endian uint32 words w_i (16- and 8-bit entries
+# packed to a word, the tail zero-padded):
+#
+# * lane 1, sum(w_i) mod 2**32: any change inside one word, and any error
+#   burst of <= 32 bits in the byte stream, is always caught — such a burst
+#   changes word i by a nonzero multiple of 2**a and word i+1 by less than
+#   2**a, so the two can never cancel.  A single flipped table entry
+#   (float32/bfloat16 value, int32 seg_idx pointer) can never be missed —
+#   the zero-false-negative property the chaos suite unit-tests;
+# * lane 2, sum(fmix32(w_i ^ h(i))) mod 2**32, with ``fmix32`` MurmurHash3's
+#   bijective finalizer and h a position hash: swapped entries and changes
+#   to several words are missed with probability about 2**-32.
+#
+# Both lanes are wrapping sums, so the record depends neither on XLA's
+# reduction order nor on how the array is sharded: a sharded table reduces
+# in place and records what the unsharded one does.
 # ----------------------------------------------------------------------------
 
+_WEYL = 0x9E3779B9  # position hash h(i) = i * 2**32 / golden ratio
 
-def host_copy(arr) -> np.ndarray:
-    """``arr`` on the host (gathers sharded arrays); a device array keeps
-    the copy for later calls, so only the first one transfers."""
-    if isinstance(arr, np.ndarray):
-        return arr
-    with span("integrity.host_copy", bytes=int(arr.nbytes)):
-        return np.asarray(arr)
+
+def _fmix32(h: jax.Array) -> jax.Array:
+    h = h ^ (h >> 16)
+    h = h * jnp.uint32(0x85EBCA6B)
+    h = h ^ (h >> 13)
+    h = h * jnp.uint32(0xC2B2AE35)
+    return h ^ (h >> 16)
+
+
+def _words(x: jax.Array) -> jax.Array:
+    """``x``'s bytes (1-, 2- or 4-byte entries) as little-endian
+    ``uint32`` words: narrower entries pack along the last axis (the array
+    is flattened and zero-padded first when that axis does not divide into
+    words)."""
+    size = x.dtype.itemsize
+    if size == 4:
+        return jax.lax.bitcast_convert_type(x, jnp.uint32)
+    per = 4 // size
+    u = jax.lax.bitcast_convert_type(x, jnp.dtype(f"uint{8 * size}"))
+    if u.ndim == 0 or u.shape[-1] % per:
+        u = u.reshape(-1)
+        u = jnp.pad(u, (0, -u.size % per))
+    return jax.lax.bitcast_convert_type(
+        u.reshape(*u.shape[:-1], -1, per), jnp.uint32)
+
+
+def _lanes(x: jax.Array) -> jax.Array:
+    """The checksum's two lanes over all of ``x``: ``uint32[2]``."""
+    w = _words(x)
+    i = jnp.zeros(w.shape, jnp.uint32)
+    stride = 1
+    for d in reversed(range(w.ndim)):  # row-major word index
+        i = i + jax.lax.broadcasted_iota(jnp.uint32, w.shape, d) * \
+            jnp.uint32(stride % (1 << 32))
+        stride *= w.shape[d]
+    return jnp.stack([
+        jnp.sum(w, dtype=jnp.uint32),
+        jnp.sum(_fmix32(w ^ (i * jnp.uint32(_WEYL))), dtype=jnp.uint32)])
+
+
+@jax.jit
+def _table_lanes(*arrays) -> jax.Array:
+    with jax.named_scope("checksum"):
+        return jnp.stack([_lanes(a) for a in arrays])
+
+
+@functools.partial(jax.jit, static_argnames="axes")
+def _slice_lanes(index, *stacks, axes) -> jax.Array:
+    # the slice is read in place: the dynamic slice fuses into the
+    # reductions, so no copy of it is made
+    with jax.named_scope("checksum"):
+        return jnp.stack([
+            _lanes(jax.lax.dynamic_index_in_dim(t, index, a, keepdims=False))
+            for t, a in zip(stacks, axes)])
+
+
+def _records(lanes) -> List[int]:
+    """``[n, 2]`` lanes -> one 64-bit record (a Python int) per row: lane
+    1 in the low 32 bits, lane 2 in the high."""
+    return [int(a) | int(b) << 32
+            for a, b in np.asarray(jax.device_get(lanes), np.uint64)]
+
+
+def table_checksums(*arrays) -> List[int]:
+    """The checksum record of each whole array, in one device call."""
+    with span("integrity.checksum", bytes=sum(int(a.nbytes) for a in arrays)):
+        return _records(_table_lanes(*arrays))
 
 
 def table_checksum(arr) -> int:
-    """CRC-32 over the raw bytes of a table array (gathers sharded arrays)."""
-    a = host_copy(arr)
-    with span("integrity.crc32", bytes=int(a.nbytes)):
-        return zlib.crc32(np.ascontiguousarray(a).tobytes())
+    """The checksum record of a whole table array (sharded arrays reduce
+    in place)."""
+    return table_checksums(arr)[0]
+
+
+def slice_checksums(index: int, stacks: Sequence, axes: Sequence[int]
+                    ) -> List[int]:
+    """The record of slice ``index`` of each stack along its axis, in one
+    device call (``index`` is traced: one compile serves every layer)."""
+    axes = tuple(int(a) for a in axes)
+    with span("integrity.checksum",
+              bytes=sum(int(t.nbytes) // t.shape[a]
+                        for t, a in zip(stacks, axes))):
+        return _records(_slice_lanes(int(index), *stacks, axes=axes))
 
 
 def stacked_checksums(arr, axis: int = 0) -> List[int]:
-    """Per-layer CRC-32s for a stacked table — one checksum per slice along
+    """Per-layer records for a stacked table — one per slice along
     ``axis``, so verification localizes a breach to the layer that must be
     demoted.  Dense stacks are layer-major (``[L, G, V, O]``, ``axis=0``);
-    paired stacks are segment-major (``[G2, L, V**2, O]``, ``axis=1``)."""
-    a = host_copy(arr)
-    if axis:
-        a = np.moveaxis(a, axis, 0)
-    return [table_checksum(a[i]) for i in range(a.shape[0])]
+    paired stacks are segment-major (``[G2, L, V**2, O]``, ``axis=1``).
+    Each slice goes through the function of :func:`slice_checksums`, so a
+    record equals the layer check ``PCILTMambaDecode.verify_layer`` makes.
+    """
+    with span("integrity.checksum", bytes=int(arr.nbytes)):
+        return _records(jnp.concatenate([
+            _slice_lanes(i, arr, axes=(int(axis),))
+            for i in range(arr.shape[axis])]))

@@ -27,8 +27,8 @@ from .quantization import (QuantSpec, calibrate, fake_quant, quantize,
                            dequantize, scale_from_amax)
 from .pcilt import (SharedGroupedTables, ShardedSharedPool,
                     build_grouped_tables, build_shared_grouped_tables,
-                    host_copy, shard_shared_grouped_tables,
-                    stacked_checksums, table_checksum)
+                    shard_shared_grouped_tables, slice_checksums,
+                    stacked_checksums, table_checksum, table_checksums)
 from .lut_layers import (build_dwconv_tables, mesh_shard_count, pcilt_conv2d,
                          pcilt_depthwise_conv1d, pcilt_linear)
 from repro.runtime.tracing import span
@@ -42,11 +42,14 @@ __all__ = ["PCILTLinear", "PCILTConv2d", "PCILTDwConv1d", "convert_kernel",
 
 
 def pcilt_integrity(pcilt: Dict) -> Dict:
-    """Conversion-time CRC-32 record of every table array in a Mamba PCILT
-    bundle — per layer for the stacked arrays, so verification localizes a
-    breach to the layer the health monitor must demote.  CRC-32 detects all
-    error bursts of <= 32 bits: a single flipped table entry (f32/bf16
-    value, int32 pointer) can never slip through."""
+    """Conversion-time checksum record of every table array in a Mamba
+    PCILT bundle (``core.pcilt.table_checksum``, computed on the device
+    over the arrays' own bytes) — per layer for the stacked arrays, so
+    verification localizes a breach to the layer the health monitor must
+    demote.  A change inside one 32-bit word and any error burst of <= 32
+    bits are always caught, so a single flipped table entry (f32/bf16
+    value, int32 pointer) can never slip through; any other change is
+    missed with probability about 2**-32."""
     integ: Dict[str, Any] = {"conv": stacked_checksums(pcilt["tables"])}
     proj = pcilt.get("proj")
     if proj is not None:
@@ -57,8 +60,8 @@ def pcilt_integrity(pcilt: Dict) -> Dict:
                         for name, t in proj["tables"].items()}
     head = pcilt.get("head")
     if head is not None:
-        integ["head"] = {"pool": table_checksum(head["pool"]),
-                         "seg_idx": table_checksum(head["seg_idx"])}
+        pool, seg_idx = table_checksums(head["pool"], head["seg_idx"])
+        integ["head"] = {"pool": pool, "seg_idx": seg_idx}
     return integ
 
 
@@ -126,8 +129,8 @@ class PCILTLinear:
         if tables is not None:
             self.integrity["tables"] = table_checksum(tables)
         if shared is not None:
-            self.integrity["pool"] = table_checksum(shared.pool)
-            self.integrity["seg_idx"] = table_checksum(shared.seg_idx)
+            self.integrity["pool"], self.integrity["seg_idx"] = \
+                table_checksums(shared.pool, shared.seg_idx)
         self.shard_pools: Optional[ShardedSharedPool] = None
         if mesh is not None and self.shard_count > 1:
             if shared is not None:
@@ -183,8 +186,8 @@ class PCILTLinear:
         if self.tables is not None:
             cur["tables"] = table_checksum(self.tables)
         if self.shared is not None:
-            cur["pool"] = table_checksum(self.shared.pool)
-            cur["seg_idx"] = table_checksum(self.shared.seg_idx)
+            cur["pool"], cur["seg_idx"] = table_checksums(
+                self.shared.pool, self.shared.seg_idx)
         return {k: cur[k] == v for k, v in self.integrity.items()}
 
     def _pad_x(self, x: jax.Array) -> jax.Array:
@@ -530,8 +533,11 @@ class PCILTMambaDecode:
     shapes under a mesh), so the jitted dispatch hits the lookup table at
     trace time.
 
-    Integrity: the bundle carries a conversion-time CRC-32 record per table
-    (per layer for the stacked arrays); it is verified at load
+    Integrity: the bundle carries a conversion-time checksum record per
+    table (per layer for the stacked arrays; ``core.pcilt``'s device
+    checksum: one-word changes and bursts of <= 32 bits always caught,
+    other changes missed with probability about 2**-32, the device bytes
+    themselves read); it is verified at load
     (``verify=True``) and on demand (:meth:`verify_layer` /
     :meth:`verify_head` / :meth:`verify_integrity` — what the serving
     :class:`HealthMonitor` amortizes one layer per tick).  The step executor
@@ -659,21 +665,22 @@ class PCILTMambaDecode:
 
     def verify_layer(self, layer: int) -> List[Tuple]:
         """Checksum one layer's conv + projection table slices against the
-        conversion-time record; returns the breached ``(name, layer)``
-        sites (empty = clean)."""
+        conversion-time record, in one device call that reads the slices
+        in place; returns the breached ``(name, layer)`` sites (empty =
+        clean)."""
         integ = self.pcilt["integrity"]
-        bad: List[Tuple] = []
-        if table_checksum(
-                host_copy(self.pcilt["tables"])[layer]) != integ["conv"][layer]:
-            bad.append(("conv", int(layer)))
+        sites = [("conv", self.pcilt["tables"], 0, integ["conv"])]
         proj = self.pcilt.get("proj")
         if proj is not None:
-            for name, t in proj["tables"].items():
-                sl = (host_copy(t)[:, layer] if proj.get("paired")
-                      else host_copy(t)[layer])
-                if table_checksum(sl) != integ["proj"][name][layer]:
-                    bad.append((name, int(layer)))
-        return bad
+            # paired stacks are seg-major: the layer axis is axis 1
+            axis = 1 if proj.get("paired") else 0
+            sites += [(name, t, axis, integ["proj"][name])
+                      for name, t in proj["tables"].items()]
+        got = slice_checksums(layer, [t for _, t, _, _ in sites],
+                              [a for _, _, a, _ in sites])
+        return [(name, int(layer))
+                for (name, _, _, rec), g in zip(sites, got)
+                if g != rec[layer]]
 
     def layer_check_bytes(self) -> int:
         """Bytes :meth:`verify_layer` hashes: one layer's slice of the conv
@@ -699,12 +706,9 @@ class PCILTMambaDecode:
         if head is None:
             return []
         integ = self.pcilt["integrity"]["head"]
-        bad: List[Tuple] = []
-        if table_checksum(head["pool"]) != integ["pool"]:
-            bad.append(("head.pool",))
-        if table_checksum(head["seg_idx"]) != integ["seg_idx"]:
-            bad.append(("head.seg_idx",))
-        return bad
+        names = ("pool", "seg_idx")
+        got = table_checksums(*(head[k] for k in names))
+        return [("head." + k,) for k, g in zip(names, got) if g != integ[k]]
 
     def verify_integrity(self) -> List[Tuple]:
         """Full verification: every layer of every stacked table plus the
@@ -793,15 +797,19 @@ class HealthMonitor:
     checking uniquely cheap: any deviation at all is corruption, not noise.
     The monitor holds per-layer (and head) boolean health masks and, once
     per tick, spot-checks **one** still-healthy layer (round-robin), so the
-    steady-state overhead is one layer's CRC per tick regardless of depth:
+    steady-state overhead is one layer's checksum per tick regardless of
+    depth (and the head's every ``n_layers``-th tick):
 
-    * **checksum check** — :meth:`PCILTMambaDecode.verify_layer` CRCs the
-      layer's conv + projection table slices against the conversion-time
-      record (zero false negatives on single-entry flips);
+    * **checksum check** — :meth:`PCILTMambaDecode.verify_layer` checksums
+      the layer's conv + projection table slices on the device, where the
+      kernels read them, against the conversion-time record: one-word
+      changes and bursts of <= 32 bits always caught (zero false negatives
+      on single-entry flips), other changes missed with probability about
+      2**-32;
     * **dense-oracle spot-check** (every ``oracle_every``-th clean check) —
       a fixed probe activation through the layer's table fetch vs the
-      fake-quant dense matmul, catching corruption of anything the CRC
-      record does not cover;
+      fake-quant dense matmul, catching corruption of anything the
+      checksum record does not cover;
     * **output check** — :meth:`check_outputs` flags NaN/Inf in the decode
       logits (activation poisoning / numerical blowup), which the engine
       answers with checkpoint rollback rather than demotion.
@@ -812,19 +820,19 @@ class HealthMonitor:
     degraded and logged, never wrong.  ``last_verified`` records the newest
     tick each layer passed at, bounding how far a rollback must rewind.
 
-    Calibration-drift sentinel (PR 10): the CRC/oracle checks above cover
-    *table* corruption, but PCILT is only correct while runtime activations
-    stay inside the absmax range captured at calibration — ``quantize``
-    silently clips anything outside, yielding wrong-but-finite outputs no
-    checksum can see.  :meth:`observe_saturation` closes that hole from the
-    in-kernel saturation counters of the monitored decode step
+    Calibration-drift sentinel: the checksum/oracle checks above cover
+    *table* corruption, but PCILT is only correct while runtime
+    activations stay inside the absmax range captured at calibration —
+    ``quantize`` silently clips anything outside, yielding wrong-but-finite
+    outputs no checksum can see.  :meth:`observe_saturation` closes that
+    hole from the in-kernel saturation counters of the monitored decode step
     (``step(with_stats=True)``): per (layer, quantizer-grid) saturation
     *rates* feed an EWMA, classified against two thresholds —
     ``sat_hard`` (instant ``"saturated"``: this step's outputs are already
     suspect) and ``sat_drift`` on the EWMA (``"drifting"``: sustained mild
     clipping).  Either breach demotes the drifting layer through the same
-    typed ``layer_ok`` path as a CRC breach (event ``kind="drift"``) and
-    queues it on :attr:`drift_pending`; the serving loop then calls
+    typed ``layer_ok`` path as a checksum breach (event ``kind="drift"``)
+    and queues it on :attr:`drift_pending`; the serving loop then calls
     :meth:`recalibrate_layer` between ticks — tables are cheap to rebuild
     (the paper's point), so the layer's grid is re-scaled to the observed
     peak ``|x|/scale`` ratio (× ``headroom``), its stacked tables are
@@ -859,8 +867,8 @@ class HealthMonitor:
         self.head_last_verified = -1
         #: clean layer checks (what ``oracle_every`` counts)
         self.checks = 0
-        #: ``on_tick``'s work: layer and head CRC checks, dense-oracle
-        #: probes, and the table bytes those CRCs hashed
+        #: ``on_tick``'s work: layer and head checksum checks, dense-oracle
+        #: probes, and the table bytes those checks read on the device
         self.layer_checks = 0
         self.head_checks = 0
         self.oracle_probes = 0
@@ -935,7 +943,8 @@ class HealthMonitor:
         dense matmul — exact on the grid, so any mismatch beyond float-sum
         reassociation noise is corruption.  ``on_tick`` rotates ``name``
         across every converted projection (``nn.ssm.PROJ_NAMES``) so a
-        corrupt ``wo`` or ``wdt`` is probed directly, not only via CRC."""
+        corrupt ``wo`` or ``wdt`` is probed directly, not only via the
+        checksum."""
         proj = self.decode.pcilt.get("proj")
         if proj is None or name not in proj["tables"]:
             return True
@@ -976,9 +985,9 @@ class HealthMonitor:
         ``sat`` (optional) is the saturation-counter pytree of a monitored
         step (``PCILTMambaDecode.step(with_stats=True)``'s third result) and
         ``rows`` its decode batch; when given, the drift sentinel runs
-        (:meth:`observe_saturation`) *before* the amortized CRC pass, so an
-        instant ``"saturated"`` classification demotes on the very tick
-        whose outputs it indicts."""
+        (:meth:`observe_saturation`) *before* the amortized checksum pass,
+        so an instant ``"saturated"`` classification demotes on the very
+        tick whose outputs it indicts."""
         tick = int(tick)
         with span("monitor", tick=tick):
             breaches: List[Dict] = []
@@ -1153,7 +1162,7 @@ class HealthMonitor:
                     group)[:, 0]
                 t = t.at[:, l].set(t_new.astype(t.dtype))
                 proj["tables"][name] = t
-                integ[name][l] = table_checksum(np.asarray(t)[:, l])
+                integ[name][l] = slice_checksums(l, [t], [1])[0]
             else:
                 pad_n = (-wf.shape[0]) % group
                 if pad_n:  # group-alignment slots, exactly as conversion
@@ -1162,7 +1171,7 @@ class HealthMonitor:
                 t_new = build_grouped_tables(wf, spec, new_scale, group)
                 t = t.at[l].set(t_new.astype(t.dtype))
                 proj["tables"][name] = t
-                integ[name][l] = table_checksum(np.asarray(t)[l])
+                integ[name][l] = slice_checksums(l, [t], [0])[0]
             proj["scales"][name] = proj["scales"][name].at[l].set(
                 jnp.asarray(new_scale, jnp.float32))
             new_scales[name] = float(np.asarray(new_scale))

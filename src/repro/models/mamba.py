@@ -145,9 +145,11 @@ class MambaLM:
         :meth:`_head_logits` on the ``"shared"`` dispatch path.
 
         The returned bundle carries an ``"integrity"`` record — per-layer
-        CRC-32 checksums of every table array
-        (``core.serving.pcilt_integrity``) — verified at executor load and
-        on demand by the serving health monitor.
+        checksums of every table array, computed on the device
+        (``core.serving.pcilt_integrity``): one-word changes and bursts of
+        <= 32 bits always caught, other changes missed with probability
+        about 2**-32 — verified at executor load and on demand by the
+        serving health monitor, which reads the device bytes themselves.
         """
         from repro.core import QuantSpec
         from repro.core.lut_layers import build_dwconv_tables

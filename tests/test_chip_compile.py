@@ -112,3 +112,42 @@ def test_shared_head_compiles(one_chip, no_compile_cache):
         x, s, i, p, bits=BITS, zero_point=ZP, group=GROUP, tiles=tiles),
         one_chip, ((Bp, D), jnp.float32), ((1, 1), jnp.float32),
         ((1, G), jnp.int32), ((X, V, Op), jnp.float32))
+
+
+@pytest.mark.parametrize("paired", [False, True])
+def test_layer_checksum_reads_the_slices_in_place(one_chip, no_compile_cache,
+                                                  paired):
+    """The monitor's one-call layer check over every table stack at the
+    published widths: the dynamic slices fuse into the reductions, so the
+    program's scratch stays far below one layer's slices (122 MB
+    unpaired)."""
+    from repro.core.pcilt import _slice_lanes
+
+    C, Vc = D_INNER + 2 * N_STATE, 1 << (BITS * K)
+    outs = {"wz": D_INNER, "wx": D_INNER, "wB": N_STATE, "wC": N_STATE,
+            "wdt": HEADS}
+    proj = [(D, O) for O in outs.values()] + [(D_INNER, D)]
+    Ls = 4 if paired else L  # 24 layers of V**2-row pairs exceed 16 GB
+    if paired:
+        shapes = [(n // GROUP // 2, Ls, V * V, O) for n, O in proj]
+    else:
+        shapes = [(Ls, n // GROUP, V, O) for n, O in proj]
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in [(Ls, C, Vc)] + shapes]
+    layer = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    axes = (0,) + (1 if paired else 0,) * len(shapes)
+    mem = _slice_lanes.lower(layer, *args, axes=axes).compile() \
+        .memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 20
+
+
+def test_head_checksum_reads_the_pool_in_place(one_chip, no_compile_cache):
+    """The head check over the 1.24 GB shared pool and its pointers makes
+    no copy of the pool."""
+    from repro.core.pcilt import _table_lanes
+
+    G = D // GROUP
+    pool = jax.ShapeDtypeStruct((G, V, VOCAB), jnp.float32, sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((G,), jnp.int32, sharding=one_chip)
+    mem = _table_lanes.lower(pool, idx).compile().memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 20

@@ -35,10 +35,8 @@ PARENTS = {
     "serve.monitor.crc_layer": {"serve.monitor"},
     "serve.monitor.oracle": {"serve.monitor"},
     "serve.monitor.crc_head": {"serve.monitor"},
-    "serve.integrity.host_copy": {"serve.monitor.crc_layer",
-                                  "serve.monitor.crc_head"},
-    "serve.integrity.crc32": {"serve.monitor.crc_layer",
-                              "serve.monitor.crc_head"},
+    "serve.integrity.checksum": {"serve.monitor.crc_layer",
+                                 "serve.monitor.crc_head"},
     "serve.deadlines": {"serve.tick"},
     "serve.checkpoint": {None, "serve.tick"},  # the first is before a tick
     "serve.recover": {"serve.tick"},
@@ -151,6 +149,22 @@ def test_span_args(traced):
     assert {s[3]["slot"] for s in refills} <= set(range(eng.slots))
     assert sum(s[3]["admitted"] for s in named(spans, "serve.admit")) == \
         len(refills) == len(reqs)
+
+
+def test_a_monitored_tick_copies_no_table_to_the_host(traced):
+    """The monitor's checks run on the device: no ``integrity.host_copy``
+    span opens, and each layer or head check is one device checksum call
+    over the bytes its span names."""
+    _, _, _, spans = traced
+    assert named(spans, "serve.integrity.host_copy") == []
+    checks = sorted(named(spans, "serve.monitor.crc_layer") +
+                    named(spans, "serve.monitor.crc_head"),
+                    key=lambda s: s[1])
+    sums = named(spans, "serve.integrity.checksum")
+    assert len(sums) == len(checks)
+    for (_, a, b, args), (_, c, d, sargs) in zip(checks, sums):
+        assert a <= c and d <= b
+        assert sargs["bytes"] == args["bytes"]
 
 
 def test_telemetry_splits_each_tick(traced):
