@@ -21,9 +21,9 @@ a float (unquantized) pass on calibration tokens.  The benchmark computes
 them here and hands the same numbers to the program's table build, so both
 sides quantize on one grid.
 
-Weights are the benchmark's own (``bench/model.py``), in the program's
-parameter layout: kernels are ``[d_in, d_out]`` and blocks are stacked on a
-leading layer axis.
+Weights are the benchmark's own (``bench/families/mamba2.py``), in the
+program's parameter layout: kernels are ``[d_in, d_out]`` and blocks are
+stacked on a leading layer axis.
 """
 
 from __future__ import annotations

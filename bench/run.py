@@ -7,14 +7,16 @@ The cell, its configuration file and its traffic mix are looked up by name
 in ``BENCHMARK.json``; each metric is read by ``bench/metrics/<name>.py``
 (or the file named by the part of the name before its first ``.``).
 
-A run: makes the weights from the seed, calibrates the activation scales
-with the plain reference (``bench/reference.py``), builds the program's
-tables from them (``MambaLM.build_pcilt``, the configuration's own table
-format), and serves the mix's requests through the program's ``Engine``
-with the tables, the health monitor and the drift sentinel on, on the wall
-clock.  Set-up ends, and the window of ``--seconds`` opens, when a closed
-loop's first fill is done (its first decode step) or an open loop's first
-arrival is due.  The window ends without draining.  Then the served tokens
+Everything that depends on the architecture comes from the configuration's
+family module, ``bench/families/<family>.py`` (``bench/model.py`` loads it
+by the file's ``"family"``).  A run: makes the weights from the seed,
+calibrates the activation scales with the family's plain reference, builds
+the program's tables from them (the configuration's own table format), and
+serves the mix's requests through the program's ``Engine`` with the
+tables, the health monitor and the drift sentinel on, on the wall clock.
+Set-up ends, and the window of ``--seconds`` opens, when a closed loop's
+first fill is done (its first decode step) or an open loop's first arrival
+is due.  The window ends without draining.  Then the served tokens
 of a sample of requests are compared with the reference (``correct``).
 
 With ``--trace 1`` the window runs under the profiler and the line carries
@@ -61,10 +63,6 @@ TRACE_DIR = os.path.join(ROOT, ".bench_trace")
 SAMPLE_MAX = 16
 #: the reference's padded shapes: a multiple of this many positions
 POS_BUCKET = 256
-#: share of the compared served tokens whose logit may lie below the
-#: reference's best: sound runs read 0.0030 at most, tables held in
-#: bfloat16 0.0125 or more (PERF.md gives the readings)
-MAX_MISMATCH_SHARE = 0.007
 
 
 class WindowClosed(BaseException):
@@ -239,49 +237,17 @@ def timed_engine_class():
 
 # -- one run -----------------------------------------------------------------
 
-def build(conf: Dict, mix: Dict, seed: int, table_dtype: str):
-    """Weights, scales, the program's tables and its engine."""
-    import jax
-    import jax.numpy as jnp
-
-    from bench import reference
-    from repro.models import build_model
-
-    cfg = model.program_config(conf)
-    params = model.make_params(conf, seed)
-    d = reference.dims(conf)
-    scales = jax.jit(lambda p, t: reference.calibrate(p, t, d))(
-        params, jnp.asarray(model.calib_tokens(conf, seed)))
-    q = conf["pcilt"]
-    if q["head"] != "shared":
-        raise SystemExit(f"unsupported head {q['head']!r}")
-    bundle = build_model(cfg).build_pcilt(
-        params, scales["conv"], proj_scales={"in": scales["in"],
-                                             "out": scales["out"]},
-        proj_path="fused", table_dtype=jnp.dtype(table_dtype),
-        head_scale=scales["head"], head_weight_bits=q["head_weight_bits"])
-    eng = timed_engine_class()(cfg, slots=mix["slots"], pcilt=True,
-                               pcilt_bundle=bundle, clock=Clock())
-    # the engine draws its own weights from a fixed key; serve the seed's
-    eng.params = params
-    eng.monitor.params = params
-    return eng, params, scales
-
-
-def warm_up(eng) -> None:
+def warm_up(eng, fam) -> None:
     """Compile, before the window, every program the window runs: the
     monitored step at this slot count, the engine's per-step host-side
-    device ops, slot resets, and the monitor's dense-oracle probe of each
-    projection."""
+    device ops, slot resets, and what the family warms (the monitor's
+    probes)."""
     import jax
-
-    from repro.nn.ssm import PROJ_NAMES
 
     eng._step()
     for s in range(eng.slots):
         eng._reset_slot(s)
-    for name in PROJ_NAMES:
-        eng.monitor._oracle_check(0, name)
+    fam.warm_up(eng)
     jax.block_until_ready(eng.cache)
 
 
@@ -323,18 +289,14 @@ def sample(reqs, slot_of: Dict[int, int], seed: int) -> list:
     return out[:SAMPLE_MAX]
 
 
-def compare(conf: Dict, params, scales, picked: list) -> Dict:
-    """Teacher-forced reference over each picked request's prompt and
-    served tokens: the share of served tokens whose logit lies below the
-    reference's best, and the widest such gap, in logits and in steps of
-    the logit grid."""
-    import jax
+def compare(fam, conf: Dict, params, scales, picked: list) -> Dict:
+    """The family's teacher-forced reference over each picked request's
+    prompt and served tokens: the share of served tokens whose logit lies
+    below the reference's best, and the widest such gap, in logits and in
+    steps of the logit grid."""
     import jax.numpy as jnp
     import numpy as np
 
-    from bench import reference
-
-    d = reference.dims(conf)
     T = max(len(r.prompt) + len(r.out) - 1 for r in picked)
     T = -(-T // POS_BUCKET) * POS_BUCKET
     inputs = np.zeros((SAMPLE_MAX, T), np.int32)
@@ -344,8 +306,8 @@ def compare(conf: Dict, params, scales, picked: list) -> Dict:
         inputs[i, :len(seq)] = seq
         p = len(r.prompt) - 1
         targets[i, p:p + len(r.out)] = r.out
-    fn = jax.jit(lambda p, s, x, y: reference.gaps(p, s, x, y, d))
-    gap, step = fn(params, scales, jnp.asarray(inputs), jnp.asarray(targets))
+    gap, step = fam.gaps(conf, params, scales, jnp.asarray(inputs),
+                         jnp.asarray(targets))
     gap, step = np.asarray(gap, np.float64), float(step)
     mask = targets >= 0
     steps = np.rint(gap[mask] / step)
@@ -367,13 +329,17 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     man = man or manifest()
     c = cell(man, workload)
     conf = conf or model.load_config(config_file(man, c["config"]))
+    fam = model.family(conf)
     mix = mix or traffic.load(c["traffic"])
     table_dtype = table_dtype or conf["pcilt"]["table_dtype"]
     compiles = compile_counter()
 
-    eng, params, scales = build(conf, mix, seed, table_dtype)
+    params = fam.make_params(conf, seed)
+    scales = fam.calibrate(conf, params, seed)
+    eng = fam.build(conf, params, scales, table_dtype, timed_engine_class(),
+                    Clock(), mix["slots"])
     eng.setup_timing(seconds, mix["loop"], TRACE_DIR if trace else None)
-    warm_up(eng)
+    warm_up(eng, fam)
     gen = traffic.generate(mix, conf["vocab_size"], seconds,
                            model.rng(seed, model.STREAM_TRAFFIC))
     reqs = [Request(g.rid, g.prompt, g.max_new) for g in gen]
@@ -399,18 +365,19 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
 
         path = tr.latest_xplane(TRACE_DIR)
         trace_red = tr.reduce(path, seconds) if path else None
-    dev = jax.devices()[0]
-    mem = dev.memory_stats() or {}
+    devs = jax.devices()[:c["chips"]]
+    dev = devs[0]
+    # the fullest of the cell's chips
+    peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+               for d in devs)
     in_window = sum(w0 <= t <= w1 for _, t in compiles)
 
-    itemsize = next(iter(
-        eng.pdecode.pcilt["proj"]["tables"].values())).dtype.itemsize
-    pool_rows = int(eng.pdecode.pcilt["head"]["pool"].shape[0])
     ctx = SimpleNamespace(
-        conf=conf, mix=mix, cell=c, seconds=seconds, w0=w0, w1=w1,
-        token_times=eng.token_times, arrivals=arrivals, spans=eng.spans,
-        setup_s=setup_s, trace=trace_red, device_kind=dev.device_kind,
-        rows=mix["slots"], chips=c["chips"], table_itemsize=itemsize)
+        conf=conf, family=fam, mix=mix, cell=c, seconds=seconds, w0=w0,
+        w1=w1, token_times=eng.token_times, arrivals=arrivals,
+        spans=eng.spans, setup_s=setup_s, trace=trace_red,
+        device_kind=dev.device_kind, rows=mix["slots"], chips=c["chips"],
+        table_itemsize=fam.table_itemsize(eng))
     outcomes = [r.outcome for r in reqs]
     attempted = sum(o != "queued" for o in outcomes)
     failed = sum(o in ("degraded", "failed", "rejected") or
@@ -430,16 +397,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         f"steps {len(window.spans(eng.spans, 'decode_step', w0, w1))}")
     log(f"compiles or cache loads inside the window: {in_window} "
         f"(whole run: {len(compiles)})")
-    from bench import roofline
-
-    rows = mix["slots"]
-    staged = sum(roofline.gemv_staged_bytes(conf, n, itemsize)
-                 for n in roofline.projections(conf)) * conf["n_layer"]
-    least = sum(roofline.gemv_call(conf, n, rows, itemsize)[0]
-                for n in roofline.projections(conf)) * conf["n_layer"]
-    log(f"bytes per step: GEMV tables staged {staged:.0f}, GEMV least "
-        f"{least:.0f}; head staged {roofline.head_staged_bytes(conf, pool_rows, 4):.0f}"
-        f", head least {roofline.head_call(conf, rows, 4)[0]:.0f}")
+    log(fam.bytes_per_step(conf, eng, mix["slots"]))
 
     metrics = {}
     for m in metrics_for(man, workload, trace):
@@ -448,7 +406,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": c["chips"],
-              "memory_peak_bytes": int(mem.get("peak_bytes_in_use", 0))}
+              "memory_peak_bytes": peak}
     out = {"attempted": attempted, "failed": failed, "metrics": metrics,
            "device": device}
     if trace_red is not None:
@@ -465,10 +423,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     n_slots = len({eng.slot_of.get(r.rid) for r in picked})
     del eng
     gc.collect()
-    checks = compare(conf, params, scales, picked) if picked else None
+    checks = compare(fam, conf, params, scales, picked) if picked else None
     if checks:
         checks["compared_slots"] = n_slots
-    limits = {"mismatch_share": MAX_MISMATCH_SHARE}
+    limits = fam.LIMITS
     compared = {k: {"value": checks[k] if checks else None, "limit": v}
                 for k, v in limits.items()}
     correct = checks is not None and all(

@@ -1,6 +1,7 @@
 """``BENCHMARK.json`` against the rules its harness and its readers rely on:
-names and units, every metric's reader, every cell's files, and that each
-per-layer metric's ``moves`` is reported in each cell that reports it."""
+names and units, every metric's reader, every cell's files and family
+module, and that each per-layer metric's ``moves`` is reported in each cell
+that reports it."""
 
 import json
 import os
@@ -14,8 +15,6 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
-WIDTHS = ("d_model", "d_state", "headdim", "expand", "d_intermediate",
-          "ngroups")
 
 
 @pytest.fixture(scope="module")
@@ -73,7 +72,9 @@ def test_every_cell_finds_its_files(man):
         conf = model.load_config(os.path.join(ROOT, c["file"]))
         assert c["file"].startswith(tuple(p + "/" for p in man["paths"]))
         assert c["reduced"] == conf["reduced"]
-        assert not set(c["reduced"]) & set(WIDTHS)
+        fam = model.family(conf)
+        assert all(hasattr(fam, hook) for hook in model.HOOKS)
+        assert not set(c["reduced"]) & set(fam.WIDTHS)
         assert conf["source"] == c["source"]
 
 

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from bench import model, run
+from bench.families import mamba2
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -22,13 +23,37 @@ MIX = {"loop": "closed", "slots": 4, "requests": 400,
        "output": {"median": 40, "sigma": 0.5, "min": 8, "max": 100}}
 
 
-def tiny_run(seed, seconds=2.0, table_dtype=None):
-    conf = model.load_config(os.path.join(DATA, "mamba2-tiny.json"))
+def tiny_conf():
+    return model.load_config(os.path.join(DATA, "mamba2-tiny.json"))
+
+
+def tiny_run(seed, seconds=2.0, table_dtype=None, conf=None):
     man = dict(run.manifest(), workloads=[
         {"name": "tiny.batch", "config": "mamba2-tiny", "traffic": "batch",
          "chips": 1, "why": "a CPU size"}])
     return run.run_cell("tiny.batch", seed, seconds, False,
-                        table_dtype=table_dtype, man=man, conf=conf, mix=MIX)
+                        table_dtype=table_dtype, man=man,
+                        conf=conf or tiny_conf(), mix=MIX)
+
+
+class StepClock(run.Clock):
+    """A clock that moves a millisecond at each reading and never waits, so
+    a window holds the same steps in every run."""
+
+    def __init__(self):
+        super().__init__()
+        self.t = 1000.0
+
+    def time(self):
+        self.t += 1e-3
+        return self.t
+
+    def sleep(self, seconds):
+        if self.end is not None:
+            seconds = min(seconds, self.end - self.t)
+        self.t += max(seconds, 0.0)
+        if self.end is not None and self.t >= self.end:
+            raise run.WindowClosed
 
 
 def share(out):
@@ -45,7 +70,7 @@ def test_served_tokens_agree_with_the_reference():
 def test_control_bf16_tables_is_refused():
     out = tiny_run(12, table_dtype="bfloat16")
     assert out["correct"] is False
-    assert share(out) > run.MAX_MISMATCH_SHARE
+    assert share(out) > mamba2.MAX_MISMATCH_SHARE
 
 
 def test_a_step_that_returns_its_state_unchanged_is_refused(monkeypatch):
@@ -96,7 +121,69 @@ def test_tokens_of_half_the_slots_altered_is_refused(monkeypatch, half):
     monkeypatch.setattr(Engine, "_step", altered)
     out = tiny_run(15 + half)
     assert out["correct"] is False
-    assert share(out) > run.MAX_MISMATCH_SHARE
+    assert share(out) > mamba2.MAX_MISMATCH_SHARE
+
+
+#: a tiny run at seed 2**32 + 11 under :class:`StepClock`: the result's
+#: object without the metrics' values (times), and lines of its log
+PINNED_RESULT = {
+    "correct": True, "attempted": 14, "failed": 0,
+    "device": {"platform": "cpu", "kind": "cpu", "count": 1,
+               "memory_peak_bytes": 0},
+    "checks": {"mismatch_share": {"value": 0.0, "limit": 0.007}}}
+PINNED_LOG = [
+    "window: 2.000s; decode ticks 81, steps 185; restarts 0, rollbacks 0, "
+    "health events 0 []",
+    "requests: 10 finished, 4 in flight, 386 waiting for a slot; tokens in "
+    "window 487; prefill steps 69, decode steps 81",
+    "bytes per step: GEMV tables staged 1736704, GEMV least 134400; head "
+    "staged 1572864, head least 38016",
+    "compare: max_gap_steps = 0.0", "compare: max_gap_logits = 0.0",
+    "compare: compared_tokens = 544", "compare: compared_requests = 13",
+    "compare: compared_slots = 4"]
+
+
+def test_a_tiny_run_under_a_step_clock_reads_the_pinned_result(
+        monkeypatch, capsys):
+    monkeypatch.setattr(run, "Clock", StepClock)
+    out = tiny_run(2**32 + 11)
+    log = capsys.readouterr().out.splitlines()
+    units = {k: v["unit"] for k, v in out.pop("metrics").items()}
+    assert units == {"setup_s": "s", "itl_p95_ms": "ms"}
+    assert out == PINNED_RESULT
+    assert [ln for ln in log if ln in PINNED_LOG] == PINNED_LOG
+
+
+def test_a_family_plugs_in_from_its_own_file(monkeypatch):
+    monkeypatch.setattr(model, "FAMILIES", os.path.join(DATA, "families"))
+    out = tiny_run(2**31 + 17, conf=dict(tiny_conf(),
+                                         family="mamba2_renamed"))
+    assert out["correct"] is True and out["attempted"] > 0
+    # the limit is the test family's own
+    assert out["checks"] == {"mismatch_share": {"value": 0.0, "limit": 0.0}}
+
+
+@pytest.mark.parametrize("family, looked_for", [
+    (None, "<family>.py"), ("no_such_family", "no_such_family.py")])
+def test_a_missing_or_unknown_family_exits_naming_the_file(family,
+                                                           looked_for):
+    conf = tiny_conf()
+    conf.pop("family")
+    if family:
+        conf["family"] = family
+    with pytest.raises(SystemExit) as e:
+        tiny_run(1, conf=conf)
+    assert os.path.join(model.FAMILIES, looked_for) in str(e.value)
+
+
+def test_a_family_without_every_hook_exits_naming_them(monkeypatch,
+                                                        tmp_path):
+    (tmp_path / "bare.py").write_text("WIDTHS = ()\n")
+    monkeypatch.setattr(model, "FAMILIES", str(tmp_path))
+    with pytest.raises(SystemExit) as e:
+        tiny_run(1, conf=dict(tiny_conf(), family="bare"))
+    assert str(tmp_path / "bare.py") in str(e.value)
+    assert "LIMITS" in str(e.value) and "WIDTHS" not in str(e.value)
 
 
 def test_sample_takes_the_longest_then_one_request_of_each_slot():
