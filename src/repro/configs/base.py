@@ -46,6 +46,9 @@ class SSMConfig:
     expand: int = 2
     chunk: int = 256
     dt_rank: int = 0  # unused in SSD; kept for provenance
+    #: SSD heads this chip holds (0 = all): a tensor-parallel share takes
+    #: whole heads, with the B/C state projections of the groups they sit in
+    heads_held: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,8 +82,12 @@ class ModelConfig:
     # families
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
-    shared_attn_period: int = 0  # zamba2: shared attn every N blocks
+    # zamba2: the layers that run a shared transformer block before their
+    # Mamba mixer, the shared blocks they take in turn, and the rank of the
+    # LoRA adapter each application adds to the shared MLP
+    hybrid_layer_ids: Tuple[int, ...] = ()
     n_shared_attn_blocks: int = 2
+    adapter_rank: int = 0
     encoder_layers: int = 0      # whisper enc-dec
     encoder_len: int = 1500
     n_img_tokens: int = 0        # llava stub frontend

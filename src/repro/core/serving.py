@@ -16,6 +16,7 @@ value; shared-PCILT keeps memory feasible).
 
 from __future__ import annotations
 
+import functools
 import logging
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -38,6 +39,7 @@ log = logging.getLogger("repro.serving")
 __all__ = ["PCILTLinear", "PCILTConv2d", "PCILTDwConv1d", "convert_kernel",
            "convert_conv_kernel", "convert_dwconv", "convert_mamba_decode",
            "PCILTMambaDecode", "HealthMonitor", "pcilt_integrity",
+           "build_site_tables",
            "pcilt_apply", "mlp_table_bytes"]
 
 
@@ -63,6 +65,55 @@ def pcilt_integrity(pcilt: Dict) -> Dict:
         pool, seg_idx = table_checksums(head["pool"], head["seg_idx"])
         integ["head"] = {"pool": pool, "seg_idx": seg_idx}
     return integ
+
+
+def build_site_tables(kernels: Dict[str, jax.Array],
+                      scales: Dict[str, jax.Array], spec: QuantSpec,
+                      group: int, *, table_dtype=jnp.float32, mesh=None,
+                      mesh_axis: str = "model",
+                      paired: bool = False) -> Dict[str, jax.Array]:
+    """Grouped tables of every projection site, one stack per site.
+
+    ``kernels[name]`` is the site's stack of dense kernels ``[S, n, O]``
+    (one per layer, shared block or application) and ``scales[name]`` its
+    calibrated activation scale per stack entry ``[S]``.  Each site becomes
+    ``[S, G, V, O]`` tables (``G = ceil(n / group)``, alignment rows from
+    zero weights) or, with ``paired``, seg-major ``[G2, S, V2, O]`` paired
+    stacks (``build_paired_stacked_tables``).  With ``mesh=`` the segment
+    axis is placed sharded over ``mesh_axis``."""
+    from .pcilt import build_paired_stacked_tables
+
+    tabs = {}
+    for name, ks in kernels.items():
+        s_l = jnp.asarray(scales[name], jnp.float32)
+        _, n, _ = ks.shape
+        pad_n = (-n) % group
+        if paired:
+            # build_paired_stacked_tables pads n to the pair width itself
+            # (alignment + phantom slots from zero weights); building in
+            # f32 and casting once keeps bf16 tables rounding-safe.
+            t = build_paired_stacked_tables(
+                ks.astype(jnp.float32), spec, s_l, group).astype(table_dtype)
+            seg_count, seg_axis = t.shape[0], 0
+        else:
+            def build(w, s):
+                wf = w.astype(jnp.float32)
+                if pad_n:  # group-alignment slots from zero weights
+                    wf = jnp.concatenate(
+                        [wf, jnp.zeros((pad_n, wf.shape[-1]), wf.dtype)], 0)
+                return build_grouped_tables(wf, spec, s, group)
+
+            t = jax.vmap(build)(ks, s_l).astype(table_dtype)
+            seg_count, seg_axis = t.shape[1], 1
+        if mesh is not None and mesh_shard_count(
+                mesh, mesh_axis, seg_count) > 1:
+            from repro.nn.module import pcilt_table_sharding
+
+            t = jax.device_put(t, pcilt_table_sharding(
+                mesh, seg_count, ndim=4, mesh_axis=mesh_axis,
+                seg_axis=seg_axis))
+        tabs[name] = t
+    return tabs
 
 
 def _place_sharded_pool(sp: ShardedSharedPool, mesh,
@@ -520,14 +571,16 @@ def convert_dwconv(filters: jax.Array, act_spec: QuantSpec,
 
 
 class PCILTMambaDecode:
-    """A fully-converted Mamba decode path: the calibrated PCILT bundle
-    (conv ``[L, C, V]`` tables + layer-stacked ``[L, G, V, O]`` projection
-    tables) plus the **hoisted jitted step executor** — eager serving loops
-    call one compiled function per token instead of re-tracing
-    ``decode_step`` (and re-closing over the table stack) every step.
+    """A fully-converted decode path (``MambaLM`` or the Zamba2
+    ``HybridLM``): the calibrated PCILT bundle (conv ``[L, C, V]`` tables +
+    one stacked ``[S, G, V, O]`` table per projection site of the model's
+    ``pcilt_sites``) plus the **hoisted jitted step executor** — eager
+    serving loops call one compiled function per token instead of
+    re-tracing ``decode_step`` (and re-closing over the table stack) every
+    step.
 
     Built by :func:`convert_mamba_decode`; ``step``/``__call__`` mirror
-    ``MambaLM.decode_step(params, cache, tokens)``.  :meth:`tune` eagerly
+    ``model.decode_step(params, cache, tokens)``.  :meth:`tune` eagerly
     autotunes the stacked projection kernels for a decode batch shape and
     records the winners under ``fused_gemv_stacked`` keys (local-shard
     shapes under a mesh), so the jitted dispatch hits the lookup table at
@@ -648,11 +701,11 @@ class PCILTMambaDecode:
         the per-layer saturation-counter pytree of
         ``MambaLM.decode_step(with_stats=True)``.
 
-        ``layer_ok`` (``[L]`` bool) / ``head_ok`` (bool) demote unhealthy
-        layers' fetches (and the PCILT logits head) to their exact dense
+        ``layer_ok`` (``[n_units]`` bool) / ``head_ok`` (bool) demote
+        unhealthy units' fetches (and the PCILT logits head) to their exact dense
         fake-quant oracles; both default to all-healthy."""
         if layer_ok is None:
-            layer_ok = jnp.ones((self.model.cfg.n_layers,), bool)
+            layer_ok = jnp.ones((self.model.n_units,), bool)
         if head_ok is None:
             head_ok = jnp.asarray(True)
         fn = self.executor(int(tokens.shape[0]), stats=with_stats)
@@ -663,34 +716,49 @@ class PCILTMambaDecode:
 
     # -- integrity verification ----------------------------------------------
 
-    def verify_layer(self, layer: int) -> List[Tuple]:
-        """Checksum one layer's conv + projection table slices against the
-        conversion-time record, in one device call that reads the slices
-        in place; returns the breached ``(name, layer)`` sites (empty =
-        clean)."""
-        integ = self.pcilt["integrity"]
-        sites = [("conv", self.pcilt["tables"], 0, integ["conv"])]
+    def _unit_sites(self, unit: int) -> List[Tuple]:
+        """``(name, stack array, stack axis, index, record)`` of every
+        table slice a monitor unit serves from: a layer's conv and
+        projection slices, or a shared-block application's slices of its
+        block's tables and of its own adapter and ``linear`` tables."""
+        model, integ = self.model, self.pcilt["integrity"]
+        sites = []
+        li = model.site_index("layer", unit)
+        if li is not None:
+            sites.append(("conv", self.pcilt["tables"], 0, li, integ["conv"]))
         proj = self.pcilt.get("proj")
         if proj is not None:
             # paired stacks are seg-major: the layer axis is axis 1
             axis = 1 if proj.get("paired") else 0
-            sites += [(name, t, axis, integ["proj"][name])
-                      for name, t in proj["tables"].items()]
-        got = slice_checksums(layer, [t for _, t, _, _ in sites],
-                              [a for _, _, a, _ in sites])
-        return [(name, int(layer))
-                for (name, _, _, rec), g in zip(sites, got)
-                if g != rec[layer]]
+            stacks = model.pcilt_sites()
+            for name, t in proj["tables"].items():
+                i = model.site_index(stacks[name][0], unit)
+                if i is not None:
+                    sites.append((name, t, axis, i, integ["proj"][name]))
+        return sites
 
-    def layer_check_bytes(self) -> int:
-        """Bytes :meth:`verify_layer` hashes: one layer's slice of the conv
-        table and of each projection table."""
-        L = self.pcilt["tables"].shape[0]
-        stacks = [self.pcilt["tables"]]
-        proj = self.pcilt.get("proj")
-        if proj is not None:
-            stacks += list(proj["tables"].values())
-        return sum(int(t.nbytes) // L for t in stacks)
+    def verify_layer(self, layer: int) -> List[Tuple]:
+        """Checksum one monitor unit's table slices (see
+        :meth:`_unit_sites`) against the conversion-time record, one
+        device call per stack index that reads the slices in place;
+        returns the breached ``(name, unit)`` sites (empty = clean)."""
+        by_index: Dict[int, List[Tuple]] = {}
+        for site in self._unit_sites(layer):
+            by_index.setdefault(site[3], []).append(site)
+        bad = []
+        for i, sites in by_index.items():
+            got = slice_checksums(i, [t for _, t, _, _, _ in sites],
+                                  [a for _, _, a, _, _ in sites])
+            bad += [(name, int(layer))
+                    for (name, _, _, _, rec), g in zip(sites, got)
+                    if g != rec[i]]
+        return bad
+
+    def layer_check_bytes(self, layer: int = 0) -> int:
+        """Bytes :meth:`verify_layer` hashes for one unit: its slice of
+        each table it serves from."""
+        return sum(int(t.nbytes) // t.shape[axis]
+                   for _, t, axis, _, _ in self._unit_sites(layer))
 
     def head_check_bytes(self) -> int:
         """Bytes :meth:`verify_head` hashes (0 = no head)."""
@@ -713,10 +781,9 @@ class PCILTMambaDecode:
     def verify_integrity(self) -> List[Tuple]:
         """Full verification: every layer of every stacked table plus the
         head; returns all breached sites (what the monitor amortizes)."""
-        L = self.pcilt["tables"].shape[0]
         bad: List[Tuple] = []
-        for l in range(L):
-            bad.extend(self.verify_layer(l))
+        for u in range(self.model.n_units):
+            bad.extend(self.verify_layer(u))
         bad.extend(self.verify_head())
         return bad
 
@@ -790,7 +857,9 @@ class PCILTMambaDecode:
 
 class HealthMonitor:
     """Amortized health checking + graceful degradation for a converted
-    Mamba decode path.
+    decode path, over the model's units: its layers and, for the Zamba2
+    hybrid, its shared-block applications after them (``model.n_units``;
+    ``n_layers`` here counts units).
 
     The paper's exactness guarantee — a PCILT fetch is *bit-exact* against
     the dense matmul on the quantized activation grid — makes health
@@ -844,22 +913,20 @@ class HealthMonitor:
     longer comparable to a pre-drift reference.
     """
 
-    #: the distinct quantizer grids a monitored decode step reports, in
-    #: the order ``mamba_decode`` emits them
-    SAT_GRIDS = ("in", "conv", "out")
-
     def __init__(self, decode: PCILTMambaDecode, params, *,
                  oracle_every: int = 4, oracle_batch: int = 1,
                  oracle_tol: float = 5e-3, seed: int = 0,
                  sat_hard: float = 0.25, sat_drift: float = 0.02,
                  sat_alpha: float = 0.2, headroom: float = 1.05,
                  max_recalibrations: int = 2):
-        cfg = decode.model.cfg
+        model = decode.model
         self.decode = decode
         self.params = params
         self.oracle_every = oracle_every
         self.oracle_tol = oracle_tol
-        self.n_layers = int(cfg.n_layers)
+        #: the units checked and demoted one at a time: the layers, then (a
+        #: hybrid's) shared-block applications (``model.n_units``)
+        self.n_layers = int(model.n_units)
         self.layer_ok = np.ones(self.n_layers, bool)
         self.head_ok = True
         #: newest tick each layer passed verification at (-1 = never)
@@ -870,19 +937,13 @@ class HealthMonitor:
         #: ``on_tick``'s work: layer and head checksum checks, dense-oracle
         #: probes, and the table bytes those checks read on the device
         self.layer_checks = 0
+        self.shared_checks = 0
         self.head_checks = 0
         self.oracle_probes = 0
         self.crc_bytes = 0
         self.events: List[Dict] = []
-        rng = np.random.default_rng(seed)
-        d_inner = cfg.ssm.expand * cfg.d_model
-        conv_ch = d_inner + 2 * cfg.ssm.n_groups * cfg.ssm.d_state
-        self._probe = (0.3 * rng.normal(
-            size=(oracle_batch, cfg.d_model))).astype(np.float32)
-        # wo consumes the post-norm gated inner stream, not the block input
-        # — the rotating oracle probe needs both widths.
-        self._probe_out = (0.3 * rng.normal(
-            size=(oracle_batch, d_inner))).astype(np.float32)
+        self._seed, self._oracle_batch = seed, oracle_batch
+        self._probes: Dict[int, np.ndarray] = {}
         self._oracle_rr = 0
         # -- drift sentinel state ------------------------------------------
         self.sat_hard = float(sat_hard)
@@ -890,16 +951,16 @@ class HealthMonitor:
         self.sat_alpha = float(sat_alpha)
         self.headroom = float(headroom)
         self.max_recalibrations = int(max_recalibrations)
-        #: saturable elements per decode row per grid — the denominator
-        #: turning the kernels' raw counts into rates
-        self._sat_elems = {"in": int(cfg.d_model),
-                           "conv": int(cfg.ssm.conv_kernel * conv_ch),
-                           "out": int(d_inner)}
-        self.sat_last = {g: np.zeros(self.n_layers) for g in self.SAT_GRIDS}
-        self.sat_ewma = {g: np.zeros(self.n_layers) for g in self.SAT_GRIDS}
-        #: running peak |x|/scale per (grid, layer) since last recalibration
+        #: the quantizer grids the monitored step reports: ``{grid: (first
+        #: unit, units, saturable elements per decode row)}`` — the last
+        #: turns the kernels' raw counts into rates
+        self.grids = model.sat_grids()
+        self._sat_elems = {g: int(e) for g, (_, _, e) in self.grids.items()}
+        self.sat_last = {g: np.zeros(n) for g, (_, n, _) in self.grids.items()}
+        self.sat_ewma = {g: np.zeros(n) for g, (_, n, _) in self.grids.items()}
+        #: running peak |x|/scale per (grid, unit) since last recalibration
         #: — the observed absmax the rebuild re-scales to
-        self.sat_peak = {g: np.zeros(self.n_layers) for g in self.SAT_GRIDS}
+        self.sat_peak = {g: np.zeros(n) for g, (_, n, _) in self.grids.items()}
         #: (layer, grid) pairs demoted for drift, awaiting recalibration
         self.drift_pending: List[Tuple[int, str]] = []
         self.recalibrations = np.zeros(self.n_layers, np.int64)
@@ -938,42 +999,50 @@ class HealthMonitor:
         """NaN/Inf gate on the step's logits (True = healthy)."""
         return bool(jnp.all(jnp.isfinite(logits)))
 
+    def _probe(self, width: int) -> np.ndarray:
+        """The fixed probe activation of one input width."""
+        if width not in self._probes:
+            rng = np.random.default_rng((self._seed, width))
+            self._probes[width] = (0.3 * rng.normal(
+                size=(self._oracle_batch, width))).astype(np.float32)
+        return self._probes[width]
+
     def _oracle_check(self, layer: int, name: str = "wx") -> bool:
-        """Probe one layer's ``name`` table fetch against the fake-quant
+        """Probe one unit's ``name`` table fetch against the fake-quant
         dense matmul — exact on the grid, so any mismatch beyond float-sum
         reassociation noise is corruption.  ``on_tick`` rotates ``name``
-        across every converted projection (``nn.ssm.PROJ_NAMES``) so a
-        corrupt ``wo`` or ``wdt`` is probed directly, not only via the
+        across every site the unit serves from (:meth:`_next_probe_name`)
+        so a corrupt ``wo`` or ``wdt`` is probed directly, not only via the
         checksum."""
         proj = self.decode.pcilt.get("proj")
+        model = self.decode.model
         if proj is None or name not in proj["tables"]:
             return True
-        t = proj["tables"][name]  # [L, G, V, O] (paired: [G/2, L, V^2, O])
-        spec, group = proj["spec"], proj["group"]
-        paired = bool(proj.get("paired"))
-        scale = proj["scales"][name][layer]
-        x = self._probe_out if name == "wo" else self._probe
+        i = model.site_index(model.pcilt_sites()[name][0], layer)
+        if i is None:
+            return True
+        t = proj["tables"][name]  # [S, G, V, O] (paired: [G/2, L, V^2, O])
+        group, paired = proj["group"], bool(proj.get("paired"))
+        k = model.site_kernels(self.params)[name]
+        x = self._probe(k.shape[1])
         n = t.shape[0] * 2 * group if paired else t.shape[1] * group
         pad = n - x.shape[-1]
         xx = np.concatenate(
             [x, np.zeros((x.shape[0], pad), x.dtype)], -1) if pad else x
-        got = pcilt_linear(jnp.asarray(xx), t, spec, scale, group,
-                           path="gather", stacked=int(layer), paired=paired)
-        k = self.params["blocks"]["mixer"][name]["kernel"][layer]
-        want = jnp.dot(fake_quant(jnp.asarray(x), spec, scale),
-                       k.astype(jnp.float32),
-                       precision=jax.lax.Precision.HIGHEST)
-        return bool(np.allclose(np.asarray(got), np.asarray(want),
-                                rtol=self.oracle_tol, atol=self.oracle_tol))
+        probe = _oracle_probe(proj["spec"], group, paired,
+                              float(self.oracle_tol))
+        return bool(probe(jnp.asarray(xx), jnp.asarray(x), t,
+                          proj["scales"][name], k, jnp.int32(i)))
 
-    def _next_probe_name(self) -> str:
-        """Round-robin over the converted projections for the dense-oracle
-        spot-check (falls back to ``wx`` when no projections converted)."""
-        from repro.nn.ssm import PROJ_NAMES
-
+    def _next_probe_name(self, layer: int = 0) -> str:
+        """Round-robin over the sites a unit serves from for the
+        dense-oracle spot-check (``wx`` when no projection converted)."""
         proj = self.decode.pcilt.get("proj")
-        names = tuple(n for n in PROJ_NAMES
-                      if proj is not None and n in proj["tables"]) or ("wx",)
+        model = self.decode.model
+        names = tuple(
+            n for n, (stack, _) in model.pcilt_sites().items()
+            if proj is not None and n in proj["tables"]
+            and model.site_index(stack, layer) is not None) or ("wx",)
         name = names[self._oracle_rr % len(names)]
         self._oracle_rr += 1
         return name
@@ -997,10 +1066,15 @@ class HealthMonitor:
             candidates = [l for l in range(self.n_layers) if self.layer_ok[l]]
             if candidates:
                 l = candidates[tick % len(candidates)]
-                nbytes = self.decode.layer_check_bytes()
-                with span("monitor.crc_layer", layer=l, bytes=nbytes):
+                nbytes = self.decode.layer_check_bytes(l)
+                model = self.decode.model
+                with span("monitor.crc_layer", layer=model.unit_label(l),
+                          bytes=nbytes):
                     bad = self.decode.verify_layer(l)
-                self.layer_checks += 1
+                if model.site_index("layer", l) is None:
+                    self.shared_checks += 1
+                else:
+                    self.layer_checks += 1
                 self.crc_bytes += nbytes
                 if bad:
                     breaches.append(self.demote(
@@ -1009,7 +1083,7 @@ class HealthMonitor:
                     self.checks += 1
                     if self.oracle_every and \
                             self.checks % self.oracle_every == 0:
-                        name = self._next_probe_name()
+                        name = self._next_probe_name(l)
                         with span("monitor.oracle", layer=l, proj=name):
                             ok = self._oracle_check(l, name)
                         self.oracle_probes += 1
@@ -1036,6 +1110,7 @@ class HealthMonitor:
     def counters(self) -> Dict[str, int]:
         """Totals of the health passes run so far (``Engine`` stats)."""
         return {"layer_checks": self.layer_checks,
+                "shared_checks": self.shared_checks,
                 "head_checks": self.head_checks,
                 "oracle_probes": self.oracle_probes,
                 "crc_bytes": self.crc_bytes}
@@ -1043,7 +1118,8 @@ class HealthMonitor:
     # -- calibration-drift sentinel ------------------------------------------
 
     def saturation_state(self, grid: str, layer: int) -> str:
-        """Classify one (grid, layer) quantizer: ``"healthy"`` /
+        """Classify one (grid, unit) quantizer, ``layer`` counted from the
+        grid's first unit: ``"healthy"`` /
         ``"drifting"`` (EWMA past ``sat_drift``) / ``"saturated"`` (last
         observed rate past ``sat_hard``)."""
         if self.sat_last[grid][layer] >= self.sat_hard:
@@ -1071,30 +1147,32 @@ class HealthMonitor:
         # per-array np.asarray syncs add measurable per-tick latency).
         sat = jax.device_get(sat)
         for grid, st in sat.items():
+            first, units, elems = self.grids[grid]
             counts = np.asarray(st["count"], np.int64)
             ratios = np.asarray(st["ratio"], np.float64)
-            rates = counts / float(max(int(rows), 1) * self._sat_elems[grid])
+            rates = counts / float(max(int(rows), 1) * elems)
             a = self.sat_alpha
             self.sat_last[grid] = rates
             self.sat_ewma[grid] = (1.0 - a) * self.sat_ewma[grid] + a * rates
             self.sat_peak[grid] = np.maximum(self.sat_peak[grid], ratios)
-            for l in range(self.n_layers):
+            for i in range(units):
+                l = first + i
                 if not self.layer_ok[l]:
                     continue
-                state = self.saturation_state(grid, l)
+                state = self.saturation_state(grid, i)
                 if state == "healthy":
                     continue
                 if state == "saturated":
-                    reason = (f"saturation {grid} rate={rates[l]:.4f} >= "
+                    reason = (f"saturation {grid} rate={rates[i]:.4f} >= "
                               f"sat_hard={self.sat_hard}")
                 else:
                     reason = (f"saturation {grid} "
-                              f"ewma={self.sat_ewma[grid][l]:.4f} >= "
+                              f"ewma={self.sat_ewma[grid][i]:.4f} >= "
                               f"sat_drift={self.sat_drift}")
                 ev = self.demote("drift", l, tick, reason)
-                ev.update(grid=grid, state=state, rate=float(rates[l]),
-                          ewma=float(self.sat_ewma[grid][l]),
-                          ratio=float(self.sat_peak[grid][l]))
+                ev.update(grid=grid, state=state, rate=float(rates[i]),
+                          ewma=float(self.sat_ewma[grid][i]),
+                          ratio=float(self.sat_peak[grid][i]))
                 self.drift_pending.append((l, grid))
                 breaches.append(ev)
         return breaches
@@ -1131,6 +1209,10 @@ class HealthMonitor:
             return _sticky("conv grid shares one global scale across layers "
                            "— per-layer hot-swap impossible; demoted to the "
                            "dense oracle")
+        if grid not in ("in", "out"):
+            return _sticky("shared-block grid: its block's tables serve "
+                           "every application of the block — no per-unit "
+                           "hot-swap; demoted to the dense oracle")
         if proj is None:
             return _sticky("no converted projections to rebuild")
         if self.recalibrations[l] >= self.max_recalibrations:
@@ -1140,17 +1222,17 @@ class HealthMonitor:
         spec, group = proj["spec"], proj["group"]
         paired = bool(proj.get("paired"))
         integ = self.decode.pcilt["integrity"]["proj"]
-        names = ("wo",) if grid == "out" else tuple(
-            n for n in proj["tables"] if n != "wo")
+        model = self.decode.model
+        names = tuple(n for n, site in model.pcilt_sites().items()
+                      if site == ("layer", grid) and n in proj["tables"])
+        kernels = model.site_kernels(self.params)
         new_amax = float(self.sat_peak[grid][l]) * self.headroom
         new_scales: Dict[str, float] = {}
         for name in names:
             old_scale = float(np.asarray(proj["scales"][name][l]))
             new_scale = scale_from_amax(
                 jnp.asarray(new_amax * old_scale, jnp.float32), spec)
-            wf = jnp.asarray(
-                self.params["blocks"]["mixer"][name]["kernel"][l],
-                jnp.float32)
+            wf = jnp.asarray(kernels[name][l], jnp.float32)
             t = proj["tables"][name]
             if paired:
                 # seg-major [G2, L, V2, O]: rebuild the one layer through
@@ -1213,15 +1295,33 @@ class HealthMonitor:
         recalibrations, pending drift responses, taint flag."""
         return {
             "rate": {g: float(self.sat_last[g].max(initial=0.0))
-                     for g in self.SAT_GRIDS},
+                     for g in self.grids},
             "ewma": {g: float(self.sat_ewma[g].max(initial=0.0))
-                     for g in self.SAT_GRIDS},
+                     for g in self.grids},
             "peak_ratio": {g: float(self.sat_peak[g].max(initial=0.0))
-                           for g in self.SAT_GRIDS},
+                           for g in self.grids},
             "recalibrations": int(self.recalibrations.sum()),
             "pending": len(self.drift_pending),
             "tainted": bool(self.tainted),
         }
+
+
+@functools.lru_cache(maxsize=None)
+def _oracle_probe(spec: QuantSpec, group: int, paired: bool, tol: float):
+    """The monitor's dense-oracle probe as one jitted call (one dispatch,
+    one read-back): stack entry ``i``'s table fetch of ``xx`` (``x``
+    padded to the tables' width) against the fake-quant dense matmul of
+    ``x`` with the entry's kernel, ``np.allclose``'s test at ``tol``."""
+    def probe(xx, x, tables, scales, kernels, i):
+        scale = scales[i]
+        got = pcilt_linear(xx, tables, spec, scale, group, path="gather",
+                           stacked=i, paired=paired)
+        want = jnp.dot(fake_quant(x, spec, scale),
+                       kernels[i].astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
+        return jnp.all(jnp.abs(got - want) <= tol + tol * jnp.abs(want))
+
+    return jax.jit(probe)
 
 
 def convert_mamba_decode(model, params, calib_tokens, ctx=None, *,
@@ -1229,14 +1329,17 @@ def convert_mamba_decode(model, params, calib_tokens, ctx=None, *,
                          mesh=None, mesh_axis: str = "model",
                          table_dtype=jnp.float32, paired: bool = False,
                          head: Optional[str] = None) -> PCILTMambaDecode:
-    """Offline full-PCILT conversion of a ``MambaLM`` decode step.
+    """Offline full-PCILT conversion of a ``MambaLM`` or ``HybridLM``
+    decode step, over every projection site the model reports
+    (``pcilt_sites``).
 
     The once-per-lifetime build for the paper's end-to-end decode story:
 
     1. **calibrate** — one prefill pass over ``calib_tokens`` ``[B, S]``
-       (``MambaLM.calibrate_pcilt``) captures per-layer absmax of every
-       activation the converted step quantizes, turned into per-projection
-       per-layer scales on the symmetric ``cfg.pcilt.act_bits`` grid;
+       (``model.calibrate_pcilt``) captures the absmax of every activation
+       grid the converted step quantizes (per layer, per shared-block
+       application), turned into scales on the symmetric
+       ``cfg.pcilt.act_bits`` grid;
     2. **build** — per-layer conv ``[C, V]`` tables stacked to ``[L, C, V]``
        and, when ``cfg.pcilt.apply_to_gemv``, one layer-stacked
        ``[L, G, V, O]`` grouped-table array per projection
@@ -1245,8 +1348,9 @@ def convert_mamba_decode(model, params, calib_tokens, ctx=None, *,
     3. **hoist** — the jitted decode executor is built once and reused
        every step (:class:`PCILTMambaDecode`).
 
-    ``projections`` restricts the converted set (default: all six —
-    ``nn.ssm.PROJ_NAMES``); ``proj_path`` selects the execution route
+    ``projections`` restricts the converted set (default: every site —
+    a Mamba model's six ``nn.ssm.PROJ_NAMES``, the hybrid's also its shared
+    blocks'); ``proj_path`` selects the execution route
     (``"fused"`` is the deployment path; ``"kernel"`` is the host-packed
     baseline the benchmark measures against; ``"dense_fq"`` the parity
     oracle).  ``table_dtype=jnp.bfloat16`` halves table memory (the stacked
@@ -1277,8 +1381,9 @@ def convert_mamba_decode(model, params, calib_tokens, ctx=None, *,
         return scale_from_amax(jnp.asarray(a, jnp.float32), spec)
 
     proj_scales = None
-    if cfg.pcilt.apply_to_gemv:
-        proj_scales = {"in": to_scale(amax["in"]), "out": to_scale(amax["out"])}
+    if cfg.pcilt.apply_to_gemv:  # every projection grid the model reports
+        proj_scales = {g: to_scale(a) for g, a in amax.items()
+                       if g not in ("conv_in", "head_in")}
     if head is not None and head != "shared":
         raise ValueError(f"head= accepts None or 'shared', got {head!r}")
     pcilt = model.build_pcilt(

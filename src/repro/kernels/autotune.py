@@ -729,8 +729,15 @@ def shared_gemv_candidates(B: int, G: int, V: int, O: int, X: int,
         cand = clamp(TileConfig(Bb=Bb, Gb=G, Ob=ob))
         if cand is not None and cand not in out:
             out.append(cand)
-    if not out:  # degenerate budget: still emit one dispatchable tile
-        out.append(TileConfig(Bb=Bb, Gb=1, Ob=min(128, O_full)))
+    if not out:
+        # the staged pool tile alone oversubscribes the scratch budget (a
+        # pool of many rows): one dispatchable tile, its codes whole
+        # 128-lane blocks (Gb a multiple of 128, or all of G) and its
+        # [X, Gb] pointer one-hot a quarter of the budget; the kernel
+        # raises its VMEM limit to hold the pool tile
+        gb = next((d for d in range(G, 0, -1) if G % d == 0 and d % 128 == 0
+                   and X * d * 4 <= scratch_budget / 4), G)
+        out.append(TileConfig(Bb=Bb, Gb=gb, Ob=min(128, O_full)))
     return out[:7]
 
 

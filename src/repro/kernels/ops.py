@@ -456,7 +456,13 @@ def pcilt_fused_gemv_stacked(
         if cfg is not None:
             tiles = (cfg.Bb, cfg.Gb, cfg.Ob)
         else:
-            tiles = default_tiles(B, G, V, O, itemsize=tables.dtype.itemsize)
+            # the staged table tile is double-buffered and its lanes pad to
+            # 128: two tiles of 7 MiB as laid out, with the pack and one-hot
+            # operands, stay inside the chip's 16 MiB of scoped VMEM (up to
+            # 894 segments stage whole, as at the default 8 MiB)
+            tiles = default_tiles(
+                B, G, V, O, vmem_budget=7 * 2**20 * min(O, 128) // 128,
+                itemsize=tables.dtype.itemsize)
     tiles = _fit_tiles(tiles, B, G, O)
     xp, _ = _pad_axis(x, 0, tiles[0])  # zero rows quantize harmlessly
     tp, _ = _pad_axis(tables, 3, tiles[2] if O >= 128 else 1)

@@ -50,6 +50,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .pcilt_fused import _precision, _quantize_f32, _strip_codes, pack_matrix
 
@@ -156,7 +157,24 @@ def pcilt_shared_gemv_pallas(
         out_shape=jax.ShapeDtypeStruct((B, O), jnp.float32),
         interpret=interpret,
         name="pcilt_shared_gemv",
+        **_vmem_limit(Bb, Gb, Ob, V, X, group, pool.dtype.itemsize),
     )(x, scale, pack, seg_idx, pool_t).astype(pool.dtype)
+
+
+#: VMEM a Pallas call may use on the chip unless it asks for more
+SCOPED_VMEM = 16 * 2**20
+
+
+def _vmem_limit(Bb, Gb, Ob, V, X, group, itemsize):
+    """The compiler parameter raising the call's VMEM limit when its
+    double-buffered pool tile and per-step scratch need more than the
+    default scoped VMEM (a pool of many rows); none otherwise."""
+    need = (2 * (V * X * Ob * itemsize + Bb * Gb * group * 4 + Bb * Ob * 4)
+            + Bb * V * X * 4 + X * Gb * 4 + Gb * group * Gb * 4)
+    if need <= SCOPED_VMEM * 3 // 4:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=min(need + SCOPED_VMEM, 100 * 2**20))}
 
 
 # ----------------------------------------------------------------------------

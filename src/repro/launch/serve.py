@@ -166,6 +166,13 @@ class Engine:
         cspecs = self.model.cache_specs(slots, max_len)
         self.cache = materialize(cspecs, jax.random.PRNGKey(1))
         self.cache = dict(self.cache, pos=jnp.asarray(0, jnp.int32))
+        #: a hybrid's KV rings, kept by length: host copies of each slot's
+        #: ring start and length, and the steps committed since start (each
+        #: writes one position of every slot's ring)
+        self._kv = "kv" in self.cache
+        self._kv_start = np.zeros(slots, np.int64)
+        self._kv_len = np.zeros(slots, np.int64)
+        self._kv_writes = 0
         self.decode = jax.jit(make_decode_step(cfg, mesh))
         self.active: List[Optional[Request]] = [None] * slots
         self.tokens = np.zeros((slots, 1), np.int32)
@@ -269,14 +276,21 @@ class Engine:
             # poisoned ssd state yields finite logits while the corruption
             # persists in the cache.
             with span("step.gate"):
+                # a KV ring is gated by the rows this step wrote
+                # (``kv_rows``), not read whole
                 checks = [jnp.all(jnp.isfinite(logits))]
                 checks += [jnp.all(jnp.isfinite(l))
-                           for l in jax.tree.leaves(new_cache)
+                           for l in jax.tree.leaves(
+                               {k: v for k, v in new_cache.items()
+                                if k != "kv"})
                            if jnp.issubdtype(l.dtype, jnp.floating)]
                 if not bool(jnp.all(jnp.stack(checks))):
                     raise RuntimeError(
                         "non-finite decode outputs or state (NaN/Inf)")
             self.cache = new_cache
+            if self._kv:
+                self._kv_len += 1
+                self._kv_writes += 1
             with span("step.sample"):
                 return np.asarray(jnp.argmax(logits, axis=-1))
 
@@ -348,7 +362,10 @@ class Engine:
 
     def _reset_slot(self, s: int):
         """Zero one slot's recurrent/cache state so a recycled (or evicted)
-        slot can never leak a previous request's context into the next."""
+        slot can never leak a previous request's context into the next.
+        A slot's KV ring is not cleared: its next request starts where the
+        last one ended, with length 0, so what a rollback may restore
+        stays in place."""
         def z(a):
             if hasattr(a, "ndim") and a.ndim >= 2 and a.shape[1] == self.slots:
                 return a.at[:, s].set(0)
@@ -356,16 +373,27 @@ class Engine:
 
         self.cache = dict(self.cache,
                           layers=jax.tree.map(z, self.cache["layers"]))
+        if self._kv:
+            self._kv_start[s] = (self._kv_start[s] + self._kv_len[s]) \
+                % self.max_len
+            self._kv_len[s] = 0
+            self.cache = dict(
+                self.cache, kv_start=jnp.asarray(self._kv_start, jnp.int32),
+                kv_len=jnp.asarray(self._kv_len, jnp.int32))
 
     # -- checkpoint ring -----------------------------------------------------
 
     def _checkpoint(self):
         """Snapshot the full engine state (jax arrays are immutable — holding
-        the refs *is* the snapshot; host-side state is copied)."""
+        the refs *is* the snapshot; host-side state is copied).  KV rings
+        are kept by length: the snapshot holds each slot's start and length,
+        never the rings, so the ring's bytes do not grow with snapshots."""
         with span("checkpoint"):
             self.ckpts.append({
                 "tick": self.tick,
-                "cache": self.cache,
+                "cache": {k: v for k, v in self.cache.items() if k != "kv"},
+                "kv": (self._kv_start.copy(), self._kv_len.copy(),
+                       self._kv_writes),
                 "tokens": self.tokens.copy(),
                 "active": list(self.active),
                 "queue": list(self.queue),
@@ -389,7 +417,8 @@ class Engine:
         keep = [c for c in self.ckpts if c["tick"] <= snap["tick"]
                 and c is not snap] + [snap]
         self.ckpts = deque(keep, maxlen=self.ckpts.maxlen)
-        self.cache = snap["cache"]
+        self.cache = dict(snap["cache"], **(
+            {"kv": self.cache["kv"]} if self._kv else {}))
         self.tokens = snap["tokens"].copy()
         self.active = list(snap["active"])
         self.queue = list(snap["queue"])
@@ -404,6 +433,8 @@ class Engine:
                 retries, degraded, t_admit, nb
             r.t_arrive, r.t_enqueue, r.t_done = t_arrive, t_enqueue, t_done
         self.tick = snap["tick"]
+        if self._kv:
+            self._restore_kv(*snap["kv"])
         # telemetry for replayed ticks will be re-recorded
         self.telemetry = [e for e in self.telemetry if e["tick"] < self.tick]
         if self.monitor is not None:
@@ -415,6 +446,25 @@ class Engine:
             self.monitor.head_last_verified = min(
                 self.monitor.head_last_verified, self.tick)
         log.warning("restored engine state at tick %d", self.tick)
+
+    def _restore_kv(self, start, length, writes):
+        """Take the snapshot's ring starts and lengths over the live rings.
+        Each slot wrote one position a step since then, after its
+        snapshot's positions; a slot whose positions the writes have
+        wrapped onto gives its request back to the queue, to be served
+        again from its prompt."""
+        self._kv_start, self._kv_len = start.copy(), length.copy()
+        since = self._kv_writes - writes
+        for s in np.nonzero(length + since > self.max_len)[0]:
+            req = self.active[s]
+            if req is None:
+                continue
+            log.warning("req %d requeued: its KV positions were overwritten "
+                        "since the restored tick", req.rid)
+            self.active[s] = None
+            req.out, req.degraded, req.outcome = [], False, "queued"
+            self.queue.append(req)
+            self._reset_slot(int(s))
 
     # -- admission / scheduling ----------------------------------------------
 
@@ -643,6 +693,8 @@ class Engine:
             "step_s": t_monitor - t_step,
             "monitor_s": t_commit - t_monitor,
         }
+        if self._kv:  # positions the decode step attended, all slots
+            entry["kv_positions"] = int(self._kv_len.sum())
         self._refills_recorded = self.prefill_ticks
         if self.sentinel and self.monitor is not None:
             entry["saturation"] = self.monitor.saturation_summary()
@@ -855,8 +907,9 @@ def serve_config(arch: str, *, full: bool = False, pcilt: bool = False):
         raise SystemExit("serve demo targets text decoder archs")
     if pcilt:
         if cfg.ssm is None:
-            raise SystemExit("--pcilt serves the converted Mamba decode "
-                             "path; pick an [ssm] arch (e.g. mamba2-130m)")
+            raise SystemExit("--pcilt serves the converted Mamba and hybrid "
+                             "decode paths; pick an [ssm] or [hybrid] arch "
+                             "(e.g. mamba2-130m, zamba2-7b)")
         cfg = dc.replace(cfg, pcilt=cfg.pcilt or PCILTConfig(act_bits=4,
                                                              group=2),
                          dtype=jnp.float32)

@@ -15,7 +15,8 @@ import jax.numpy as jnp
 
 from repro.nn.module import ParamSpec
 from repro.nn.layers import Ctx, dense, embed_spec, rmsnorm_spec, rmsnorm
-from repro.nn.ssm import mamba_spec, mamba_block, mamba_decode, ssm_cache_specs
+from repro.nn.ssm import (PROJ_NAMES, _dims, mamba_spec, mamba_block,
+                          mamba_decode, ssm_cache_specs)
 from .transformer import stack_specs, chunked_ce_loss
 
 __all__ = ["MambaLM"]
@@ -142,7 +143,7 @@ class MambaLM:
         tied-embedding / ``lm_head`` kernel is fake-quantized to
         ``head_weight_bits`` and converted to a **shared-pool** (ext.-3)
         PCILT (``pool [X, V, O]`` + ``seg_idx [G]``), executed by
-        :meth:`_head_logits` on the ``"shared"`` dispatch path.
+        :func:`head_logits` on the ``"shared"`` dispatch path.
 
         The returned bundle carries an ``"integrity"`` record — per-layer
         checksums of every table array, computed on the device
@@ -174,151 +175,67 @@ class MambaLM:
                 params, spec, proj_scales, proj_path, projections, mesh,
                 mesh_axis, table_dtype, paired)
         if head_scale is not None:
-            out["head"] = self._build_head_pcilt(
-                params, head_scale, head_weight_bits)
+            out["head"] = build_head_pcilt(cfg, params, head_scale,
+                                           head_weight_bits)
         from repro.core.serving import pcilt_integrity
 
         out["integrity"] = pcilt_integrity(out)
         return out
-
-    def _build_head_pcilt(self, params, head_scale, head_weight_bits):
-        """Shared-pool (ext.-3) PCILT over the weight-quantized logits head.
-
-        Weight fake-quantization to ``head_weight_bits`` gives the kernel a
-        low segment cardinality, so the ``[G, V, O]`` grouped tables dedupe
-        into a ``pool [X, V, O]`` + ``seg_idx [G]`` pointer vector; the
-        quantized kernel itself rides along as the exact dense oracle the
-        demoted path evaluates (``fetch(x) == fake_quant(x) @ kernel_q`` on
-        the activation grid — zero-padded alignment rows contribute 0).
-        Every logit is an integer multiple of ``step = act scale x weight
-        scale``; both paths snap to that grid (:meth:`_head_logits`).
-        """
-        from repro.core import (QuantSpec, build_shared_grouped_tables,
-                                fake_quant, scale_from_amax)
-
-        cfg = self.cfg
-        group = cfg.pcilt.group
-        if cfg.tie_embeddings:
-            k = params["embed"]["embedding"].astype(jnp.float32).T  # [d, Vp]
-        else:
-            k = params["lm_head"]["kernel"].astype(jnp.float32)
-        wspec = QuantSpec(bits=head_weight_bits, symmetric=True)
-        w_scale = scale_from_amax(jnp.max(jnp.abs(k)), wspec)
-        kq = fake_quant(k, wspec, w_scale)
-        n = kq.shape[0]
-        pad = (-n) % group
-        kp = jnp.concatenate(
-            [kq, jnp.zeros((pad, kq.shape[1]), kq.dtype)], 0) if pad else kq
-        spec = QuantSpec(bits=cfg.pcilt.act_bits, symmetric=True)
-        shared = build_shared_grouped_tables(
-            kp, spec, head_scale, group)
-        return {"pool": shared.pool, "seg_idx": shared.seg_idx,
-                "group": group, "spec": spec,
-                "scale": jnp.asarray(head_scale, jnp.float32),
-                "step": jnp.asarray(head_scale * w_scale, jnp.float32),
-                "kernel_q": kq, "n": n + pad}
-
-    def _head_logits(self, head, x, ok=None, mesh=None):
-        """Last-position logits through the shared-pool PCILT head.
-
-        ``x [B, d]`` -> ``[B, padded_vocab]``.  ``ok`` (traced bool) demotes
-        the fetch to the exact fake-quant dense oracle under ``lax.cond`` —
-        the response to a corrupted pool entry or re-aimed ``seg_idx``
-        pointer.  ``mesh`` (the projections' table mesh) runs the fetch
-        kernel replicated on each of its devices.
-
-        Quantized activations times quantized weights put every logit on the
-        grid ``k * step``, and many logits tie exactly.  Both paths snap
-        their f32 sums back to the grid, so the fetch and the oracle return
-        the same bits and break ties (greedy argmax) the same way — their
-        f32 summation orders differ by far less than half a step."""
-        from repro.core import fake_quant, pcilt_linear
-        from repro.core.lut_layers import replicated_on
-        from repro.core.pcilt import SharedGroupedTables
-
-        cfg = self.cfg
-
-        def shared_fetch(xx, pool, seg_idx, scale):
-            shared = SharedGroupedTables(pool=pool, seg_idx=seg_idx,
-                                         group=head["group"])
-            return pcilt_linear(xx.astype(jnp.float32), shared, head["spec"],
-                                scale, head["group"], path="shared")
-
-        def _fetch(xx):
-            pad = head["n"] - xx.shape[-1]
-            if pad:  # group-alignment slots (zero weights -> zero tables)
-                xx = jnp.concatenate(
-                    [xx, jnp.zeros((*xx.shape[:-1], pad), xx.dtype)], -1)
-            return snap(replicated_on(mesh, shared_fetch)(
-                xx, head["pool"], head["seg_idx"], head["scale"]))
-
-        def _oracle(xx):
-            xq = fake_quant(xx.astype(jnp.float32), head["spec"],
-                            head["scale"])
-            return snap(jnp.dot(xq, head["kernel_q"],
-                                precision=jax.lax.Precision.HIGHEST))
-
-        def snap(y):
-            step = head["step"]
-            return (jnp.round(y / step) * step).astype(cfg.dtype)
-
-        if ok is None:
-            return _fetch(x)
-        return jax.lax.cond(jnp.asarray(ok, bool), _fetch, _oracle, x)
 
     def _build_proj_pcilt(self, params, spec, proj_scales, proj_path,
                           projections, mesh, mesh_axis, table_dtype,
                           paired=False):
         """Stacked grouped tables per decode projection: dense
         ``[L, G, V, O]`` or, with ``paired``, seg-major ``[G2, L, V2, O]``
-        paired stacks (``build_paired_stacked_tables``)."""
-        from repro.core import build_grouped_tables
-        from repro.core.pcilt import build_paired_stacked_tables
-        from repro.core.lut_layers import mesh_shard_count
-        from repro.nn.ssm import PROJ_NAMES
+        paired stacks (``core.serving.build_site_tables``)."""
+        from repro.core.serving import build_site_tables
 
-        cfg = self.cfg
-        group = cfg.pcilt.group
-        tabs, scales = {}, {}
-        for name in (projections or PROJ_NAMES):
-            ks = params["blocks"]["mixer"][name]["kernel"]  # [L, n, O]
-            s_l = jnp.asarray(
-                proj_scales["out" if name == "wo" else "in"], jnp.float32)
-            _, n, O = ks.shape
-            pad_n = (-n) % group
-
-            if paired:
-                # build_paired_stacked_tables pads n to the pair width
-                # itself (alignment + phantom slots from zero weights) and
-                # returns the seg-major [G2, L, V2, O] layout; building in
-                # f32 and casting once keeps bf16 tables rounding-safe.
-                t = build_paired_stacked_tables(
-                    ks.astype(jnp.float32), spec, s_l, group
-                ).astype(table_dtype)
-                seg_count, seg_axis = t.shape[0], 0
-            else:
-                def build(w, s):
-                    wf = w.astype(jnp.float32)
-                    if pad_n:  # group-alignment slots from zero weights
-                        wf = jnp.concatenate(
-                            [wf, jnp.zeros((pad_n, wf.shape[-1]), wf.dtype)],
-                            0)
-                    return build_grouped_tables(wf, spec, s, group)
-
-                t = jax.vmap(build)(ks, s_l).astype(table_dtype)
-                seg_count, seg_axis = t.shape[1], 1
-            if mesh is not None and mesh_shard_count(
-                    mesh, mesh_axis, seg_count) > 1:
-                from repro.nn.module import pcilt_table_sharding
-
-                t = jax.device_put(t, pcilt_table_sharding(
-                    mesh, seg_count, ndim=4, mesh_axis=mesh_axis,
-                    seg_axis=seg_axis))
-            tabs[name] = t
-            scales[name] = s_l
+        grids = {n: g for n, (_, g) in self.pcilt_sites().items()
+                 if n in (projections or PROJ_NAMES)}
+        kernels = self.site_kernels(params)
+        scales = {n: jnp.asarray(proj_scales[g], jnp.float32)
+                  for n, g in grids.items()}
+        tabs = build_site_tables(
+            {n: kernels[n] for n in grids}, scales, spec, self.cfg.pcilt.group,
+            table_dtype=table_dtype, mesh=mesh, mesh_axis=mesh_axis,
+            paired=paired)
         return {"tables": tabs, "scales": scales, "spec": spec,
-                "group": group, "path": proj_path, "mesh": mesh,
-                "mesh_axis": mesh_axis, "paired": paired}
+                "group": self.cfg.pcilt.group, "path": proj_path,
+                "mesh": mesh, "mesh_axis": mesh_axis, "paired": paired}
+
+    # -- the table sites, for conversion and the health monitor --------------
+
+    @property
+    def n_units(self) -> int:
+        """Units the health monitor checks and demotes: the layers."""
+        return self.cfg.n_layers
+
+    def pcilt_sites(self):
+        """``{site: (stack, grid)}``: each projection a table conversion
+        serves, the stack its tables ride (one entry per layer) and the
+        quantizer grid of its input (the drift sentinel's grids)."""
+        return {n: ("layer", "out" if n == "wo" else "in") for n in PROJ_NAMES}
+
+    def site_kernels(self, params):
+        """``{site: [stack, n, O]}`` dense kernels of every site."""
+        return {n: params["blocks"]["mixer"][n]["kernel"] for n in PROJ_NAMES}
+
+    def site_index(self, stack: str, unit: int):
+        """Index into a ``stack`` of the tables a unit uses (None: none)."""
+        return unit if stack == "layer" else None
+
+    def unit_label(self, unit: int):
+        return int(unit)
+
+    def sat_grids(self):
+        """``{grid: (first unit, units, saturable elements per row)}`` of
+        every quantizer grid the monitored decode step reports."""
+        cfg = self.cfg
+        d_inner, _, conv_ch = _dims(cfg)
+        L = cfg.n_layers
+        return {"in": (0, L, cfg.d_model),
+                "conv": (0, L, cfg.ssm.conv_kernel * conv_ch),
+                "out": (0, L, d_inner)}
 
     def calibrate_pcilt(self, params, batch, ctx: Ctx):
         """Calibration prefill: one full-sequence pass over a calibration
@@ -437,8 +354,92 @@ class MambaLM:
             if head is None:
                 logits = self._logits(params, x)[:, -1]
             else:
-                logits = self._head_logits(head, x[:, -1], head_ok, mesh)
+                logits = head_logits(cfg, head, x[:, -1], head_ok, mesh)
         new_cache = dict(cache, layers=new_states, pos=pos + 1)
         if with_stats:
             return logits, new_cache, sat
         return logits, new_cache
+
+
+def build_head_pcilt(cfg, params, head_scale, head_weight_bits):
+    """Shared-pool (ext.-3) PCILT over the weight-quantized logits head.
+
+    Weight fake-quantization to ``head_weight_bits`` gives the kernel a
+    low segment cardinality, so the ``[G, V, O]`` grouped tables dedupe
+    into a ``pool [X, V, O]`` + ``seg_idx [G]`` pointer vector; the
+    quantized kernel itself rides along as the exact dense oracle the
+    demoted path evaluates (``fetch(x) == fake_quant(x) @ kernel_q`` on
+    the activation grid — zero-padded alignment rows contribute 0).
+    Every logit is an integer multiple of ``step = act scale x weight
+    scale``; both paths snap to that grid (:func:`head_logits`).
+    """
+    from repro.core import (QuantSpec, build_shared_grouped_tables,
+                            fake_quant, scale_from_amax)
+
+    group = cfg.pcilt.group
+    if cfg.tie_embeddings:
+        k = params["embed"]["embedding"].astype(jnp.float32).T  # [d, Vp]
+    else:
+        k = params["lm_head"]["kernel"].astype(jnp.float32)
+    wspec = QuantSpec(bits=head_weight_bits, symmetric=True)
+    w_scale = scale_from_amax(jnp.max(jnp.abs(k)), wspec)
+    kq = fake_quant(k, wspec, w_scale)
+    n = kq.shape[0]
+    pad = (-n) % group
+    kp = jnp.concatenate(
+        [kq, jnp.zeros((pad, kq.shape[1]), kq.dtype)], 0) if pad else kq
+    spec = QuantSpec(bits=cfg.pcilt.act_bits, symmetric=True)
+    shared = build_shared_grouped_tables(
+        kp, spec, head_scale, group)
+    return {"pool": shared.pool, "seg_idx": shared.seg_idx,
+            "group": group, "spec": spec,
+            "scale": jnp.asarray(head_scale, jnp.float32),
+            "step": jnp.asarray(head_scale * w_scale, jnp.float32),
+            "kernel_q": kq, "n": n + pad}
+
+def head_logits(cfg, head, x, ok=None, mesh=None):
+    """Last-position logits through the shared-pool PCILT head.
+
+    ``x [B, d]`` -> ``[B, padded_vocab]``.  ``ok`` (traced bool) demotes
+    the fetch to the exact fake-quant dense oracle under ``lax.cond`` —
+    the response to a corrupted pool entry or re-aimed ``seg_idx``
+    pointer.  ``mesh`` (the projections' table mesh) runs the fetch
+    kernel replicated on each of its devices.
+
+    Quantized activations times quantized weights put every logit on the
+    grid ``k * step``, and many logits tie exactly.  Both paths snap
+    their f32 sums back to the grid, so the fetch and the oracle return
+    the same bits and break ties (greedy argmax) the same way — their
+    f32 summation orders differ by far less than half a step."""
+    from repro.core import fake_quant, pcilt_linear
+    from repro.core.lut_layers import replicated_on
+    from repro.core.pcilt import SharedGroupedTables
+
+
+    def shared_fetch(xx, pool, seg_idx, scale):
+        shared = SharedGroupedTables(pool=pool, seg_idx=seg_idx,
+                                     group=head["group"])
+        return pcilt_linear(xx.astype(jnp.float32), shared, head["spec"],
+                            scale, head["group"], path="shared")
+
+    def _fetch(xx):
+        pad = head["n"] - xx.shape[-1]
+        if pad:  # group-alignment slots (zero weights -> zero tables)
+            xx = jnp.concatenate(
+                [xx, jnp.zeros((*xx.shape[:-1], pad), xx.dtype)], -1)
+        return snap(replicated_on(mesh, shared_fetch)(
+            xx, head["pool"], head["seg_idx"], head["scale"]))
+
+    def _oracle(xx):
+        xq = fake_quant(xx.astype(jnp.float32), head["spec"],
+                        head["scale"])
+        return snap(jnp.dot(xq, head["kernel_q"],
+                            precision=jax.lax.Precision.HIGHEST))
+
+    def snap(y):
+        step = head["step"]
+        return (jnp.round(y / step) * step).astype(cfg.dtype)
+
+    if ok is None:
+        return _fetch(x)
+    return jax.lax.cond(jnp.asarray(ok, bool), _fetch, _oracle, x)

@@ -160,19 +160,50 @@ def _proj(params, name, x, cfg, proj, with_stats: bool = False):
     return jax.lax.cond(ok, _fetch, _oracle, x)
 
 
+def _groups(cfg):
+    """``(groups held, channels per norm group)``.  A chip that holds
+    ``heads_held`` of the heads holds the B/C projections of the groups
+    those heads sit in; the gated norm's groups are the published ones,
+    cut to the channels held (within one group, the statistic covers only
+    this chip's channels of it)."""
+    s = cfg.ssm
+    heads = s.expand * cfg.d_model // s.head_dim
+    per_group = heads // s.n_groups
+    held = s.heads_held or heads
+    if held % per_group and per_group % held:
+        raise ValueError(
+            f"heads_held {held} neither fills whole groups of {per_group} "
+            f"heads nor lies inside one")
+    groups = max(held // per_group, 1)
+    return groups, min(per_group, held) * s.head_dim
+
+
 def _dims(cfg):
     s = cfg.ssm
-    d_inner = s.expand * cfg.d_model
-    n_heads = d_inner // s.head_dim
-    conv_ch = d_inner + 2 * s.n_groups * s.d_state
+    n_heads = s.heads_held or s.expand * cfg.d_model // s.head_dim
+    d_inner = n_heads * s.head_dim
+    conv_ch = d_inner + 2 * _groups(cfg)[0] * s.d_state
     return d_inner, n_heads, conv_ch
+
+
+def _gated_norm(params, cfg, y):
+    """RMSNorm of the gated inner stream by group (``RMSNormGated`` with
+    ``group_size = d_inner / ngroups``); one group is the plain norm."""
+    groups, size = _groups(cfg)
+    if y.shape[-1] == size:
+        return rmsnorm(params, y, cfg.norm_eps)
+    yg = y.reshape(*y.shape[:-1], -1, size).astype(jnp.float32)
+    yg = yg * jax.lax.rsqrt(jnp.mean(yg * yg, -1, keepdims=True)
+                            + cfg.norm_eps)
+    return (yg.reshape(y.shape)
+            * params["scale"].astype(jnp.float32)).astype(y.dtype)
 
 
 def mamba_spec(cfg, dtype=jnp.float32):
     s = cfg.ssm
     d = cfg.d_model
     d_inner, H, conv_ch = _dims(cfg)
-    GN = s.n_groups * s.d_state
+    GN = _groups(cfg)[0] * s.d_state
     return {
         "wz": dense_spec(d, d_inner, ("embed", "mlp"), dtype=dtype),
         "wx": dense_spec(d, d_inner, ("embed", "mlp"), dtype=dtype),
@@ -344,9 +375,10 @@ def _split_heads(cfg, ctx, x_in, B_in, C_in, dt_in):
     Bsz, T = x_in.shape[:2]
     xh = x_in.reshape(Bsz, T, H, s.head_dim)
     xh = ctx.constrain(xh, "batch", None, "ssm_heads", None)
-    rep = H // s.n_groups
-    Bm = jnp.repeat(B_in.reshape(Bsz, T, s.n_groups, s.d_state), rep, axis=2)
-    Cm = jnp.repeat(C_in.reshape(Bsz, T, s.n_groups, s.d_state), rep, axis=2)
+    G = _groups(cfg)[0]
+    rep = H // G
+    Bm = jnp.repeat(B_in.reshape(Bsz, T, G, s.d_state), rep, axis=2)
+    Cm = jnp.repeat(C_in.reshape(Bsz, T, G, s.d_state), rep, axis=2)
     Bm = ctx.constrain(Bm, "batch", None, "ssm_heads", None)
     Cm = ctx.constrain(Cm, "batch", None, "ssm_heads", None)
     return xh, Bm, Cm
@@ -359,7 +391,7 @@ def _finish(params, cfg, ctx, y, xh, z, proj=None, return_inner=False,
     y = y + params["D"].astype(y.dtype)[None, None, :, None] * xh
     y = y.reshape(Bsz, T, d_inner)
     y = y * jax.nn.silu(z.astype(y.dtype))
-    y = rmsnorm(params["norm"], y, cfg.norm_eps)
+    y = _gated_norm(params["norm"], cfg, y)
     out = _proj(params, "wo", y, cfg, proj, with_stats=with_stats)
     if with_stats:
         out, count, ratio = out
@@ -402,7 +434,7 @@ def mamba_block(params, cfg, ctx: Ctx, x: jax.Array,
     xBC, _ = _conv1d(params, cfg, xBC, pcilt=pcilt)
     xBC = jax.nn.silu(xBC)
     xi, Bi, Ci = jnp.split(
-        xBC, [d_inner, d_inner + s.n_groups * s.d_state], axis=-1
+        xBC, [d_inner, d_inner + _groups(cfg)[0] * s.d_state], axis=-1
     )
 
     dt = jax.nn.softplus(dt + params["dt_bias"].astype(jnp.float32))
@@ -468,7 +500,7 @@ def mamba_decode(
             xBC, conv_state = conv
         xBC = jax.nn.silu(xBC)
         xi, Bi, Ci = jnp.split(
-            xBC, [d_inner, d_inner + s.n_groups * s.d_state], axis=-1
+            xBC, [d_inner, d_inner + _groups(cfg)[0] * s.d_state], axis=-1
         )
 
     with jax.named_scope("ssd"):

@@ -83,7 +83,11 @@ def test_moe_arch_extras():
     mm = get_config("mamba2-130m")
     assert mm.ssm.d_state == 128
     zb = get_config("zamba2-7b")
-    assert zb.ssm.d_state == 64 and zb.shared_attn_period == 6
+    assert zb.ssm.d_state == 64 and zb.ssm.n_groups == 2
+    assert zb.hybrid_layer_ids == (6, 11, 17, 23, 29, 35, 41, 47, 53, 59, 65,
+                                   71, 77)
+    assert (zb.n_shared_attn_blocks, zb.adapter_rank, zb.head_dim) == \
+        (2, 128, 224)
     lv = get_config("llava-next-mistral-7b")
     assert lv.window == 4096
     qw = get_config("qwen1.5-4b")

@@ -585,3 +585,85 @@ def test_per_slot_count_executors_cached_and_dropped_on_rehoist(donor):
     pd.rehoist()
     assert pd._execs == {}  # stale closures dropped, rebuilt on next step
     assert pd.executor(2) is not e2
+
+
+# ---- the hybrid: KV caches kept by length in the checkpoint ring -------------
+
+
+def _hybrid_cfg():
+    return dc.replace(get_smoke_config("zamba2-7b"),
+                      pcilt=PCILTConfig(act_bits=BITS, group=GROUP),
+                      dtype=jnp.float32)
+
+
+@pytest.fixture(scope="module")
+def hybrid_donor():
+    return Engine(_hybrid_cfg(), max_len=64, slots=2, pcilt=True,
+                  sentinel=False)
+
+
+def _hybrid_engine(donor, max_len=64, **kw):
+    return Engine(_hybrid_cfg(), max_len=max_len, slots=2, pcilt=True,
+                  sentinel=False,
+                  pcilt_bundle=_copy_bundle(donor.pdecode.pcilt), **kw)
+
+
+@pytest.fixture(scope="module")
+def hybrid_ref(hybrid_donor):
+    """A fault-free hybrid run: its tokens, telemetry and step count."""
+    ref = _hybrid_engine(hybrid_donor)
+    reqs = _requests(ref.cfg, n=6, max_new=6)
+    stats = ref.run(reqs)
+    return [list(r.out) for r in reqs], stats["telemetry"], ref.steps
+
+
+@pytest.mark.parametrize("wrapped", [False, True])
+def test_hybrid_rollback_restores_positions_and_tokens(hybrid_donor,
+                                                       hybrid_ref, wrapped,
+                                                       caplog):
+    """Step faults restore the newest checkpoint: the live KV rings with
+    the snapshot's positions.  The tokens served after each rollback equal
+    a fault-free run's, and so do the positions each tick attends.  With
+    ``wrapped``, the first fault comes after a ring's worth of writes is
+    counted since the snapshot: the positions of the requests it restores
+    may be overwritten, so they are served again from their prompts, to
+    the same tokens."""
+    want, want_telemetry, steps = hybrid_ref
+    # more apart than a tick's steps, all inside the run
+    faults = tuple(range(9, steps, 9))
+    inj = FaultInjector(fail_at=faults, seed=0)
+
+    def fault(e, k):
+        if wrapped and k == faults[0]:
+            e._kv_writes += e.max_len
+        inj.maybe_fail(k)
+
+    eng = _hybrid_engine(hybrid_donor, chaos={
+        k: [lambda e, k=k: fault(e, k)] for k in faults})
+    reqs = _requests(eng.cfg, n=6, max_new=6)
+    stats = eng.run(reqs)
+    assert stats["restarts"] == len(faults) >= 3
+    assert [r.outcome for r in reqs] == ["served"] * len(reqs)
+    assert [list(r.out) for r in reqs] == want
+    assert np.array_equal(np.asarray(eng.cache["kv_len"]), eng._kv_len)
+    requeued = [r for r in caplog.records
+                if "KV positions were overwritten" in r.getMessage()]
+    assert bool(requeued) == wrapped
+    if not wrapped:
+        assert [e["kv_positions"] for e in stats["telemetry"]] == \
+            [e["kv_positions"] for e in want_telemetry]
+
+
+def test_hybrid_checkpoint_ring_keeps_no_kv_cache(hybrid_donor):
+    """The ring's snapshots hold each slot's ring start and length, never a
+    KV ring: what the ring keeps alive is the small state per snapshot,
+    however many snapshots it holds."""
+    eng = _hybrid_engine(hybrid_donor)
+    eng.run(_requests(eng.cfg, n=4, max_new=8))
+    assert len(eng.ckpts) == eng.ckpts.maxlen
+    kv_shape = eng.cache["kv"][0]["k"].shape
+    held = [a for c in eng.ckpts for a in jax.tree.leaves(c["cache"])]
+    assert not any(a.shape == kv_shape for a in held)
+    small = sum(a.nbytes for a in jax.tree.leaves(
+        {k: v for k, v in eng.cache.items() if k != "kv"}))
+    assert sum(a.nbytes for a in held) == len(eng.ckpts) * small

@@ -269,7 +269,7 @@ def test_ewma_classification_and_typed_demotion(env):
     z = {"count": np.zeros(L, np.int64), "ratio": np.zeros(L)}
 
     def sat(grid, layer, rate, ratio):
-        s = {g: dict(z) for g in mon.SAT_GRIDS}
+        s = {g: dict(z) for g in mon.grids}
         cnt = np.zeros(L, np.int64)
         cnt[layer] = int(rate * mon._sat_elems[grid])
         rat = np.zeros(L)

@@ -203,3 +203,81 @@ def test_the_compiled_step_names_its_parts(traced):
     for scope in ("embed", "head", block + "in_proj", block + "conv",
                   block + "ssd", block + "out_proj"):
         assert f'op_name="jit(pcilt_decode_step)/{scope}/' in text, scope
+
+
+# -- the hybrid: the shared block's scope, checks and KV positions -----------
+
+
+@pytest.fixture(scope="module")
+def traced_hybrid(tmp_path_factory):
+    """A hybrid smoke engine (7 layers, shared blocks at layers 2 and 5)
+    serving through tables under the profiler, long enough for the
+    monitor's round robin to reach both shared-block applications (the
+    drift sentinel off: a rank-8 adapter's grid reads a single clipped
+    activation as a 6% rate)."""
+    from jax.profiler import ProfileData
+
+    cfg = dc.replace(get_smoke_config("zamba2-7b"),
+                     pcilt=PCILTConfig(act_bits=4, group=2),
+                     dtype=jnp.float32)
+    eng = Engine(cfg, max_len=64, slots=2, pcilt=True, sentinel=False)
+    reqs = _requests(cfg, n=3, max_new=8)
+    out = str(tmp_path_factory.mktemp("trace_hybrid"))
+    jax.profiler.start_trace(out)
+    try:
+        stats = eng.run(reqs)
+    finally:
+        jax.profiler.stop_trace()
+    assert all(r.outcome == "served" for r in reqs)
+    (path,) = glob.glob(os.path.join(out, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    spans = sorted(((ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                     dict(ev.stats))
+                    for plane in ProfileData.from_file(path).planes
+                    for line in plane.lines for ev in line.events
+                    if ev.name.startswith("serve.")),
+                   key=lambda s: (s[1], -s[2]))
+    return eng, stats, spans
+
+
+def test_hybrid_checks_name_the_shared_block_sites(traced_hybrid):
+    """Layers and shared-block applications take their turn under
+    ``serve.monitor.crc_layer``, an application named ``shared.<j>``, and
+    ``shared_checks`` counts the applications' checks."""
+    eng, stats, spans = traced_hybrid
+    ticks, units = stats["decode_ticks"], eng.model.n_units
+    L = eng.cfg.n_layers
+    assert ticks > units
+    crc = named(spans, "serve.monitor.crc_layer")
+    want = [t % units if t % units < L else f"shared.{t % units - L}"
+            for t in range(ticks)]
+    assert [s[3]["layer"] for s in crc] == want
+    mon = eng.monitor
+    assert mon.shared_checks == sum(isinstance(w, str) for w in want)
+    assert mon.layer_checks + mon.shared_checks == ticks
+    pd = eng.pdecode
+    assert {s[3]["bytes"] for s in crc if s[3]["layer"] == "shared.0"} == \
+        {pd.layer_check_bytes(L)}
+    assert stats["shared_checks"] == mon.shared_checks
+
+
+def test_hybrid_telemetry_counts_the_positions_attended(traced_hybrid):
+    eng, stats, _ = traced_hybrid
+    tel = stats["telemetry"]
+    assert all(0 < e["kv_positions"] <= eng.slots * eng.max_len
+               for e in tel)
+    assert tel[-1]["kv_positions"] == int(np.asarray(
+        eng.cache["kv_len"]).sum())
+
+
+def test_the_compiled_hybrid_step_names_its_parts(traced_hybrid):
+    eng, _, _ = traced_hybrid
+    pd = eng.pdecode
+    lmask, hmask = eng.monitor.ok_masks()
+    text = pd.executor(eng.slots, stats=True).lower(
+        eng.params, eng.cache, jnp.asarray(eng.tokens), lmask, hmask,
+        pd.bundle_arrays()).compile().as_text()
+    for scope in ("embed", "head", "blocks/while/body/closed_call/in_proj",
+                  "shared/attn", "shared/mlp", "shared/adapter",
+                  "shared/linear"):
+        assert f'op_name="jit(pcilt_decode_step)/{scope}/' in text, scope
