@@ -396,12 +396,11 @@ def _build_families() -> List[Family]:
     def shared_gemv_trace(s, c):
         tiles = ops._fit_tiles((c.Bb, c.Gb, c.Ob), s["B"], s["G"], s["O"])
         Bp = _round_up(s["B"], tiles[0])
-        Op = _padded_O(s["O"], tiles[2])
         j = mk(pcilt_shared_gemv_pallas,
                sds((Bp, s["G"] * s["group"]), jnp.float32),
                sds((1, 1), jnp.float32),
                sds((1, s["G"]), jnp.int32),
-               sds((s["X"], s["V"], Op), tdt(s)),
+               sds((s["X"], s["V"], s["O"]), tdt(s)),
                bits=s["bits"], zero_point=(1 << s["bits"]) // 2,
                group=s["group"], tiles=tiles, interpret=True)
         return j, tiles
@@ -436,13 +435,12 @@ def _build_families() -> List[Family]:
                                     s["Ho"], s["G"], s["O"])
         Hp = (s["Ho"] - 1) * s["stride"] + s["kh"]
         Wp = (s["Wo"] - 1) * s["stride"] + s["kw"]
-        Op = _padded_O(s["O"], tiles[2])
         j = mk(pcilt_shared_conv2d_pallas,
                sds((s["B"], Hp, Wp, s["C"]), jnp.float32),
                sds((1, 1), jnp.float32),
                sds((1, 1), jnp.int32),
                sds((1, s["G"]), jnp.int32),
-               sds((s["X"], s["V"], Op), tdt(s)),
+               sds((s["X"], s["V"], s["O"]), tdt(s)),
                bits=s["bits"], zero_point=(1 << s["bits"]) // 2,
                group=s["group"], kh=s["kh"], kw=s["kw"], stride=s["stride"],
                n_total=s["G"] * s["group"], tiles=tiles, interpret=True)

@@ -397,8 +397,8 @@ def _fit_scratch_gb(G: int, R: int, V: int, onehot_itemsize: int = 4,
     the kernels materialize each grid step: the ``[R, Gb, V]`` one-hot
     (``R`` = rows per step — ``Bb`` for GEMV, ``Hb*Wo`` for conv) in
     ``onehot_itemsize`` bytes, plus ``fixed_bytes`` of Gb-independent scratch
-    (the shared kernels' ``[R, V, X]`` counts and staged ``[V, X, Ob]``
-    pool).  Replaces try-compile pruning: candidates above the bound used to
+    (the shared kernels' ``[R, V, X]`` counts and staged ``[V, Ob, X]``
+    pool tile).  Replaces try-compile pruning: candidates above the bound used to
     be generated anyway and relied on TPU compile-rejection inside ``tune``
     — every rejection a wasted compile.  Returns the largest ``Gb | G``
     admitted (>= 1, so degenerate budgets still yield a dispatchable tile).
@@ -676,8 +676,9 @@ def _div_down(x: int, cap: int) -> int:
 
 def _shared_fixed_bytes(R: int, V: int, X: int, Ob: int, itemsize: int) -> int:
     """Gb-independent per-step scratch of the shared kernels: the f32
-    ``[R, V, X]`` counts plus the staged (pre-transposed) ``[V, X, Ob]``
-    pool tile."""
+    ``[R, V, X]`` counts plus the staged ``[V, Ob, X]`` pool tile (its
+    elements; the kernel's own VMEM limit also counts the lane padding of a
+    pool of fewer than 128 rows)."""
     return R * V * X * 4 + V * X * Ob * itemsize
 
 
@@ -687,8 +688,8 @@ def shared_gemv_candidates(B: int, G: int, V: int, O: int, X: int,
                            ) -> List[TileConfig]:
     """Tilings for the shared-pool GEMV (``kernels/pcilt_shared.py``).
 
-    The staged table operand is the deduped ``[X, V, Ob]`` pool — its VMEM
-    footprint is *independent of Gb*, so unlike the dense kernels ``Gb`` only
+    The staged table operand is the deduped pool's ``[V, Ob, X]`` tile — its
+    VMEM footprint is *independent of Gb*, so unlike the dense kernels ``Gb`` only
     trades one-hot scratch / MXU contraction size against grid steps.  The
     dense sweep stays valid (its budget is just conservative), plus "stage
     as many groups as the scratch admits": the analytic bound

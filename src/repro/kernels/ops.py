@@ -854,9 +854,8 @@ def pcilt_shared_gemv(
         tiles = (cfg.Bb, cfg.Gb, cfg.Ob)
     tiles = _fit_tiles(tiles, B, G, O)
     xp, _ = _pad_axis(x, 0, tiles[0])  # zero rows quantize harmlessly
-    pp, _ = _pad_axis(pool, 2, tiles[2] if O >= 128 else 1)
-    out = pcilt_shared_gemv_pallas(xp, s2, idx2, pp, tiles=tiles, **kw)
-    return out[:B, :O]
+    out = pcilt_shared_gemv_pallas(xp, s2, idx2, pool, tiles=tiles, **kw)
+    return out[:B]
 
 
 def _shared_gemv_bench(x, s2, idx2, pool, cfg, kw):
@@ -864,9 +863,8 @@ def _shared_gemv_bench(x, s2, idx2, pool, cfg, kw):
     G = idx2.shape[-1]
     tiles = _fit_tiles((cfg.Bb, cfg.Gb, cfg.Ob), B, G, O)
     xp, _ = _pad_axis(x, 0, tiles[0])
-    pp, _ = _pad_axis(pool, 2, tiles[2] if O >= 128 else 1)
     return lambda: pcilt_shared_gemv_pallas(
-        xp, s2, idx2, pp, tiles=tiles, **kw
+        xp, s2, idx2, pool, tiles=tiles, **kw
     ).block_until_ready()
 
 
@@ -930,16 +928,13 @@ def pcilt_shared_conv2d(
                                                pool.dtype.itemsize, Wo=Wo)[0]
         tiles = (cfg.row_tile, cfg.Gb, cfg.Ob)
     Hb, Gb, Ob = _fit_conv_tiles(tiles, Ho, G, O)
-    pp, _ = _pad_axis(pool, 2, Ob if O >= 128 else 1)
-    out = pcilt_shared_conv2d_pallas(x, s2, seg2, idx2, pp,
-                                     tiles=(Hb, Gb, Ob), **kw_args)
-    return out[..., :O]
+    return pcilt_shared_conv2d_pallas(x, s2, seg2, idx2, pool,
+                                      tiles=(Hb, Gb, Ob), **kw_args)
 
 
 def _shared_conv2d_bench(x, s2, seg2, idx2, pool, cfg, kw_args, Ho):
     G, O = idx2.shape[-1], pool.shape[-1]
     Hb, Gb, Ob = _fit_conv_tiles((cfg.row_tile, cfg.Gb, cfg.Ob), Ho, G, O)
-    pp, _ = _pad_axis(pool, 2, Ob if O >= 128 else 1)
     return lambda: pcilt_shared_conv2d_pallas(
-        x, s2, seg2, idx2, pp, tiles=(Hb, Gb, Ob), **kw_args
+        x, s2, seg2, idx2, pool, tiles=(Hb, Gb, Ob), **kw_args
     ).block_until_ready()

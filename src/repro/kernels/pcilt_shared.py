@@ -23,15 +23,19 @@ The kernels here stage **the pool and the pointers, never the dense tables**:
       oh_v[r, g]       = (off[r, g] == v)          # [R, Gb] per v
       sel[x, g]        = (seg_idx[g] == x)         # [X, Gb] — tiny
       counts_v[r, x]   = sum_g oh_v[r, g] * sel[x, g]
-      out[r, :]       += sum_v counts_v @ pool_t[v]
+      out[r, o]       += sum_v sum_x counts_v[r, x] * pool[x, v, o]
 
-  where ``pool_t`` is the pool staged **pre-transposed** to ``[V, X, Ob]``
-  (done once per call by the wrapper) so each offset value's ``[X, Ob]``
-  slab is a leading-axis slice.  The fetch contraction therefore scales
-  with the pool cardinality ``X``, not the segment count ``G``, mirroring
-  exactly how ext. 3 makes the table *memory* scale with ``X``.  No
-  data-dependent addressing reaches the memory system (compares + 2-D
-  matmuls, TPU-friendly);
+  The kernel reads the pool **where it lies**: the TPU stores a pool whose
+  row count ``X`` fills whole 128-lane tiles (both served heads) with its
+  rows minor, ``[V, O, X]`` in memory, so the wrapper hands the kernel that
+  view (``transpose(pool, (1, 2, 0))``, a bitcast there) and each offset
+  value's ``[Ob, X]`` slab is a leading-axis slice of the ``[V, Ob, X]``
+  tile.  Nothing rewrites or pads the pool per call; the last output tile
+  may run past ``O`` (its extra columns are dropped).  The fetch
+  contraction scales with the pool cardinality ``X``, not the segment
+  count ``G``, mirroring exactly how ext. 3 makes the table *memory* scale
+  with ``X``.  No data-dependent addressing reaches the memory system
+  (compares + 2-D matmuls, TPU-friendly);
 * the activation side is the dense-fused pipeline's — quantize in VMEM and
   pack each segment's codes with one exact integer contraction (helpers
   imported from ``pcilt_fused``) — and counts are small integers built in
@@ -52,6 +56,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .autotune import _round_up
 from .pcilt_fused import _precision, _quantize_f32, _strip_codes, pack_matrix
 
 __all__ = ["pcilt_shared_gemv_pallas", "pcilt_shared_conv2d_pallas"]
@@ -59,16 +64,16 @@ __all__ = ["pcilt_shared_gemv_pallas", "pcilt_shared_conv2d_pallas"]
 
 def _pool_counts_dot(codes, pack_ref, idx_ref, pool_ref, *, V: int, X: int):
     """The pooled fetch: f32 ``codes [R, Gb*group]``, pointer row
-    ``idx_ref [1, Gb]``, pre-transposed pool tile ``pool_ref [V, X, Ob]``
-    -> f32 ``[R, Ob]``.
+    ``idx_ref [1, Gb]``, pool tile ``pool_ref [V, Ob, X]`` -> f32
+    ``[R, Ob]``.
 
     Every segment pointing at pool row ``x`` with offset ``v`` fetches the
     *same* cell, so the adder tree over this grid step's ``Gb`` segments is
-    ``sum_v counts_v @ pool_t[v]``: count how many segments land on each
-    ``(v, x)`` cell (an ``[R, Gb] x [X, Gb]^T`` contraction of 0/1 values,
-    exact at any precision), then one ``[R, X] x [X, Ob]`` contraction per
-    offset value.  Counts are small integers in f32 (exact up to 2**24 ≫
-    any Gb); f32 pools contract at full precision.
+    ``sum_v counts_v @ pool_ref[v]^T``: count how many segments land on
+    each ``(v, x)`` cell (an ``[R, Gb] x [X, Gb]^T`` contraction of 0/1
+    values, exact at any precision), then one ``[R, X] x [Ob, X]^T``
+    contraction per offset value.  Counts are small integers in f32 (exact
+    up to 2**24 ≫ any Gb); f32 pools contract at full precision.
     """
     off = jnp.dot(codes, pack_ref[...],
                   preferred_element_type=jnp.float32)  # [R, Gb] offsets
@@ -81,10 +86,11 @@ def _pool_counts_dot(codes, pack_ref, idx_ref, pool_ref, *, V: int, X: int):
         counts = jax.lax.dot_general(
             oh, sel, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)  # [R, X]
-        pool_v = pool_ref[v]
-        part = jnp.dot(counts.astype(pool_v.dtype), pool_v,
-                       preferred_element_type=jnp.float32,
-                       precision=_precision(pool_v.dtype))
+        pool_v = pool_ref[v]  # [Ob, X]
+        part = jax.lax.dot_general(
+            counts.astype(pool_v.dtype), pool_v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=_precision(pool_v.dtype))
         acc = part if acc is None else acc + part
     return acc
 
@@ -125,11 +131,11 @@ def pcilt_shared_gemv_pallas(
     """x ``[B, n]`` float, scale ``[1, 1]``, seg_idx ``[1, G]`` int32,
     pool ``[X, V, O]`` -> ``[B, O]``.
 
-    ``n == G * group``; B, O are padded to tile multiples by ``ops.py``;
-    ``tiles`` is a ``(Bb, Gb, Ob)`` tuple with ``Gb | G``.  The whole pool is
-    staged per output tile (pre-transposed to ``[V, X, Ob]`` so each offset
-    value's slab is a leading-axis slice); only the ``[Gb]`` pointer block
-    walks the G axis.
+    ``n == G * group``; B is padded to a tile multiple by ``ops.py``;
+    ``tiles`` is a ``(Bb, Gb, Ob)`` tuple with ``Gb | G``.  Every pool row
+    is staged per output tile, as the ``[V, Ob, X]`` tile of the pool's
+    stored ``[V, O, X]`` order; only the ``[Gb]`` pointer block walks the G
+    axis.  The last output tile may be ragged.
     """
     B, n = x.shape
     G = seg_idx.shape[-1]
@@ -138,7 +144,7 @@ def pcilt_shared_gemv_pallas(
         raise ValueError(
             f"x trailing dim {n} != G*group = {G}*{group} "
             f"(x {x.shape}, seg_idx {seg_idx.shape}, pool {pool.shape})")
-    pool_t = jnp.transpose(pool, (1, 0, 2))  # [V, X, O], once per call
+    pool_t = jnp.transpose(pool, (1, 2, 0))  # [V, O, X]: as stored
     Bb, Gb, Ob = tiles
     pack = pack_matrix(Gb, group, bits, 1)  # codes -> [Gb] offsets
     grid = (pl.cdiv(B, Bb), pl.cdiv(O, Ob), G // Gb)
@@ -151,7 +157,7 @@ def pcilt_shared_gemv_pallas(
             pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),
             pl.BlockSpec(pack.shape, lambda i, j, k: (0, 0)),
             pl.BlockSpec((1, Gb), lambda i, j, k: (0, k)),
-            pl.BlockSpec((V, X, Ob), lambda i, j, k: (0, 0, j)),
+            pl.BlockSpec((V, Ob, X), lambda i, j, k: (0, j, 0)),
         ],
         out_specs=pl.BlockSpec((Bb, Ob), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, O), jnp.float32),
@@ -167,9 +173,11 @@ SCOPED_VMEM = 16 * 2**20
 
 def _vmem_limit(Bb, Gb, Ob, V, X, group, itemsize):
     """The compiler parameter raising the call's VMEM limit when its
-    double-buffered pool tile and per-step scratch need more than the
-    default scoped VMEM (a pool of many rows); none otherwise."""
-    need = (2 * (V * X * Ob * itemsize + Bb * Gb * group * 4 + Bb * Ob * 4)
+    double-buffered ``[V, Ob, X]`` pool tile (``X`` on lanes, padded to
+    whole 128-lane tiles) and per-step scratch need more than the default
+    scoped VMEM (a pool of many rows); none otherwise."""
+    tile = V * _round_up(Ob, 8) * _round_up(X, 128) * itemsize
+    need = (2 * (tile + Bb * Gb * group * 4 + Bb * Ob * 4)
             + Bb * V * X * 4 + X * Gb * 4 + Gb * group * Gb * 4)
     if need <= SCOPED_VMEM * 3 // 4:
         return {}
@@ -241,7 +249,7 @@ def pcilt_shared_conv2d_pallas(
             f"n_total {n_tot} must cover the patch length kh*kw*C = {n} "
             f"and the table span G*group = {G}*{group} "
             f"(x {x.shape}, seg_idx {seg_idx.shape}, pool {pool.shape})")
-    pool_t = jnp.transpose(pool, (1, 0, 2))  # [V, X, O], once per call
+    pool_t = jnp.transpose(pool, (1, 2, 0))  # [V, O, X]: as stored
     Ho = (Hp - kh) // stride + 1
     Wo = (Wp - kw) // stride + 1
     Hb, Gb, Ob = tiles
@@ -258,7 +266,7 @@ def pcilt_shared_conv2d_pallas(
             pl.BlockSpec((1, 1), lambda b, r, j, k: (0, 0)),
             pl.BlockSpec(pack.shape, lambda b, r, j, k: (0, 0)),
             pl.BlockSpec((1, Gb), lambda b, r, j, k: (0, k)),
-            pl.BlockSpec((V, X, Ob), lambda b, r, j, k: (0, 0, j)),
+            pl.BlockSpec((V, Ob, X), lambda b, r, j, k: (0, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, Hb, Wo, Ob), lambda b, r, j, k: (b, r, 0, j)),
         out_shape=jax.ShapeDtypeStruct((B, Ho, Wo, O), jnp.float32),

@@ -198,7 +198,7 @@ def test_candidate_generators_valid():
 
 def _shared_onehot_bytes(cfg, B, V, X, itemsize):
     """Per-grid-step scratch of the shared GEMV at tiling ``cfg``: f32
-    one-hot [Bb, Gb, V] + f32 counts [Bb, V, X] + staged [V, X, Ob] pool."""
+    one-hot [Bb, Gb, V] + f32 counts [Bb, V, X] + staged [V, Ob, X] pool."""
     return (cfg.Bb * cfg.Gb * V * 4 + cfg.Bb * V * X * 4
             + V * X * cfg.Ob * itemsize)
 

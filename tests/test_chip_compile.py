@@ -107,11 +107,32 @@ def test_shared_head_compiles(one_chip, no_compile_cache):
     cfg = atn.shared_gemv_candidates(SLOTS, G, V, VOCAB, X)[0]
     tiles = ops._fit_tiles((cfg.Bb, cfg.Gb, cfg.Ob), SLOTS, G, VOCAB)
     Bp = atn._round_up(SLOTS, tiles[0])
-    Op = atn._round_up(VOCAB, tiles[2])
     _compile(lambda x, s, i, p: pcilt_shared_gemv_pallas(
         x, s, i, p, bits=BITS, zero_point=ZP, group=GROUP, tiles=tiles),
         one_chip, ((Bp, D), jnp.float32), ((1, 1), jnp.float32),
-        ((1, G), jnp.int32), ((X, V, Op), jnp.float32))
+        ((1, G), jnp.int32), ((X, V, VOCAB), jnp.float32))
+
+
+@pytest.mark.parametrize("d,vocab", [(D, VOCAB), (3584, 8000)],
+                         ids=["mamba2-130m", "zamba2-7b.tp4"])
+def test_shared_head_reads_the_pool_in_place(one_chip, no_compile_cache,
+                                             monkeypatch, d, vocab):
+    """The head's fetch at 16 slots over a stored ``[X, V, O]`` pool (1.24
+    GB at mamba2-130m's widths, 0.92 GB at zamba2-7b.tp4's 1792 rows and
+    8000-token share of the vocabulary) makes no copy of the pool: the
+    kernel reads it as stored, ragged last output tile and all."""
+    from repro.core import QuantSpec
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    G = X = d // GROUP
+    spec = QuantSpec(bits=BITS, symmetric=True)
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in
+            [((16, d), jnp.float32), ((X, V, vocab), jnp.float32),
+             ((G,), jnp.int32), ((), jnp.float32)]]
+    compiled = jax.jit(lambda x, p, i, s: ops.pcilt_shared_gemv(
+        x, p, i, spec, s, GROUP)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
 @pytest.mark.parametrize("paired", [False, True])

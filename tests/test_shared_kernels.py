@@ -180,6 +180,66 @@ def test_shared_conv2d_parity(B, H, W, C, kh, kw, stride, O, bits, group,
                                rtol=1e-4, atol=1e-4)
 
 
+def _cycled_pool(n, O, group, X, spec, s):
+    """A shared pool of exactly ``X`` rows: the ``[group, O]`` segments
+    cycle through an ``X``-entry codebook (``n`` weight rows, zero rows
+    past ``n`` up to the group multiple)."""
+    G = -(-n // group)
+    cb = RNG.normal(size=(X, group, O))
+    w = cb[np.arange(G) % X].reshape(G * group, O)
+    w[n:] = 0.0
+    st = build_shared_grouped_tables(jnp.asarray(w, jnp.float32), spec, s,
+                                     group)
+    return w[:n], st
+
+
+@pytest.mark.parametrize("B,n,O,group,X", [
+    (5, 40, 300, 2, 13),    # last output tile 44 of 128 columns
+    (16, 24, 200, 2, 12),   # 16 rows, as the served head; tile 72 of 128
+    (3, 30, 129, 3, 7),     # one column past a whole tile
+])
+def test_shared_gemv_ragged_tile_reads_pool_in_place(B, n, O, group, X):
+    """The kernel reads the stored ``[X, V, O]`` pool with a ragged last
+    output tile (``O`` no multiple of ``Ob``) and a pool of rows no
+    multiple of 8, against the pointer-gather reference."""
+    spec = QuantSpec(2)
+    x = jnp.asarray(RNG.uniform(0, 3, (B, n)), jnp.float32)
+    s = calibrate(x, spec)
+    _, st = _cycled_pool(n, O, group, X, spec, s)
+    assert st.pool.shape[0] == X and O % 128
+    want = pcilt_linear(x, st, spec, s, group, path="gather")
+    G = st.n_segments
+    got = ops.pcilt_shared_gemv(x, st.pool, st.seg_idx, spec, s, group,
+                                tiles=(8, G, 128))
+    assert got.shape == (B, O)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("B,H,W,C,kh,kw,stride,O,X", [
+    (1, 6, 6, 3, 3, 3, 1, 200, 5),   # last output tile 72 of 128
+    (2, 7, 5, 2, 3, 3, 2, 131, 11),  # strided; three columns past a tile
+])
+def test_shared_conv2d_ragged_tile_reads_pool_in_place(B, H, W, C, kh, kw,
+                                                       stride, O, X):
+    """The conv kernel shares the GEMV's pool reader: ragged last output
+    tile, pool rows no multiple of 8, against the gather reference."""
+    spec, group = QuantSpec(2), 2
+    x = jnp.asarray(RNG.uniform(0, 2, (B, H, W, C)), jnp.float32)
+    n = kh * kw * C
+    s = calibrate(x, spec)
+    w, st = _cycled_pool(n, O, group, X, spec, s)
+    assert st.pool.shape[0] % 8 and O % 128
+    f = jnp.asarray(w.reshape(kh, kw, C, O), jnp.float32)
+    want = pcilt_conv2d(x, f, spec, s, group, stride=stride, path="gather")
+    got = ops.pcilt_shared_conv2d(x, st.pool, st.seg_idx, spec, s, group,
+                                  kh, kw, stride=stride,
+                                  tiles=(1, st.n_segments, 128))
+    assert got.shape == want.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
+
+
 def test_shared_conv2d_prebuilt_pool_bf16():
     from repro.core.pcilt import build_shared_grouped_tables as build
 
